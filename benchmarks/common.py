@@ -17,6 +17,29 @@ from repro.core.devicetree import detect_platform
 from repro.core.pools import PoolManager
 
 
+def harness_setup(name: str, min_devices: int = 2) -> bool:
+    """What a multi-device harness calls first.  Places the persistent
+    compile cache (``compat.use_compile_cache``), then says whether the
+    harness must re-run itself in a child process with forced host
+    devices to get ``min_devices``.  Only a CPU process does that: on a
+    chip this process already holds the devices, and a child that needs
+    them would fail or hang, so too few chips is an error, not a
+    re-exec."""
+    import jax
+
+    from repro import compat
+    compat.use_compile_cache()
+    n = len(jax.devices())
+    if n >= min_devices:
+        return False
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{name} needs >= {min_devices} devices and this "
+            f"{jax.devices()[0].device_kind} process has {n}; on a chip "
+            f"it runs in-process on the devices present")
+    return True
+
+
 def coordinator(platform: str = None, backend: str = "simulate"):
     plat = detect_platform(platform)
     return CoreCoordinator(PoolManager(plat), plat, backend=backend)

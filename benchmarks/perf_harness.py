@@ -127,7 +127,7 @@ def _count_signatures(specs) -> int:
 WARM_ROUNDS = 5
 
 
-def _time_modes(specs, n_sig: int, cache_dir=None) -> dict:
+def _time_modes(specs, n_sig: int) -> dict:
     """Cold + warm timings for all four contenders.
 
     The cold pass runs once per mode; the warm (steady-state) passes
@@ -158,7 +158,6 @@ def _time_modes(specs, n_sig: int, cache_dir=None) -> dict:
         coord = CoreCoordinator(backend="spmd", spmd_dispatch=dispatch,
                                 spmd_pack=pack,
                                 spmd_cache_cap=CACHE_CAP,
-                                compile_cache_dir=cache_dir,
                                 faults=False, quality="off")
         t0 = time.perf_counter()
         cold_res = coord.run_matrix(specs)
@@ -225,7 +224,7 @@ def _time_modes(specs, n_sig: int, cache_dir=None) -> dict:
     return modes
 
 
-def _packing_section(n_dev: int, cache_dir=None) -> dict:
+def _packing_section(n_dev: int) -> dict:
     """The width-packing showcase: a sweep of 2-engine ladders
     (observer + ONE stressor), where a wide mesh packs
     ``n_dev // 2`` ladders side by side per dispatch.  Times the
@@ -251,8 +250,7 @@ def _packing_section(n_dev: int, cache_dir=None) -> dict:
         coords[name] = CoreCoordinator(backend="spmd",
                                        spmd_pack=pack,
                                        spmd_cache_cap=CACHE_CAP,
-                                       compile_cache_dir=cache_dir,
-                                       faults=False, quality="off")
+                                              faults=False, quality="off")
         t0 = time.perf_counter()
         coords[name].run_matrix(specs)
         section[name] = {"wall_s_cold":
@@ -313,20 +311,24 @@ def _packing_section(n_dev: int, cache_dir=None) -> dict:
 
 def _run_leg(smoke: bool, cache_dir=None) -> dict:
     import jax
+
+    from repro import compat
+    if cache_dir:
+        compat.persistent_cache(cache_dir)
     n_dev = len(jax.devices())
     assert n_dev >= 2, "perf harness leg needs a multi-device mesh"
     specs = _sweep_specs(smoke)
     n_sig = _count_signatures(specs)
     cache_prewarmed = bool(cache_dir and os.path.isdir(cache_dir)
                            and os.listdir(cache_dir))
-    modes = _time_modes(specs, n_sig, cache_dir)
+    modes = _time_modes(specs, n_sig)
     packed, batched, fused, per_rung = (modes["packed"],
                                         modes["batched"],
                                         modes["fused"],
                                         modes["per_rung"])
-    assert packed["timing_source"] == "device", packed
-    assert batched["timing_source"] == "device", batched
-    assert fused["timing_source"] == "device", fused
+    assert packed["timing_source"] == "callback", packed
+    assert batched["timing_source"] == "callback", batched
+    assert fused["timing_source"] == "callback", fused
     assert per_rung["timing_source"] == "host", per_rung
     k = fused["rungs_per_ladder"]
 
@@ -334,7 +336,7 @@ def _run_leg(smoke: bool, cache_dir=None) -> dict:
         return {kk: round(b[f"wall_s_{kk}"] / a[f"wall_s_{kk}"], 3)
                 for kk in ("cold", "warm", "total")}
 
-    packing = _packing_section(n_dev, cache_dir)
+    packing = _packing_section(n_dev)
     gate_pass = (batched["wall_s_warm"] < per_rung["wall_s_warm"]
                  and batched["wall_s_warm"]
                  <= fused["wall_s_warm"] * FUSED_NOISE_BAND
@@ -401,8 +403,9 @@ _FORCE = "--xla_force_host_platform_device_count"
 
 def _spawn_leg(n_dev: int, smoke: bool, cache_dir=None) -> dict:
     """One mesh size = one fresh interpreter (the harness process never
-    initialises jax, so every leg gets its own device count)."""
-    env = dict(os.environ)
+    initialises jax, so every leg gets its own device count).  Legs are
+    CPU-only: forced host devices, never a chip."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" in flags:
         raise RuntimeError(

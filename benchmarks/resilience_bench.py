@@ -141,7 +141,7 @@ def _overhead_leg(smoke: bool) -> dict:
     plan = _build_warm(coord, specs)
     n_eng = coord._spmd_engines()
     disp = coord._dispatcher
-    activity = coord._resolved_activity()
+    activity = coord.spmd_activity
     policy = RetryPolicy()
     # the GATED contender: full machinery — retry wrapper, timing
     # validation, per-cell noisy evaluation — with re-measurement
@@ -273,7 +273,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    if len(jax.devices()) < 2:
+    from benchmarks.common import harness_setup
+    if harness_setup("resilience bench"):
         return _reexec(argv)
 
     out = {

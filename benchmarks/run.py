@@ -34,6 +34,8 @@ SUITES = [
 
 
 def main() -> int:
+    from repro import compat
+    compat.use_compile_cache()
     filters = sys.argv[1:]
     failures = []
     for name, fn in SUITES:

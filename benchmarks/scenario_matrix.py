@@ -55,8 +55,10 @@ def main() -> list:
     assert bw(f"hbm:r|hbm:r@{rf12}", 7) < bw(f"hbm:r|hbm:r@{rf21}", 7)
 
     # -- 2. batched vs naive dispatches, interpret backend ------------------
+    # host memory is refused by every probe kernel; vmem shares hbm's
+    # effective memory kind, so the two pools share signature groups
     ic = coordinator(backend="interpret")
-    small = scenario_matrix(pools=["hbm", "host"], buffer_bytes=64 << 10,
+    small = scenario_matrix(pools=["hbm", "vmem"], buffer_bytes=64 << 10,
                             obs_strategies=("r", "w"),
                             stress_shapes=DEFAULT_STRESS_SHAPES[:8],
                             iters=2, max_stressors=1)

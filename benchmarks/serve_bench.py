@@ -361,7 +361,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    if len(jax.devices()) < 2:
+    from benchmarks.common import harness_setup
+    if harness_setup("serve bench"):
         return _reexec(argv)
 
     out = {

@@ -26,12 +26,14 @@ if __name__ == "__main__":
 
 import jax  # noqa: E402  (after the device forcing above)
 
-from benchmarks.common import print_table  # noqa: E402
+from benchmarks.common import (harness_setup,  # noqa: E402
+                               print_table)
 
 BUF = 256 << 10
 
 
 def _run() -> list:
+    from repro import compat
     from repro.core.characterize import curvedb_from_result
     from repro.core.coordinator import CoreCoordinator
     from repro.core.scenarios import (ObserverSpec, ScenarioSpec,
@@ -52,20 +54,14 @@ def _run() -> list:
           f"rungs) -> {st.measure_dispatches} fused whole-ladder "
           f"dispatches ({st.host_sync_dispatches} host syncs total), "
           f"{st.model_evals} model evals for comparison")
-    # the dispatch accounting depends on the RESOLVED mode: the
-    # sweep-batched default blocks the host once per distinct
+    # the sweep-batched default blocks the host once per distinct
     # role-program signature (here the two observers differ, so two
-    # groups) with in-dispatch device clocks; installs without a
-    # timestamp source honestly fall back to the legacy per-rung path
-    # (warm + 3 timed syncs per rung)
+    # groups), its rungs timed by in-dispatch callback clocks
     timing_source = res.runs[0].execution["timing_source"]
-    if timing_source == "device":
-        assert st.measure_dispatches == st.spmd_groups
-        assert st.host_sync_dispatches == st.spmd_groups
-        assert st.host_sync_dispatches <= st.n_ladders
-    else:
-        assert st.measure_dispatches == st.spmd_rungs
-        assert st.host_sync_dispatches == 4 * st.spmd_rungs
+    assert timing_source == compat.CLOCK_SOURCE, timing_source
+    assert st.measure_dispatches == st.spmd_groups
+    assert st.host_sync_dispatches == st.spmd_groups
+    assert st.host_sync_dispatches <= st.n_ladders
 
     rows = []
     for run in res.runs:
@@ -96,7 +92,7 @@ def _run() -> list:
 
 
 def main() -> list:
-    if len(jax.devices()) >= 2:
+    if not harness_setup("spmd ladder"):
         return _run()
     # single-device harness process: re-exec with forced host devices.
     # Respect a pre-set device-count flag (like examples/

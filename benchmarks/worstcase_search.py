@@ -44,7 +44,8 @@ if __name__ == "__main__":
 
 import jax  # noqa: E402  (after the device forcing above)
 
-from benchmarks.common import print_table  # noqa: E402
+from benchmarks.common import (harness_setup,  # noqa: E402
+                               print_table)
 
 BUF = 256 << 10
 ITERS = 20
@@ -187,7 +188,7 @@ def main(argv=None) -> int:
     # defaults, not the harness's own filter arguments
     args = ap.parse_args(argv if argv is not None else [])
 
-    if len(jax.devices()) >= 2:
+    if not harness_setup("worst-case search"):
         _run(args.smoke, args.out, args.fail_if_not_worse)
         return 0
     # single-device harness process: re-exec with forced host devices

@@ -1,67 +1,47 @@
-"""JAX version-compat shims — the ONLY place allowed to touch
-version-sensitive JAX symbols.
+"""The few JAX spellings this repo fixes in one place.
 
-Policy (see README "Compat layer"): the JAX surface this repo needs has
-drifted repeatedly across releases —
+The repo is written for the installed JAX (0.9) and calls its API
+directly.  What stays here is a helper that encodes a choice the rest
+of the code must make the same way everywhere:
 
-* ``jax.sharding.AxisType`` + ``jax.make_mesh(..., axis_types=...)``
-  exist only on newer JAX; older releases have neither.
-* ``jax.shard_map`` graduated from ``jax.experimental.shard_map``.
-* Pallas-TPU compiler params were renamed
-  ``TPUCompilerParams`` -> ``CompilerParams``.
-* Memory-kind shardings (``memory_kind="pinned_host"``) are only
-  constructible when the backend actually exposes that memory space.
+* meshes use ``Auto`` axes (the sharding rules rely on GSPMD
+  propagation);
+* rung timestamps inside a dispatch come from one host callback
+  (:func:`device_clock`), the only ``io_callback`` in the tree;
+* grouped all-reduces spell ``axis_index_groups`` once
+  (:func:`psum_grouped`), so the fence checker can read it back;
+* the persistent compile cache is placed by :func:`persistent_cache`,
+  which never overrides ``JAX_COMPILATION_CACHE_DIR``.
 
-Every other module imports the helpers below instead of reaching into
-``jax.experimental`` / ``jax.sharding`` version-sensitive namespaces
-directly; the grep lint in ``tests/test_compat.py`` fails the suite if
-a drift-prone symbol appears outside this file.
-
-Everything here resolves lazily (no module-level jax state) so
-importing compat never touches jax device initialisation — the dry-run
-sets ``xla_force_host_platform_device_count`` first.
+The grep lint in ``tests/test_compat.py`` keeps those spellings, and
+removed APIs, out of every other module.  Nothing here touches device
+state at import time: the dry-run sets
+``xla_force_host_platform_device_count`` first.
 """
 from __future__ import annotations
 
 import functools
+import os
+import time
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
-
-# ---------------------------------------------------------------------------
-# Mesh construction (AxisType drift)
-# ---------------------------------------------------------------------------
-
-
-def axis_type_auto() -> Any:
-    """``jax.sharding.AxisType.Auto`` where it exists, else ``None``."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return getattr(at, "Auto", None) if at is not None else None
+# Where rung timestamps come from: a host callback, not a device
+# counter (the installed JAX exposes none).  Recorded as the
+# ``timing_source`` of every fused spmd ladder.
+CLOCK_SOURCE = "callback"
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices: Optional[Sequence[Any]] = None):
-    """``jax.make_mesh`` with Auto axis types when the installed JAX
-    supports them, silently without when it does not (older JAX treats
-    every axis as Auto anyway)."""
-    shape = tuple(shape)
+    """``jax.make_mesh`` with every axis in Auto mode."""
     axes = tuple(axes)
-    auto = axis_type_auto()
-    kw = {} if devices is None else {"devices": devices}
-    if auto is not None and hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(auto,) * len(axes), **kw)
-        except TypeError:        # make_mesh predates axis_types kwarg
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, **kw)
-    # pre-make_mesh JAX: build the Mesh by hand
-    devs = np.array(devices if devices is not None
-                    else jax.devices()[:int(np.prod(shape))])
-    return jax.sharding.Mesh(devs.reshape(shape), axes)
+    return jax.make_mesh(
+        tuple(shape), axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def make_mesh_from_devices(devices: Sequence[Any], axes: Sequence[str]):
@@ -70,234 +50,90 @@ def make_mesh_from_devices(devices: Sequence[Any], axes: Sequence[str]):
 
 
 # ---------------------------------------------------------------------------
-# shard_map (experimental -> top-level graduation)
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=1)
-def _resolve_shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental import shard_map as _esm
-    return _esm.shard_map
-
-
-@functools.lru_cache(maxsize=1)
-def _shard_map_params() -> frozenset:
-    import inspect
-    try:
-        return frozenset(inspect.signature(_resolve_shard_map()).parameters)
-    except (TypeError, ValueError):
-        return frozenset()
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=None, **kw):
-    """Version-portable ``shard_map`` (keyword-only, both signatures).
-
-    ``check_rep`` disables the static replication-rule check — required
-    for bodies containing ``pallas_call`` (no replication rule is
-    registered for it).  The kwarg itself drifted: older JAX spells it
-    ``check_rep``, newer releases renamed it ``check_vma``; releases
-    with neither simply don't check (the flag is dropped)."""
-    if check_rep is not None:
-        params = _shard_map_params()
-        if "check_rep" in params:
-            kw["check_rep"] = check_rep
-        elif "check_vma" in params:
-            kw["check_vma"] = check_rep
-    return _resolve_shard_map()(f, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, **kw)
-
-
-@functools.lru_cache(maxsize=1)
-def pallas_supported() -> bool:
-    """Can Pallas kernels actually execute on this process's backend?
-
-    True when a trivial ``pallas_call`` compiles and runs — compiled on
-    TPU, interpret-mode elsewhere.  False on installs whose Pallas
-    import or interpreter is broken/absent; callers (the spmd backend's
-    rung activities) fall back to pure-jnp traffic loops, and the
-    CurveDB ``execution`` provenance records which one ran."""
-    try:
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        def _probe(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1.0
-
-        out = pl.pallas_call(
-            _probe,
-            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-            interpret=jax.default_backend() != "tpu",
-        )(jnp.zeros((8, 128), jnp.float32))
-        jax.block_until_ready(out)
-        return True
-    except Exception:
-        return False
-
-
-# ---------------------------------------------------------------------------
-# In-dispatch timing probe (device-side rung clocks)
+# In-dispatch timing probe (rung clocks)
 # ---------------------------------------------------------------------------
 
 
 def _clock_parts(_dep=None):
     """Monotonic wall clock split into x32-safe int32 parts."""
-    import time
     t = time.perf_counter_ns()
     return np.asarray([t // 1_000_000_000, t % 1_000_000_000], np.int32)
 
 
-@functools.lru_cache(maxsize=1)
-def _resolve_io_callback():
-    """``jax.experimental.io_callback`` where it exists (it graduated
-    from the old host_callback machinery); ``None`` on releases without
-    it."""
-    try:
-        from jax.experimental import io_callback
-        return io_callback
-    except ImportError:
-        return None
-
-
-@functools.lru_cache(maxsize=1)
-def device_clock_source() -> str:
-    """Where :func:`device_clock` timestamps come from on this install.
-
-    ``"device"`` when an on-accelerator cycle counter is exposed by the
-    installed JAX (none is, on current public releases — when a TPU/GPU
-    clock primitive lands it slots in here, ahead of the fallback);
-    ``"callback"`` when the ``io_callback`` timestamp fallback is
-    available; ``"none"`` when neither exists — callers (the fused spmd
-    ladder) must then fall back to host wall-clock timing around whole
-    dispatches."""
-    if _resolve_io_callback() is not None:
-        return "callback"
-    return "none"
-
-
 def device_clock(dep):
-    """A ``(2,)``-int32 ``[seconds, nanoseconds]`` monotonic timestamp
-    taken INSIDE the dispatch, data-dependent on ``dep``.
+    """A ``(2,)``-int32 ``[seconds, nanoseconds]`` host timestamp taken
+    from INSIDE the dispatch, data-dependent on ``dep``.
 
     The fused spmd ladder brackets every scanned rung sample with two of
     these, so per-rung elapsed time comes from in-dispatch deltas
-    instead of host ``perf_counter`` around ``block_until_ready`` — no
-    dispatch/interrupt jitter in the measured region, no extra host
-    round-trips.  On installs without a timestamp source
-    (``device_clock_source() == "none"``) this returns zeros; callers
-    must check the source first.
+    instead of host ``perf_counter`` around ``block_until_ready``.  The
+    stamp is a host callback (:data:`CLOCK_SOURCE`): each costs a
+    device-to-host round trip, and XLA will not persist a program that
+    holds one in the compile cache.
 
     Consumers MUST thread the returned stamp's *value* into the work
     being timed (see the coordinator's exact-zero ``min(stamp, 0)``
-    trick): the callback fallback fills its result buffer
-    asynchronously, so a scheduling-only edge (``optimization_barrier``)
-    does not make the measured work wait for the stamp."""
+    trick): the callback fills its result buffer asynchronously, so a
+    scheduling-only edge (``optimization_barrier``) does not make the
+    measured work wait for the stamp."""
     import jax.numpy as jnp
-    ioc = _resolve_io_callback()
-    if ioc is None:
-        return jnp.zeros((2,), jnp.int32)
-    return ioc(_clock_parts, jax.ShapeDtypeStruct((2,), jnp.int32),
-               dep, ordered=False)
+    from jax.experimental import io_callback
+    return io_callback(_clock_parts, jax.ShapeDtypeStruct((2,), jnp.int32),
+                       dep, ordered=False)
 
 
 # ---------------------------------------------------------------------------
-# AOT compilation + persistent compile cache (jit staging / config drift)
+# Persistent compile cache
 # ---------------------------------------------------------------------------
 
-
-def aot_trace(jitted, *args):
-    """``jitted.trace(*args)`` where the installed JAX exposes the AOT
-    ``Traced`` stage of the trace -> lower -> compile pipeline; ``None``
-    on releases without it.  One trace then serves BOTH the structural
-    fence check (via ``traced.jaxpr``) and :func:`aot_compile` — without
-    it the spmd program builder traces every program twice (once in
-    ``make_jaxpr`` for the fence walk, once again at first dispatch)."""
-    trace = getattr(jitted, "trace", None)
-    if trace is None:
-        return None
-    try:
-        traced = trace(*args)
-    except Exception:
-        return None
-    return traced if hasattr(traced, "jaxpr") else None
-
-
-def aot_compile(jitted, *args, traced=None):
-    """Ahead-of-time ``jit(...).lower(...).compile()``: ONE compiled
-    executable per program signature, built at a controlled point
-    instead of inside the first timed dispatch (reusing a ``traced``
-    stage from :func:`aot_trace` when given, so the program is traced
-    exactly once end to end).  With :func:`persistent_cache` enabled,
-    ``compile()`` consults the on-disk cache, so repeated processes
-    skip the XLA compile wall for cacheable programs.  Returns ``None``
-    when the installed JAX cannot AOT-compile this program — callers
-    fall back to dispatch-triggered compilation and must record the
-    degradation (the CurveDB ``execution["aot"]`` provenance)."""
-    try:
-        if traced is not None:
-            return traced.lower().compile()
-        lower = getattr(jitted, "lower", None)
-        if lower is None:
-            return None
-        return lower(*args).compile()
-    except Exception:
-        return None
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
 def persistent_cache(cache_dir: str) -> bool:
-    """Enable JAX's persistent compilation cache at ``cache_dir`` and
-    return whether it took effect.
+    """Enable JAX's persistent compilation cache at ``cache_dir`` unless
+    ``JAX_COMPILATION_CACHE_DIR`` already places it, and return whether
+    the cache is on.
 
-    SCOPE: the cache is PROCESS-GLOBAL JAX configuration, not
-    per-caller state — once enabled it serves (and is written by)
-    every compile in the process, and a later call with a different
-    directory re-points the whole process.  Callers advertising an
-    opt-in (``CoreCoordinator(compile_cache_dir=...)``) must document
-    that the opt-in escapes the instance; pass a directory that
-    outlives the process's compiles.
-
-    The config spelling drifted (``jax_compilation_cache_dir`` config
-    key on current releases, ``compilation_cache.set_cache_dir`` on
-    older ones); the write-threshold knobs
-    (``jax_persistent_cache_min_*``) are best-effort — absent knobs
-    keep that release's defaults.  Honesty note: XLA refuses to persist
-    programs containing HOST CALLBACKS, so on installs where
-    :func:`device_clock_source` is ``"callback"`` the device-timed
-    fused/batched ladder programs recompile per process — the cache
-    still eliminates the compile wall for the host-timed rung programs
-    and the interpret-path measured passes, and a real accelerator
-    clock primitive (no callback) would make the fused programs
-    cacheable too."""
-    try:
+    SCOPE: the cache is PROCESS-GLOBAL JAX configuration — once enabled
+    it serves (and is written by) every compile in the process.  With
+    the environment variable set, JAX reads it at start-up and this
+    function changes nothing.  Every program is cached, however small:
+    sweeps are dominated by many medium-sized programs that sit below
+    the default write thresholds.  XLA refuses to persist programs that
+    hold host callbacks (:func:`device_clock`), so fused ladder
+    programs recompile in every process."""
+    if not os.environ.get(CACHE_ENV):
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.set_cache_dir(cache_dir)
-        except Exception:
-            return False
-    # the cache module memoizes a "disabled" verdict if anything was
-    # compiled before the dir was set (e.g. compat probes); reset it so
-    # the next compilation re-initializes against the new directory
-    try:
+        # the cache module memoizes a "disabled" verdict if anything
+        # was compiled before the dir was set; reset it so the next
+        # compilation re-initializes against the new directory
         from jax.experimental.compilation_cache import (
             compilation_cache as _cc)
         _cc.reset_cache()
-    except Exception:
-        pass
-    # cache every program, however small/fast to compile: the spmd
-    # sweeps are dominated by many medium-sized programs that sit
-    # below the default write thresholds
-    for opt, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                     ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:
-            pass
-    return True
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return bool(jax.config.jax_compilation_cache_dir)
+
+
+# the checkout this package runs from (src/repro/compat.py -> root)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def default_cache_dir() -> str:
+    """The fixed in-checkout cache path, ``<checkout>/.jax_compile_cache``
+    (listed in ``.gitignore``).  The path is part of a cache entry's
+    key, so it is never made from a temporary name, a pid or the time."""
+    return os.path.join(REPO_ROOT, ".jax_compile_cache")
+
+
+def use_compile_cache() -> Optional[str]:
+    """What every entry point (``chip_smoke.py``, ``launch/serve.py``,
+    the bench harnesses) calls first: the persistent compile cache
+    lives where ``JAX_COMPILATION_CACHE_DIR`` says, else at
+    :func:`default_cache_dir`.  Returns the directory in use."""
+    persistent_cache(default_cache_dir())
+    return jax.config.jax_compilation_cache_dir or None
 
 
 # ---------------------------------------------------------------------------
@@ -312,31 +148,15 @@ def donation_supported() -> bool:
     Probed by compiling a trivial donated program and checking that JAX
     did not warn the donation away (platforms without donation keep the
     program correct but ignore ``donate_argnums``).  The fused spmd
-    ladder donates its cached rung operands so repeated dispatches alias
-    buffers in place instead of copying."""
+    ladder donates its cached rung operands so repeated dispatches
+    alias buffers in place instead of copying."""
     import warnings
     import jax.numpy as jnp
-    try:
-        x = jnp.ones((8,), jnp.float32)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            out = jax.jit(lambda v: v + 1.0, donate_argnums=0)(x)
-            jax.block_until_ready(out)
-        return not any("donat" in str(m.message).lower() for m in w)
-    except Exception:
-        return False
-
-
-def optimization_barrier(x):
-    """``jax.lax.optimization_barrier`` where it exists (it moved into
-    ``jax.lax`` from ad_checkpoint internals); identity on releases
-    without it.  Used to pin the SPMD measured region behind the start
-    barrier: threading the barrier psum through this op gives the
-    measured activity a dataflow dependency XLA cannot hoist across."""
-    fn = getattr(jax.lax, "optimization_barrier", None)
-    if fn is None:
-        return x
-    return fn(x)
+    x = jnp.ones((8,), jnp.float32)
+    with warnings.catch_warnings(record=True) as w:
+        warnings.simplefilter("always")
+        jax.block_until_ready(jax.jit(lambda v: v + 1.0, donate_argnums=0)(x))
+    return not any("donat" in str(m.message).lower() for m in w)
 
 
 def psum_grouped(x, axis, groups=None):
@@ -345,113 +165,18 @@ def psum_grouped(x, axis, groups=None):
     (each packed ladder's psum sandwich reduces over ITS engine subset
     only).  ``groups`` is a tuple of index tuples that must partition
     the axis (e.g. ``((0, 1), (2, 3))`` on a 4-engine mesh); ``None``
-    or empty means a plain global all-reduce.
-
-    The keyword has drifted before (``axis_index_groups`` was once
-    positional-adjacent to ``axis_name`` and its validation rules vary
-    across releases), so the raw spelling is confined to this shim
-    (the grep lint in tests/test_compat.py rejects it elsewhere).  On
-    a release that rejects the keyword this degrades to a GLOBAL psum:
-    numerically safe (it is a strictly stronger barrier) but it breaks
-    subset isolation — the packed fence check sees the ungrouped psum
-    in the jaxpr and honestly reports the program unfenced."""
+    or empty means a plain global all-reduce.  The packed fence checker
+    reads the grouping back out of the traced jaxpr."""
     if not groups:
         return jax.lax.psum(x, axis)
-    try:
-        return jax.lax.psum(
-            x, axis,
-            axis_index_groups=tuple(tuple(g) for g in groups))
-    except TypeError:
-        return jax.lax.psum(x, axis)
-
-
-def pvary(x, axes):
-    """``jax.lax.pvary`` where it exists (newer shard_map replication
-    typing); identity on older JAX, where values are device-varying by
-    default and no marker is needed."""
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is None:
-        return x
-    return fn(x, axes)
+    return jax.lax.psum(x, axis,
+                        axis_index_groups=tuple(tuple(g) for g in groups))
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU compiler params (TPUCompilerParams -> CompilerParams rename)
-# ---------------------------------------------------------------------------
-
-
-def tpu_compiler_params(**kw) -> Any:
-    """Construct Pallas-TPU compiler params under either name.
-
-    Returns ``None`` when neither class exists (pure-interpret installs);
-    ``pallas_call`` accepts ``compiler_params=None``.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    try:
-        return cls(**kw)
-    except TypeError:
-        # field drift inside the params class: drop unknown kwargs
-        import inspect
-        ok = set(inspect.signature(cls).parameters)
-        return cls(**{k: v for k, v in kw.items() if k in ok})
-
-
-# ---------------------------------------------------------------------------
-# Compiled-program cost analysis (list-of-dicts -> dict drift)
-# ---------------------------------------------------------------------------
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every JAX version
-    (older releases return a one-element list of per-program dicts)."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
-# ---------------------------------------------------------------------------
-# Memory-kind shardings (HBM vs pinned-host placement)
+# Memory kinds
 # ---------------------------------------------------------------------------
 
 
 def device_memory_kinds(device) -> Tuple[str, ...]:
-    try:
-        return tuple(m.kind for m in device.addressable_memories())
-    except Exception:
-        return ()
-
-
-def single_device_sharding(device, memory_kind: Optional[str] = None):
-    """SingleDeviceSharding with ``memory_kind`` when the device can
-    address it, plain default-memory sharding otherwise (CPU containers
-    model host placement; they cannot materialise it)."""
-    if memory_kind is not None and memory_kind in device_memory_kinds(device):
-        try:
-            return jax.sharding.SingleDeviceSharding(
-                device, memory_kind=memory_kind)
-        except (TypeError, ValueError, RuntimeError):
-            pass
-    return jax.sharding.SingleDeviceSharding(device)
-
-
-def named_sharding(mesh, spec, memory_kind: Optional[str] = None):
-    """NamedSharding with the same graceful memory-kind degradation."""
-    if memory_kind is not None:
-        kinds = device_memory_kinds(mesh.devices.flat[0])
-        if memory_kind in kinds:
-            try:
-                return jax.sharding.NamedSharding(
-                    mesh, spec, memory_kind=memory_kind)
-            except (TypeError, ValueError, RuntimeError):
-                pass
-    return jax.sharding.NamedSharding(mesh, spec)
+    return tuple(m.kind for m in device.addressable_memories())

@@ -557,6 +557,62 @@ DEFAULT_BW_STRATS = ("r", "w")
 DEFAULT_STRESS_STRATS = ("r", "w", "y")
 
 
+def characterize_specs(
+    coord: CoreCoordinator,
+    *,
+    pools: Optional[Iterable[str]] = None,
+    buffer_bytes: int = 256 << 20,
+    obs_strategies: Tuple[str, ...] = DEFAULT_BW_STRATS + ("l",),
+    stress_strategies: Tuple[str, ...] = DEFAULT_STRESS_STRATS,
+    stress_shapes: Optional[
+        Iterable[Tuple[str, TrafficShape]]] = None,
+    iters: int = 500,
+) -> Tuple[List[ScenarioSpec], Dict[str, str]]:
+    """The scenario matrix :func:`characterize` runs, and the
+    ``"pool:strategy"`` pairs the coordinator's backend refuses, each
+    with its reason (observers always; stressors where the backend
+    executes them).  A refused pair gets no spec: it is reported, never
+    measured in another memory under the pool's name."""
+    pool_names = list(pools) if pools is not None else [
+        p.node.name for p in coord.pools.pools()
+        if p.node.kind != "vmem"]      # vmem probed via small buffers
+    shapes: List[Tuple[str, TrafficShape]] = [
+        (s, TrafficShape.steady()) for s in stress_strategies]
+    if stress_shapes is not None:
+        for pair in stress_shapes:
+            if pair not in shapes:
+                shapes.append(pair)
+
+    specs: List[ScenarioSpec] = []
+    refused: Dict[str, str] = {}
+    for op in pool_names:
+        cap = coord.pools.pool(op).node.size_bytes
+        nbytes = min(buffer_bytes, cap // 2)
+        for ostrat in obs_strategies:
+            why = coord.refusal(ostrat, op, nbytes)
+            if why is not None:
+                refused[f"{op}:{ostrat}"] = why
+                continue
+            for sp in pool_names:
+                s_cap = coord.pools.pool(sp).node.size_bytes
+                s_bytes = min(buffer_bytes, s_cap // 2)
+                for sstrat, shape in shapes:
+                    why = (coord.refusal(sstrat, sp, s_bytes)
+                           if coord.backend == "spmd" else None)
+                    if why is not None:
+                        refused[f"{sp}:{sstrat}"] = why
+                        continue
+                    spec = ScenarioSpec(
+                        name=f"{op}.{ostrat}|{sp}.{sstrat}"
+                             f"{('@' + shape.tag()) if shape.tag() else ''}",
+                        observer=ObserverSpec(ostrat, op, (nbytes,)),
+                        stressors=(StressorSpec(sstrat, sp, s_bytes,
+                                                shape),),
+                        iters=iters)
+                    specs.append(spec)
+    return specs, refused
+
+
 def characterize(
     coord: CoreCoordinator,
     *,
@@ -579,38 +635,16 @@ def characterize(
     consumers see identical keys); pass ``stress_shapes`` — e.g.
     :data:`repro.core.scenarios.DEFAULT_STRESS_SHAPES` — to add shaped
     stressor scenarios (mixed r/w ratios, bursts, copies, strided
-    chases) on top.
+    chases) on top.  Pairs the backend refuses are listed with their
+    reasons under ``meta["refused"]``.
     """
-    platform = coord.platform
-    pool_names = list(pools) if pools is not None else [
-        p.node.name for p in coord.pools.pools()
-        if p.node.kind != "vmem"]      # vmem probed via small buffers
-    shapes: List[Tuple[str, TrafficShape]] = [
-        (s, TrafficShape.steady()) for s in stress_strategies]
-    if stress_shapes is not None:
-        for pair in stress_shapes:
-            if pair not in shapes:
-                shapes.append(pair)
-
-    specs: List[ScenarioSpec] = []
-    for op in pool_names:
-        cap = coord.pools.pool(op).node.size_bytes
-        nbytes = min(buffer_bytes, cap // 2)
-        for ostrat in obs_strategies:
-            for sp in pool_names:
-                s_cap = coord.pools.pool(sp).node.size_bytes
-                s_bytes = min(buffer_bytes, s_cap // 2)
-                for sstrat, shape in shapes:
-                    spec = ScenarioSpec(
-                        name=f"{op}.{ostrat}|{sp}.{sstrat}"
-                             f"{('@' + shape.tag()) if shape.tag() else ''}",
-                        observer=ObserverSpec(ostrat, op, (nbytes,)),
-                        stressors=(StressorSpec(sstrat, sp, s_bytes,
-                                                shape),),
-                        iters=iters)
-                    specs.append(spec)
-    return characterize_matrix(coord, specs, batched=batched,
-                               journal=journal)
+    specs, refused = characterize_specs(
+        coord, pools=pools, buffer_bytes=buffer_bytes,
+        obs_strategies=obs_strategies, stress_strategies=stress_strategies,
+        stress_shapes=stress_shapes, iters=iters)
+    db = characterize_matrix(coord, specs, batched=batched, journal=journal)
+    db.meta["refused"] = refused
+    return db
 
 
 def characterize_matrix(coord: CoreCoordinator,
@@ -889,7 +923,8 @@ def refresh_surface_cells(
     ``journal=<path>`` (spmd backend only) makes the probe sweep
     crash-resumable through :class:`repro.core.exec.SweepJournal` —
     a serving-engine restart resumes the sweep value-identically
-    instead of restarting it.
+    instead of restarting it.  Pairs the backend refuses are skipped
+    and listed with their reasons under ``stats_meta["refused"]``.
 
     Returns ``(refreshed_keys, stats_meta)``.
     """
@@ -899,15 +934,29 @@ def refresh_surface_cells(
     s_pools = list(stress_pools) if stress_pools is not None else pool_names
 
     specs: List[ScenarioSpec] = []
+    refused: Dict[str, str] = {}
+
+    def runnable(strat: str, pool: str, nbytes: int, executed=True):
+        why = coord.refusal(strat, pool, nbytes) if executed else None
+        if why is not None:
+            refused[f"{pool}:{strat}"] = why
+        return why is None
+
     for op in pool_names:
         cap = coord.pools.pool(op).node.size_bytes
         nb_o = min(buffer_bytes, cap // 2)
         for sp in s_pools:
             s_cap = coord.pools.pool(sp).node.size_bytes
             nb = min(nb_o, s_cap // 2)
+            obs = tuple(o for o in obs_strategies if runnable(o, op, nb))
+            # surface cells stress with the mixed stream "b", which
+            # only the spmd backend executes
+            if not obs or not runnable("b", sp, nb,
+                                       coord.backend == "spmd"):
+                continue
             specs.extend(surface_matrix(
                 pools=[op], stress_pools=[sp], buffer_bytes=nb,
-                obs_strategies=obs_strategies, rw_ratios=(rw,),
+                obs_strategies=obs, rw_ratios=(rw,),
                 inject_rates=(ir,), iters=iters,
                 max_stressors=max_stressors, name_prefix="online."))
     result = coord.run_matrix(specs, batched=batched, journal=journal)
@@ -915,6 +964,7 @@ def refresh_surface_cells(
                                   rw_ratios=(rw,), inject_rates=(ir,),
                                   backend=coord.backend)
     stats = _stats_meta(result, coord.backend)
+    stats["refused"] = refused
 
     refreshed: List[SurfaceKey] = []
     for key, surf in fresh.surfaces.items():

@@ -22,7 +22,7 @@ interpret mode; contended rungs fall back to the model), ``tpu`` (same
 code path on real hardware), and ``spmd`` — which *executes*
 contention ladders on an ("engine",) mesh: observer + coupled sibling
 observers + live stressor engines, rung activities from the real
-Pallas kernel library (jnp fallback via ``compat.pallas_supported``),
+Pallas kernel library (or pure-jnp traffic loops on request),
 measured region dataflow-fenced between two psum barriers.
 
 The spmd machinery itself lives in :mod:`repro.core.exec` as an
@@ -36,8 +36,9 @@ the mesh is wide enough (``spmd_pack="auto"``), the planner's
 engine-subset width-packing transform additionally runs several
 same-signature shallow ladders SIDE BY SIDE on disjoint engine subsets
 of that one dispatch, each subset with its own grouped-psum sandwich.
-Programs are AOT-compiled once per signature and an opt-in persistent
-compile cache (``compile_cache_dir=``) spans processes.
+Programs are AOT-compiled once per signature; the persistent compile
+cache is process-wide and placed by the entry points
+(``compat.use_compile_cache``).
 """
 from __future__ import annotations
 
@@ -67,7 +68,7 @@ from repro.core.pools import MemoryPool, PoolManager
 from repro.core.scenarios import (ObserverSpec, ScenarioSpec, StressorSpec,
                                   TrafficShape)
 from repro.core.workloads import (WorkloadResult, make_shaped_workload,
-                                  measure_group)
+                                  measure_group, refusal)
 
 # long-standing import surface: tests and benchmarks reach these via
 # the coordinator module (the implementations moved to repro.core.exec)
@@ -169,12 +170,11 @@ class CoreCoordinator:
     def __init__(self, pool_mgr: Optional[PoolManager] = None,
                  platform: Optional[Platform] = None,
                  backend: str = "auto",
-                 spmd_activity: str = "auto",
+                 spmd_activity: str = "pallas",
                  spmd_dispatch: str = "batched",
                  spmd_samples: int = 3,
                  spmd_cache_cap: Optional[int] = None,
                  spmd_pack: str = "auto",
-                 compile_cache_dir: Optional[str] = None,
                  faults=None,
                  retry: Optional[exec_resilience.RetryPolicy] = None,
                  quality="auto"):
@@ -184,17 +184,14 @@ class CoreCoordinator:
             backend = "tpu" if jax.default_backend() == "tpu" else "simulate"
         assert backend in ("simulate", "interpret", "tpu", "spmd"), backend
         # what fills the spmd backend's rung measured regions: real
-        # Pallas kernels ("pallas") or pure-jnp traffic loops ("jnp");
-        # "auto" probes compat.pallas_supported() and falls back
-        # honestly, stamped into ``execution["activity"]`` provenance
-        assert spmd_activity in ("auto", "pallas", "jnp"), spmd_activity
+        # Pallas kernels ("pallas") or pure-jnp traffic loops ("jnp"),
+        # stamped into ``execution["activity"]``
+        assert spmd_activity in ("pallas", "jnp"), spmd_activity
         # sweep dispatch granularity: "batched" (default) stacks
         # same-signature ladders into ONE dispatch per group, "ladder"
         # fuses one ladder per dispatch, "rung" is the legacy
-        # one-dispatch-per-rung path.  "batched"/"ladder" need an
-        # in-dispatch timestamp source and fall back to "rung" when
-        # compat.device_clock_source() reports none; the resolved
-        # choice lands in ``execution["timing_source"]``.
+        # one-dispatch-per-rung path.  The timing source lands in
+        # ``execution["timing_source"]``.
         assert spmd_dispatch in ("batched", "ladder", "rung"), spmd_dispatch
         assert spmd_samples >= 1, spmd_samples
         # engine-subset width-packing (the planner transform): "auto"
@@ -221,33 +218,11 @@ class CoreCoordinator:
         self.retry_policy = retry or exec_resilience.RetryPolicy()
         self.quality_gate = exec_resilience.resolve_gate(quality)
         # stage 3 of the exec pipeline: program/operand LRU, AOT
-        # compile, opt-in persistent compile cache, dispatch + decode
+        # compile, dispatch + decode
         self._dispatcher = Dispatcher(self.spmd_cache_cap, spmd_samples,
-                                      compile_cache_dir,
                                       faults=(self.fault_spec.injector()
                                               if self.fault_spec
                                               else None))
-        self.compile_cache_dir = compile_cache_dir
-        self.persistent_cache_enabled = \
-            self._dispatcher.persistent_cache_enabled
-
-    def _resolved_activity(self) -> str:
-        """The rung-activity implementation the spmd backend will use."""
-        from repro import compat
-        if self.spmd_activity != "auto":
-            return self.spmd_activity
-        return "pallas" if compat.pallas_supported() else "jnp"
-
-    def _resolved_dispatch(self) -> str:
-        """The spmd dispatch mode that will actually run: the fused
-        paths need an in-dispatch timestamp source (without one, only
-        the host-timed per-rung path is honest)."""
-        from repro import compat
-        if self.spmd_dispatch == "rung":
-            return "rung"
-        if compat.device_clock_source() == "none":
-            return "rung"
-        return self.spmd_dispatch
 
     # -- spmd program cache (LRU, coordinator lifetime; the storage
     # -- lives on the Dispatcher, these delegates are the stable API) --
@@ -274,6 +249,9 @@ class CoreCoordinator:
                 raise ValidationError(
                     f"{which}: buffer {spec.buffer_bytes}B exceeds free "
                     f"space in pool {spec.pool} ({pool.available}B)")
+            if which == "main":
+                self._check_runnable(which, spec.strategy, pool,
+                                     spec.buffer_bytes)
         if cfg.iters <= 0:
             raise ValidationError("iters must be positive")
         n = cfg.scenarios if cfg.scenarios is not None \
@@ -281,6 +259,30 @@ class CoreCoordinator:
         if not 1 <= n <= self.platform.n_engines:
             raise ValidationError(
                 f"scenarios must be in [1, {self.platform.n_engines}]")
+
+    def refusal(self, strategy: str, pool: str,
+                buffer_bytes: int) -> Optional[str]:
+        """Why this backend cannot run ``strategy`` on ``pool`` at
+        ``buffer_bytes`` (None when it can; the model runs anything)."""
+        if self.backend == "simulate":
+            return None
+        p = self.pools.pool(pool)
+        if (self.backend == "spmd" and p.node.kind == "vmem"
+                and strategy != "i"):
+            return ("spmd rung kernels stream their operands from the "
+                    "pool's memory, and VMEM holds no operands")
+        return refusal(strategy, p, buffer_bytes)
+
+    def _check_runnable(self, where: str, strategy: str, pool: MemoryPool,
+                        buffer_bytes: int) -> None:
+        """Refuse a (pool, strategy) pair whose kernel cannot target the
+        pool on this backend, instead of measuring another memory under
+        the pool's name."""
+        why = self.refusal(strategy, pool.node.name, buffer_bytes)
+        if why is not None:
+            raise ValidationError(
+                f"{where}: strategy {strategy!r} cannot run on pool "
+                f"{pool.node.name!r} ({self.backend} backend): {why}")
 
     # -- scenario ladder ----------------------------------------------------
     def run(self, cfg: ExperimentConfig) -> ExperimentResult:
@@ -417,12 +419,16 @@ class CoreCoordinator:
                     raise ValidationError(
                         f"{spec.name}: observer buffer {b}B exceeds pool "
                         f"{obs.pool} ({pool.available}B free)")
+                self._check_runnable(spec.name, obs.strategy, pool, b)
         for s in spec.stressors:
             if s.strategy not in _REGISTRY:
                 raise ValidationError(
                     f"{spec.name}: unknown stressor strategy "
                     f"{s.strategy!r}")
-            self.pools.pool(s.pool)
+            pool = self.pools.pool(s.pool)
+            if self.backend == "spmd":      # only spmd executes stressors
+                self._check_runnable(spec.name, s.strategy, pool,
+                                     s.buffer_bytes)
         if spec.iters <= 0:
             raise ValidationError(f"{spec.name}: iters must be positive")
         if spec.max_stressors is not None and not (
@@ -560,7 +566,7 @@ class CoreCoordinator:
             activity = "pallas"
             measured = self._measure_triples(triples, batched, stats)
         elif self.backend == "spmd":
-            activity = self._resolved_activity()
+            activity = self.spmd_activity
             executed, fenced_by_triple, timing_by_triple = \
                 self._execute_spmd(triples, stats, activity,
                                    batched=batched, journal=journal)
@@ -657,7 +663,7 @@ class CoreCoordinator:
                 "spmd backend needs >= 2 devices; start the process with "
                 "XLA_FLAGS=--xla_force_host_platform_device_count=8 "
                 "(CPU container) or run on a real multi-device slice")
-        dispatch = self._resolved_dispatch()
+        dispatch = self.spmd_dispatch
         if dispatch == "batched" and not batched:
             dispatch = "ladder"       # megabatching explicitly disabled
         if dispatch in ("batched", "ladder"):

@@ -19,7 +19,6 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro import compat
 
 MAX_COUNTERS = 6   # ARM PMU exposes 6 programmable counters per core
 
@@ -59,7 +58,7 @@ def cost_of(fn: Callable, *args, **kw) -> Dict[str, float]:
     """AOT cost analysis of fn(*args) without executing it."""
     lowered = jax.jit(fn).lower(*args, **kw)
     compiled = lowered.compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     mem = compiled.memory_analysis()

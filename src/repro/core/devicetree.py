@@ -110,8 +110,11 @@ class Platform:
 
 TPU_V5E = Platform(
     name="tpu-v5e",
-    n_engines=8,              # engines per *measurement group*: 8 cores of a
-                              # 2x4 slice drive contention ladders (paper: 4)
+    # the modeled contention ladder's depth: up to 8 traffic engines
+    # (paper: 4 cores).  A v5e chip has ONE TensorCore, so on the chip
+    # only rung 0 is measured by one engine; the spmd backend executes
+    # rungs on min(n_engines, devices) chips, one engine per chip.
+    n_engines=8,
     line_bytes=512,
     peak_flops=197e12,
     # max_mlp calibration: TPU DMA queues pipeline deeply (hundreds of
@@ -188,14 +191,29 @@ def zcu102_partitioned() -> Platform:
                                memories=mems)
 
 
+# device trees of the TPUs this repo describes, keyed by
+# ``jax.Device.device_kind``
+_TPU_TREES = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
 def detect_platform(override: Optional[str] = None) -> Platform:
     """Auto-detect like MEMSCOPE reads the DTB at module load.
 
-    On a real TPU backend returns the v5e tree; off-TPU returns the same
-    *modeled* tree (the simulate backend supplies the temporal behaviour).
+    On a TPU the tree is keyed by the device's ``device_kind``; a TPU
+    kind with no tree is an error.  Off-TPU (the CPU, for tests and the
+    simulate backend) returns the explicitly *modeled* v5e tree.
     """
     if override == "zcu102":
         return ZCU102
-    if override in (None, "tpu-v5e"):
+    if override == "tpu-v5e":
         return TPU_V5E
-    raise KeyError(f"unknown platform {override!r}")
+    if override is not None:
+        raise KeyError(f"unknown platform {override!r}")
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    if dev.device_kind not in _TPU_TREES:
+        raise KeyError(
+            f"no device tree for TPU kind {dev.device_kind!r}; "
+            f"described: {sorted(_TPU_TREES)}")
+    return _TPU_TREES[dev.device_kind]

@@ -22,7 +22,8 @@ from repro.core.exec.fence import measured_region_is_fenced
 from repro.core.exec.plan import PlannedDispatch
 from repro.core.exec.program import (CompiledProgram, build_ladder_entry,
                                      build_rung_operands,
-                                     build_rung_program, spmd_branch_fn)
+                                     build_rung_program, compile_traced,
+                                     spmd_branch_fn)
 
 
 def _fault_site(key: Tuple) -> str:
@@ -61,7 +62,7 @@ class DispatchStats:
     spmd_groups: int = 0
     # spmd programs actually traced + compiled this run (cache
     # misses), and how many of those went through the AOT
-    # lower().compile() pipeline (compat.aot_compile) — together with
+    # lower().compile() pipeline — together with
     # host_sync_dispatches these make the dispatch-vs-compile
     # attribution in BENCH_spmd.json explicit
     programs_built: int = 0
@@ -132,12 +133,10 @@ class ProgramCache:
 
 class Dispatcher:
     """Stage 3: run planned dispatches.  Holds the program LRU and the
-    per-coordinator dispatch knobs (sample count, opt-in persistent
-    compile cache); the coordinator facade delegates here."""
+    per-coordinator dispatch knobs (sample count, fault seam); the
+    coordinator facade delegates here."""
 
-    def __init__(self, cache_cap: int, samples: int,
-                 compile_cache_dir: Optional[str] = None,
-                 faults=None):
+    def __init__(self, cache_cap: int, samples: int, faults=None):
         assert samples >= 1, samples
         self.cache = ProgramCache(cache_cap)
         self.samples = samples
@@ -147,20 +146,6 @@ class Dispatcher:
         # of (seed, site, phase, attempt) — and duck-typed, so this
         # module never imports the resilience layer
         self.faults = faults
-        # NOTE: the underlying JAX config is PROCESS-GLOBAL — enabling
-        # it here serves every compile in the process (other
-        # dispatchers included), and a second dispatcher with a
-        # different dir re-points the whole process; the attribute
-        # records only what THIS dispatcher requested
-        # (compat.persistent_cache documents scope + the host-callback
-        # caveat)
-        self.compile_cache_dir = compile_cache_dir
-        if compile_cache_dir:
-            from repro import compat
-            self.persistent_cache_enabled = compat.persistent_cache(
-                compile_cache_dir)
-        else:
-            self.persistent_cache_enabled = False
 
     def _fault(self, site: str, phase: str, stats: DispatchStats):
         """Consult the fault-injection seam.  Raising phases
@@ -253,8 +238,6 @@ class Dispatcher:
         The fused ladder path replaces both; this path is kept for
         comparison (``benchmarks/perf_harness.py``) and as the
         fallback where no in-dispatch timestamp source exists."""
-        from repro import compat
-
         roles = tuple(roles)
         rows_max = max(r[2] for r in roles)
         # the kind joins the cache key: identical role programs from
@@ -287,8 +270,8 @@ class Dispatcher:
             # every timed call, and the transfer (which scales with
             # the widest role, not the observer) would dominate the
             # measurement
-            from jax.sharding import PartitionSpec as P
-            sharding = compat.named_sharding(mesh, P("engine"), kind)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            sharding = NamedSharding(mesh, P("engine"), memory_kind=kind)
             xf = jax.device_put(xf, sharding)
             xi = jax.device_put(xi, sharding)
             jax.block_until_ready((xf, xi))
@@ -296,24 +279,19 @@ class Dispatcher:
             # rung programs carry no host callbacks, so with a
             # persistent cache enabled the compile is also reused
             # across processes.  provenance records the VERIFIED fence
-            # state, not an assertion (compat.optimization_barrier
-            # degrades to identity on JAX releases without the op —
-            # there the psum folds away and this honestly reports
-            # unfenced)
-            traced = compat.aot_trace(fn, xf, xi)
-            fenced = measured_region_is_fenced(
-                fn, xf, xi, jaxpr=getattr(traced, "jaxpr", None))
-            compiled = compat.aot_compile(fn, xf, xi, traced=traced)
+            # state, not an assertion
+            traced = fn.trace(xf, xi)
+            fenced = measured_region_is_fenced(fn, xf, xi,
+                                               jaxpr=traced.jaxpr)
+            fn = compile_traced(traced, f"spmd rung program {key!r}")
             stats.programs_built += 1
-            if compiled is not None:
-                stats.aot_compiles += 1
-            aot = compiled is not None
-            fn = compiled if compiled is not None else fn
+            stats.aot_compiles += 1
+            aot = True
             self.cache.put(key, CompiledProgram(mesh, fn, fenced,
                                                 xf, xi, aot))
         self._fault(site, "dispatch", stats)
-        jax.block_until_ready(fn(xf, xi))          # warm (+ compile
-        samples = []                               # when not AOT-built)
+        jax.block_until_ready(fn(xf, xi))          # warm
+        samples = []
         for _ in range(self.samples):
             t0 = _time.perf_counter_ns()
             jax.block_until_ready(fn(xf, xi))

@@ -58,7 +58,7 @@ def measured_region_is_fenced(fn, *example_args, jaxpr=None,
     mesh slice — each returns False.
 
     Pass ``jaxpr=`` (a ClosedJaxpr, e.g. from
-    ``compat.aot_trace(fn, *args).jaxpr``) to reuse an existing trace
+    ``jit(fn).trace(*args).jaxpr``) to reuse an existing trace
     instead of paying a second one here."""
     closed = jaxpr if jaxpr is not None \
         else jax.make_jaxpr(fn)(*example_args)

@@ -20,10 +20,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.core.exec.fence import measured_region_is_fenced
 from repro.core.exec.plan import (PlannedDispatch, effective_duty,
                                   merge_probe_operand_roles)
 from repro.core.workloads import LINE_BYTES, resolve_strategy
+
+
+class CompileError(RuntimeError):
+    """The compiler refused a program: deterministic, so the resilience
+    layer re-raises it rather than retrying or degrading."""
+
+
+def compile_traced(traced, what: str):
+    """``traced.lower().compile()``; a refusal raises CompileError."""
+    try:
+        return traced.lower().compile()
+    except Exception as exc:
+        raise CompileError(f"{what}: {exc}") from exc
+
 
 _SPMD_CHASES = ("l", "m", "t")      # latency walks: dependent gathers
 _SPMD_STREAM_2X = ("c", "x")        # copy/rmw touch two lines per line
@@ -60,17 +75,14 @@ def spmd_branch_fn(strategy: str, shape, rows: int, iters: int,
     All branches take the SAME operand pair and return a scalar so
     ``lax.switch`` can fuse them; each closes over its own static row
     count and iteration budget.  Loop bodies either carry the buffer or
-    re-issue it through ``optimization_barrier`` so XLA cannot hoist
-    the memory traffic out of the loop.
+    re-issue it with the carry through ``optimization_barrier`` so XLA
+    cannot hoist the memory traffic out of the loop.
 
     ``activity="pallas"`` builds the branch from the real kernel
     library (:mod:`repro.kernels.stream` / ``chase``: mixed-stream,
     copy, seeded write streams, strided/Sattolo chases — compiled on
-    TPU, interpret-mode elsewhere); ``"jnp"`` is the pure-jnp traffic
-    loop fallback for hosts where Pallas is unavailable
-    (``compat.pallas_supported``)."""
-    from repro import compat
-
+    TPU, interpret-mode elsewhere); ``"jnp"`` builds pure-jnp traffic
+    loops instead."""
     strat = resolve_strategy(strategy, shape)
     n = max(1, int(round(iters * effective_duty(shape))))
 
@@ -123,8 +135,10 @@ def spmd_branch_fn(strategy: str, shape, rows: int, iters: int,
         x = xf[:rows]
 
         def body(_, acc):
-            # re-issued buffer: barrier pins reads inside the loop
-            xx = compat.optimization_barrier(x)
+            # re-issued buffer, paired with the carry: a barrier on the
+            # loop-invariant buffer alone is hoisted out of the loop
+            # with the read, leaving one pass for all n
+            xx, _ = jax.lax.optimization_barrier((x, acc))
             return acc * 0.5 + jnp.sum(xx)
 
         return jax.lax.fori_loop(0, n, body, jnp.float32(0.0))
@@ -137,11 +151,11 @@ def _pallas_branch_fn(strat: str, shape, rows: int, n: int):
     is the real kernel library, not a jnp stand-in.  Every branch keeps
     a dataflow edge from its (barrier-fenced) operands into each
     kernel call — carried loop state where the kernel's output feeds
-    the next pass (copy/rmw/seeded write), ``optimization_barrier``
-    re-issue where it cannot (reads, mixed streams, chases) — so the
+    the next pass (copy/rmw/seeded write), re-issue through an
+    ``optimization_barrier`` paired with the carry where it cannot
+    (reads, mixed streams, chases) — so the
     extended jaxpr fence check can verify every ``pallas_call``
     consumes fenced data."""
-    from repro import compat
     from repro.kernels import chase as _kchase
     from repro.kernels import ops as kops
     from repro.kernels import stream as _kstream
@@ -158,9 +172,9 @@ def _pallas_branch_fn(strat: str, shape, rows: int, n: int):
             buf = xi[:rows]
 
             def cycle(_, acc):
-                # re-issued buffer: one dependent full traversal per
-                # pass, not hoistable/CSE-able across passes
-                bb = compat.optimization_barrier(buf)
+                # re-issued with the carry: one dependent full
+                # traversal per pass, not hoistable out of the loop
+                bb, _ = jax.lax.optimization_barrier((buf, acc))
                 idx = kern(bb, n_steps=rows, interpret=interp)
                 return acc + idx.astype(jnp.float32)
 
@@ -213,7 +227,7 @@ def _pallas_branch_fn(strat: str, shape, rows: int, n: int):
             x = xf[:rows]
 
             def body(_, acc):
-                xx = compat.optimization_barrier(x)
+                xx, _ = jax.lax.optimization_barrier((x, acc))
                 # the seed fences the write half of the mix (its store
                 # kernel consumes no other operand)
                 s, out = _kstream.mixed_hbm(
@@ -230,7 +244,7 @@ def _pallas_branch_fn(strat: str, shape, rows: int, n: int):
         x = xf[:rows]
 
         def body(_, acc):
-            xx = compat.optimization_barrier(x)
+            xx, _ = jax.lax.optimization_barrier((x, acc))
             return acc * 0.5 + _kstream.read_hbm(xx, block_rows=blk,
                                                  interpret=interp)
 
@@ -263,8 +277,6 @@ def build_rung_program(n_engines: int, branch_fns, engine_branch):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     devs = jax.devices()[:n_engines]
     mesh = compat.make_mesh_from_devices(devs, ("engine",))
     table = jnp.asarray(list(engine_branch), jnp.int32)
@@ -274,7 +286,7 @@ def build_rung_program(n_engines: int, branch_fns, engine_branch):
         # barrier #1: data-derived token, all-reduced into operands
         token = jax.lax.psum(xf[0, 0] + xi[0, 0].astype(xf.dtype),
                              "engine")
-        xf, xi, token = compat.optimization_barrier((xf, xi, token))
+        xf, xi, token = jax.lax.optimization_barrier((xf, xi, token))
         eng = jax.lax.axis_index("engine")
         out = jax.lax.switch(table[eng], branch_fns, xf, xi)
         # barrier #2: consumes every engine's finished activity.  (The
@@ -283,13 +295,12 @@ def build_rung_program(n_engines: int, branch_fns, engine_branch):
         done = jax.lax.psum(out, "engine")
         return out[None], done
 
-    # check_rep=False: pallas_call has no replication rule, so Pallas
+    # check_vma=False: pallas_call has no replication rule, so Pallas
     # rungs cannot trace under the checker; the stop psum still
     # replicates `done` at runtime
-    f = compat.shard_map(per_engine, mesh=mesh,
-                         in_specs=(P("engine"), P("engine")),
-                         out_specs=(P("engine"), P()),
-                         check_rep=False)
+    f = jax.shard_map(per_engine, mesh=mesh,
+                      in_specs=(P("engine"), P("engine")),
+                      out_specs=(P("engine"), P()), check_vma=False)
     return mesh, jax.jit(f)
 
 
@@ -357,8 +368,6 @@ def build_ladder_program(n_engines: int, branch_fns, branch_table,
     cache and rebind them without any host->device re-transfer."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     devs = jax.devices()[:n_engines]
     mesh = compat.make_mesh_from_devices(devs, ("engine",))
     table = np.repeat(np.asarray(branch_table, np.int32),
@@ -394,7 +403,7 @@ def build_ladder_program(n_engines: int, branch_fns, branch_table,
             # scheduling-only edge is not enough: the callback
             # fallback fills its result buffer asynchronously.
             z = jnp.minimum(t0[0] + t0[1], 0)
-            xf_, xi_, _tok = compat.optimization_barrier(
+            xf_, xi_, _tok = jax.lax.optimization_barrier(
                 (xf + z.astype(xf.dtype), xi + z, token))
             out = jax.lax.switch(row[eng], branch_fns, xf_, xi_)
             # barrier #2: consumes every subset engine's finished
@@ -411,13 +420,11 @@ def build_ladder_program(n_engines: int, branch_fns, branch_table,
                                             table_j)
         return outs[None], t0s[None], t1s[None], xf[None], xi[None]
 
-    f = compat.shard_map(per_engine, mesh=mesh,
-                         in_specs=(P("engine"), P("engine")),
-                         out_specs=(P("engine", None),
-                                    P("engine", None, None),
-                                    P("engine", None, None),
-                                    P("engine"), P("engine")),
-                         check_rep=False)
+    f = jax.shard_map(per_engine, mesh=mesh,
+                      in_specs=(P("engine"), P("engine")),
+                      out_specs=(P("engine", None), P("engine", None, None),
+                                 P("engine", None, None), P("engine"),
+                                 P("engine")), check_vma=False)
     kw = {"donate_argnums": (0, 1)} if donate else {}
     return mesh, jax.jit(f, **kw)
 
@@ -435,8 +442,6 @@ def build_scenario_program(n_engines: int, n_stressors: int,
     time — invariant 1 was unenforced)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     devs = jax.devices()[:n_engines]
     mesh = compat.make_mesh_from_devices(devs, ("engine",))
 
@@ -447,7 +452,7 @@ def build_scenario_program(n_engines: int, n_stressors: int,
         seed = (jnp.ravel(main_x)[0].astype(jnp.float32)
                 + jnp.ravel(stress_x)[0].astype(jnp.float32))
         ready = jax.lax.psum(seed, "engine")
-        main_x, stress_x, ready = compat.optimization_barrier(
+        main_x, stress_x, ready = jax.lax.optimization_barrier(
             (main_x, stress_x, ready))
 
         def run_main(m, _s):
@@ -472,9 +477,9 @@ def build_scenario_program(n_engines: int, n_stressors: int,
                             "engine")
         return out, done
 
-    f = compat.shard_map(per_engine, mesh=mesh,
-                         in_specs=(P("engine"), P("engine")),
-                         out_specs=(P("engine"), P()))
+    f = jax.shard_map(per_engine, mesh=mesh,
+                      in_specs=(P("engine"), P("engine")),
+                      out_specs=(P("engine"), P()))
     return mesh, f
 
 
@@ -519,8 +524,8 @@ class CompiledProgram:
 def build_ladder_entry(planned: PlannedDispatch, n_eng: int,
                        activity: str, samples: int,
                        stats) -> CompiledProgram:
-    """Build, fence-verify, place and (where the installed JAX allows)
-    AOT-compile one planned dispatch's fused ladder program.
+    """Build, fence-verify, place and AOT-compile one planned
+    dispatch's fused ladder program.
 
     The planned rung table is expanded to the full mesh: width-packed
     dispatches tile the subset-width roles across ``n_subsets``
@@ -531,11 +536,10 @@ def build_ladder_entry(planned: PlannedDispatch, n_eng: int,
     verbatim — already at full packed width, one heterogeneous row per
     step, no tiling — and seed operands from the MERGED role layout so
     one operand set serves every row (``merge_probe_operand_roles``).
-    The program is traced exactly ONCE (``compat.aot_trace``):
+    The program is traced exactly ONCE (``jit(...).trace``):
     the same trace feeds the structural fence walk — packed dispatches
     pass their subsets so EVERY subset's sandwich is verified
     independently — and ``lower().compile()``."""
-    from repro import compat
 
     idle_iters = planned.rungs[0][0][3]
     full_rungs = []
@@ -578,23 +582,19 @@ def build_ladder_entry(planned: PlannedDispatch, n_eng: int,
     # commit the operands onto the mesh BEFORE tracing: the AOT
     # executable is specialized to the placed shardings, and the
     # fence walk sees the same program the dispatch runs
-    from jax.sharding import PartitionSpec as P
-    sharding = compat.named_sharding(mesh, P("engine"), planned.kind)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    sharding = NamedSharding(mesh, P("engine"), memory_kind=planned.kind)
     xf = jax.device_put(xf, sharding)
     xi = jax.device_put(xi, sharding)
     jax.block_until_ready((xf, xi))
-    traced = compat.aot_trace(fn, xf, xi)
+    traced = fn.trace(xf, xi)
     # provenance records the VERIFIED fence state of every scanned
     # rung of every stacked ladder — including, for packed programs,
     # per-subset isolation of every psum sandwich — not an assertion
-    # (compat degradation is honestly reported as unfenced)
-    fenced = measured_region_is_fenced(
-        fn, xf, xi, jaxpr=getattr(traced, "jaxpr", None),
-        subsets=subsets)
-    compiled = compat.aot_compile(fn, xf, xi, traced=traced)
+    fenced = measured_region_is_fenced(fn, xf, xi, jaxpr=traced.jaxpr,
+                                       subsets=subsets)
+    compiled = compile_traced(
+        traced, f"spmd ladder program ({planned.group} ladders)")
     stats.programs_built += 1
-    if compiled is not None:
-        stats.aot_compiles += 1
-    return CompiledProgram(mesh, compiled if compiled is not None
-                           else fn, fenced, xf, xi,
-                           compiled is not None)
+    stats.aot_compiles += 1
+    return CompiledProgram(mesh, compiled, fenced, xf, xi, True)

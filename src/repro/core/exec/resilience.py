@@ -39,7 +39,9 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.compat import CLOCK_SOURCE
 from repro.core.exec import plan as exec_plan
+from repro.core.exec.program import CompileError
 
 log = logging.getLogger(__name__)
 
@@ -53,10 +55,10 @@ _PHASE_KINDS = {"compile": ("compile_error",),
                 "dispatch": ("runtime_error", "timeout"),
                 "decode": ("corrupt_timing",)}
 
-#: programming errors retrying cannot fix — surface immediately,
-#: wrapped with the failing group's context
+#: programming errors and compiler refusals retrying cannot fix —
+#: surface immediately, wrapped with the failing group's context
 _NON_RETRYABLE = (ValueError, TypeError, KeyError, IndexError,
-                  AttributeError, AssertionError)
+                  AttributeError, AssertionError, CompileError)
 
 
 class InjectedFault(RuntimeError):
@@ -232,11 +234,10 @@ class QualityGate:
     """Per-rung spread acceptance: a rung whose sample spread exceeds
     ``rel_spread`` times its median (and the absolute
     ``min_spread_ns`` floor — microsecond rungs jitter harmlessly) is
-    *noisy*.  Device-timed dispatches re-measure up to ``remeasure``
+    *noisy*.  Callback-timed dispatches re-measure up to ``remeasure``
     times, keeping each rung's lower-spread sample set; rungs still
-    noisy after that are flagged ``noisy=True`` in provenance.  The
-    default is a wide guard (spread 8x median) firing only on real
-    interference, so zero-noise accounting normally holds exactly."""
+    noisy are flagged ``noisy=True``.  The default (spread 8x median)
+    fires only on real interference."""
     rel_spread: float = 8.0
     remeasure: int = 2
     min_spread_ns: float = 100_000.0
@@ -252,15 +253,14 @@ def resolve_faults(faults, environ=None) -> Optional[FaultSpec]:
     env var set; a string parses; a FaultSpec passes through."""
     if faults is None:
         return FaultSpec.from_env(environ)
-    if faults is False or (isinstance(faults, str)
-                           and faults.lower() in ("off", "none")):
+    if faults is False or str(faults).lower() in ("off", "none"):
         return None
     if isinstance(faults, str):
         return FaultSpec.parse(faults)
     if isinstance(faults, FaultSpec):
         return faults
-    raise TypeError(f"faults must be None, False, 'off', a spec "
-                    f"string or a FaultSpec — got {faults!r}")
+    raise TypeError(f"faults must be None, False, 'off', a spec string "
+                    f"or a FaultSpec — got {faults!r}")
 
 
 def resolve_gate(quality) -> Optional[QualityGate]:
@@ -579,7 +579,7 @@ def _pack_outcomes(ctx: _Ctx, planned, med, spread, fenced: bool,
         _wave, subset = planned.member_slot(g)
         ks = noisy_by_g.get(g, [])
         timing = {
-            "timing_source": "device",
+            "timing_source": CLOCK_SOURCE,
             "samples": ctx.dispatcher.samples,
             "rung_time_spread_ns": [int(s) for s in spread[g]],
             "dispatches": 1 + state.remeasures,

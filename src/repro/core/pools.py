@@ -88,41 +88,39 @@ class MemoryPool:
         return self._place(data)
 
     def _place(self, data: jax.Array) -> jax.Array:
-        kind = self.node.memory_kind
         dev = jax.devices()[0]
-        if kind in (None, "device"):
+        kind = self.effective_memory_kind()
+        if kind is None:
             return jax.device_put(data, dev)
-        # compat degrades to default memory on backends without this kind
-        # (CPU container): placement is emulated; accounting stays exact.
-        try:
-            return jax.device_put(
-                data, compat.single_device_sharding(dev, kind))
-        except (ValueError, RuntimeError):
-            # kind advertised but transfer refused: same degradation
-            return jax.device_put(data, dev)
+        # a kind the device advertises but refuses raises here
+        return jax.device_put(
+            data, jax.sharding.SingleDeviceSharding(dev, memory_kind=kind))
 
     def effective_memory_kind(self) -> Optional[str]:
-        """The memory kind :meth:`place` actually lands arrays in.
+        """The memory kind :meth:`place` lands arrays in.
 
-        ``None`` = the device's default memory.  Pools whose declared
-        kind the backend cannot address (e.g. ``pinned_host`` on this
-        CPU container) degrade to the default, so two pools with equal
-        effective kinds are *execution-equivalent* — the matrix runner
-        uses this to decide which observers may share one stacked
-        vmapped measurement batch."""
+        ``None`` = the device's default memory: pools that declare no
+        kind (VMEM residency, modeled peers) or ``"device"``.  A declared
+        kind the device does not list is an error, never a quiet
+        fallback to the default memory.  Two pools with equal effective
+        kinds are *execution-equivalent* — the matrix runner uses this
+        to decide which observers may share one stacked vmapped
+        measurement batch."""
         kind = self.node.memory_kind
         if kind in (None, "device"):
             return None
-        if kind in compat.device_memory_kinds(jax.devices()[0]):
-            return kind
-        return None
+        have = compat.device_memory_kinds(jax.devices()[0])
+        if kind not in have:
+            raise PoolError(
+                f"pool {self.node.name}: memory kind {kind!r} is not "
+                f"addressable on {jax.devices()[0].device_kind} "
+                f"(device lists {list(have)})")
+        return kind
 
     def sharding_for(self, mesh, spec) -> jax.sharding.NamedSharding:
         """NamedSharding carrying this pool's memory kind (upool export)."""
-        kind = self.node.memory_kind
-        if kind in (None, "device"):
-            return jax.sharding.NamedSharding(mesh, spec)
-        return compat.named_sharding(mesh, spec, kind)
+        return jax.sharding.NamedSharding(
+            mesh, spec, memory_kind=self.effective_memory_kind())
 
     # -- status -----------------------------------------------------------
     @property
@@ -179,12 +177,18 @@ class UserPool:
     pool: MemoryPool
 
     def place(self, tree, mesh=None, specs=None):
-        """Place a pytree of arrays into this pool's memory."""
+        """Place a pytree of arrays into this pool's memory and return
+        once the copies have landed: no transfer into or out of host
+        memory is left in flight when the caller drops the arrays (the
+        TPU runtime faults on a pinned_host DMA that completes after its
+        buffers went away)."""
         if mesh is None:
-            return jax.tree.map(self.pool._place, tree)
-        return jax.tree.map(
-            lambda x, sp: jax.device_put(
-                x, self.pool.sharding_for(mesh, sp)), tree, specs)
+            placed = jax.tree.map(self.pool._place, tree)
+        else:
+            placed = jax.tree.map(
+                lambda x, sp: jax.device_put(
+                    x, self.pool.sharding_for(mesh, sp)), tree, specs)
+        return jax.block_until_ready(placed)
 
     def sharding(self, mesh, spec):
         return self.pool.sharding_for(mesh, spec)

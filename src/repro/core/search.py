@@ -314,7 +314,7 @@ def measure_candidates(coord, spec: SearchSpec, arm: SearchArm, cands,
                     + stats.degraded_ladders + stats.noisy_remeasures)
     outcomes = exec_resilience.run_group(
         coord._dispatcher, planned, n_eng=n_eng,
-        activity=coord._resolved_activity(), mode="batched",
+        activity=coord.spmd_activity, mode="batched",
         stats=stats, policy=getattr(coord, "retry_policy", None),
         gate=getattr(coord, "quality_gate", None))
     dirty = (stats.faults_injected + stats.retried_dispatches

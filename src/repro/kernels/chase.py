@@ -32,6 +32,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.stream import VMEM_LIMIT_BYTES
+
 LANE = 128
 
 
@@ -101,8 +103,10 @@ def chase_vmem(buf: jnp.ndarray, *, n_steps: int,
     return pl.pallas_call(
         functools.partial(_chase_vmem_body, n_steps=n_steps),
         in_specs=[pl.BlockSpec(buf.shape, lambda: (0, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(buf)[0, 0]
 
@@ -113,26 +117,35 @@ def chase_vmem(buf: jnp.ndarray, *, n_steps: int,
 
 
 def _chase_hbm_body(x_hbm_ref, o_ref, line_ref, sem, *, n_steps: int):
+    g = pl.program_id(0)
+
     def step(_, idx):
         cp = pltpu.make_async_copy(
-            x_hbm_ref.at[pl.ds(idx, 1)], line_ref, sem)
+            x_hbm_ref.at[g, pl.ds(idx, 1)], line_ref, sem)
         cp.start()
         cp.wait()
         return line_ref[0, 0]
 
-    o_ref[0, 0] = jax.lax.fori_loop(0, n_steps, step, jnp.int32(0))
+    o_ref[g] = jax.lax.fori_loop(0, n_steps, step, jnp.int32(0))
 
 
 def chase_hbm(buf: jnp.ndarray, *, n_steps: int,
               interpret: bool = False) -> jnp.ndarray:
     """buf: (n_lines, 128) int32 staying in HBM; exactly one outstanding
-    single-line DMA at any time."""
-    return pl.pallas_call(
+    single-line DMA at any time.  A stacked (G, n_lines, 128) buffer
+    walks its G chains one after another (one grid step each) and
+    returns their G final indices: the TPU lowering cannot ``vmap`` a
+    kernel whose operand stays in HBM, so batching lives here."""
+    stacked = buf.reshape((-1,) + buf.shape[-2:])
+    g = stacked.shape[0]
+    out = pl.pallas_call(
         functools.partial(_chase_hbm_body, n_steps=n_steps),
+        grid=(g,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, 1), lambda: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((g,), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, LANE), jnp.int32),
                         pltpu.SemaphoreType.DMA],
         interpret=interpret,
-    )(buf)[0, 0]
+    )(stacked)
+    return out if buf.ndim == 3 else out[0]

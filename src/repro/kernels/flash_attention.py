@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 LANE = 128
@@ -149,7 +148,7 @@ def flash_attention(
             pltpu.VMEM((block_q, LANE), jnp.float32),   # l
             pltpu.VMEM((block_q, d), jnp.float32),      # acc
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

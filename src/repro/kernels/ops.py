@@ -1,7 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container) and False on real
-hardware, so the same call sites work in both environments.
+``interpret`` defaults to True off-TPU (the CPU runs the Pallas
+interpreter) and False on a TPU, so the same call sites work in both.
+A TPU process never runs a kernel in interpret mode.
 """
 from __future__ import annotations
 
@@ -22,7 +23,12 @@ def on_tpu() -> bool:
 
 
 def _interp(override: Optional[bool]) -> bool:
-    return (not on_tpu()) if override is None else override
+    if on_tpu():
+        if override:
+            raise ValueError("interpret mode requested in a TPU process; "
+                             "kernels run compiled on the chip")
+        return False
+    return True if override is None else override
 
 
 # --- stream ------------------------------------------------------------------

@@ -29,9 +29,20 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 DEFAULT_BLOCK_ROWS = 512  # 512*128*4B = 256 KiB per block
+
+# VMEM-resident kernels hold their whole buffer in one block.  The
+# largest buffer they are handed (workloads.VMEM_KERNEL_BYTES) plus the
+# compiler's own scratch must fit under this scoped-VMEM limit, which
+# stays below the 128 MiB a v5e TensorCore has.
+VMEM_LIMIT_BYTES = 100 << 20
+
+
+def _vmem_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _grid_blocks(n_rows: int, block_rows: int) -> int:
@@ -45,7 +56,14 @@ def _grid_blocks(n_rows: int, block_rows: int) -> int:
 
 
 def _read_body(x_ref, acc_ref):
-    acc_ref[0, 0] = jnp.sum(x_ref[...], dtype=jnp.float32)
+    # one scalar accumulator in SMEM across the sequential grid: the TPU
+    # stores scalars only to SMEM, and an (n, 1) VMEM output would break
+    # the (8, 128) block tiling
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        acc_ref[0, 0] = jnp.float32(0.0)
+
+    acc_ref[0, 0] += jnp.sum(x_ref[...], dtype=jnp.float32)
 
 
 def _write_body(o_ref, *, value: float):
@@ -97,15 +115,14 @@ def read_hbm(x: jnp.ndarray, *, block_rows: int = DEFAULT_BLOCK_ROWS,
              interpret: bool = False) -> jnp.ndarray:
     """Sum x by streaming every block through VMEM once. x: (R, 128) f32."""
     n = _grid_blocks(x.shape[0], block_rows)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _read_body,
         grid=(n,),
         in_specs=[pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret,
-    )(x)
-    return jnp.sum(out)
+    )(x)[0, 0]
 
 
 def write_hbm(shape_rows: int, *, value: float = 1.0,
@@ -248,8 +265,9 @@ def read_vmem(x: jnp.ndarray, *, repeats: int = 16,
     return pl.pallas_call(
         functools.partial(_read_vmem_body, repeats=repeats),
         in_specs=[pl.BlockSpec(x.shape, lambda: (0, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        compiler_params=_vmem_params(),
         interpret=interpret,
     )(x)[0, 0]
 
@@ -262,5 +280,6 @@ def write_vmem(shape_rows: int, *, repeats: int = 16,
         in_specs=[],
         out_specs=pl.BlockSpec((shape_rows, LANE), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((shape_rows, LANE), jnp.float32),
+        compiler_params=_vmem_params(),
         interpret=interpret,
     )()

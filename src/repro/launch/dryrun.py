@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# The two lines above MUST run before any other import (jax locks the
-# device count on first init).  This module is the multi-pod dry-run:
+# The lines above MUST run before any other import (jax locks the
+# platform and device count on first init).  The dry-run is CPU-only:
+# it never claims a chip, so the per-cell child processes of ``--all``
+# never compete for one.  This module is the multi-pod dry-run:
 # it AOT-lowers + compiles every (architecture x input shape) cell on the
 # production meshes — 16x16 (one pod) and 2x16x16 (two pods) — proving
 # that every sharding in the system is coherent at 256/512 chips, and it
@@ -25,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.analysis import hlo as hlo_mod
 from repro.analysis import roofline
 from repro.configs.base import (SHAPES, MeshConfig, ModelConfig, ShapeSpec,
@@ -218,7 +220,7 @@ def analyze_cell(compiled, meta, cfg: ModelConfig,
                         + mem.get("temp_size_in_bytes", 0)
                         + mem.get("output_size_in_bytes", 0)
                         - mem.get("alias_size_in_bytes", 0))
-    xla_cost = compat.cost_analysis(compiled)
+    xla_cost = compiled.cost_analysis() or {}
     cost = hlo_mod.analyze(compiled.as_text())
     terms = roofline.compute_terms(
         cost, cfg=cfg, shape=shape, mesh_desc=meta["mesh"],
@@ -303,7 +305,7 @@ def main(argv=None) -> int:
     ap.add_argument("--shape", choices=list(SHAPES))
     ap.add_argument("--mesh", default="pod", choices=["pod", "multipod"])
     ap.add_argument("--all", action="store_true",
-                    help="run every cell (subprocess per cell)")
+                    help="run every cell (CPU only, subprocess per cell)")
     ap.add_argument("--skip-existing", action="store_true")
     ap.add_argument("--out", default=DEFAULT_OUT)
     ap.add_argument("--list", action="store_true")

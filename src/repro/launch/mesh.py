@@ -32,10 +32,13 @@ def mesh_from_config(mc: MeshConfig):
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Whatever this host offers (CPU tests / examples): (data, model)."""
+    """A (data, model) mesh over this process's first data*model
+    devices; asking for more devices than exist raises."""
     n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, n // data) if data else 1
+    if data * model > n:
+        raise ValueError(
+            f"mesh data={data} x model={model} needs {data * model} "
+            f"devices; this process has {n}")
     return _mesh((data, model), ("data", "model"))
 
 

@@ -2,6 +2,9 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
         --reduced --batch 4 --prompt-len 32 --new-tokens 32
+
+The pools are characterized on the backend the platform resolves to:
+compiled probe kernels on a TPU, the queueing model elsewhere.
 """
 from __future__ import annotations
 
@@ -13,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.configs.base import ServeConfig, get_config
 from repro.core.characterize import characterize
 from repro.core.coordinator import CoreCoordinator
@@ -38,6 +42,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    compat.use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -46,10 +51,13 @@ def main(argv=None) -> int:
                        shape_kind="decode")
 
     # MEMSCOPE: characterize, then let the advisor place the KV cache
-    coord = CoreCoordinator(backend="simulate")
+    coord = CoreCoordinator()
     db = characterize(coord, pools=["hbm", "host"],
                       obs_strategies=("r", "l"), stress_strategies=("w",),
                       iters=10)
+    if db.meta["refused"]:
+        print(f"[serve] refused on the {coord.backend} backend: "
+              f"{db.meta['refused']}")
     advisor = PlacementAdvisor(db, coord.platform)
 
     params = lm.init_params(cfg, jax.random.PRNGKey(args.seed))

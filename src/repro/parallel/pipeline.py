@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 def gpipe_schedule(apply_stage: Callable[[Any, jnp.ndarray], jnp.ndarray],
                    *, n_stages: int, n_micro: int, axis: str = "stage"):
@@ -72,7 +70,7 @@ def gpipe_schedule(apply_stage: Callable[[Any, jnp.ndarray], jnp.ndarray],
 
         act0 = jnp.zeros(mb_shape, x_micro.dtype)
         # the carry becomes device-varying after ppermute: mark it so
-        act0, out0 = compat.pvary((act0, out0), (axis,))
+        act0, out0 = jax.lax.pvary((act0, out0), (axis,))
         (_, out), _ = jax.lax.scan(
             tick, (act0, out0), jnp.arange(ticks, dtype=jnp.int32))
         # only the last stage banked anything (zeros elsewhere): reduce to
@@ -91,9 +89,8 @@ def make_gpipe(mesh: Mesh, apply_stage, *, n_micro: int,
     n_stages = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     per_device = gpipe_schedule(apply_stage, n_stages=n_stages,
                                 n_micro=n_micro, axis=axis)
-    return compat.shard_map(per_device, mesh=mesh,
-                            in_specs=(params_spec, x_spec),
-                            out_specs=x_spec)
+    return jax.shard_map(per_device, mesh=mesh,
+                         in_specs=(params_spec, x_spec), out_specs=x_spec)
 
 
 def reference_pipeline(apply_stage, params_all, x_micro):

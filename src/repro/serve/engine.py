@@ -71,11 +71,12 @@ def pool_capacities(advisor, *, pool_mgr=None,
     """
     caps: Dict[str, int] = {}
     if pool_mgr is not None:
+        from repro.core.pools import PoolError
         for p in advisor.pools:
             try:
                 caps[p] = pool_mgr.pool(p).available
-            except Exception:
-                continue            # pool not backed on this platform
+            except PoolError:
+                continue            # the platform has no such pool
     elif hbm_free_bytes is not None:
         caps = {p: advisor.platform.memories[p].size_bytes
                 for p in advisor.pools if p in advisor.platform.memories}
@@ -164,6 +165,8 @@ class GenerateResult:
     tokens: Any                 # (B, T)
     steps: int
     kv_pool: str                # the pool the caches ENDED in
+    # (B, V) f32 logits the last emitted token was sampled from
+    last_logits: Any = None
     # online-loop provenance (monitored decode only; empty otherwise)
     drift_events: List[Any] = field(default_factory=list)
     migrations: List[Any] = field(default_factory=list)
@@ -212,14 +215,11 @@ class ServeEngine:
         """Materialise the cache pytree in ``pool_name`` via its upool.
         With a pool manager every pool goes through ``upool.place`` —
         including "hbm", so a rollback moves host-placed arrays BACK to
-        device memory instead of silently leaving them put."""
+        device memory instead of silently leaving them put.  A pool the
+        platform cannot back raises (``PoolError``)."""
         if self.pool_mgr is None:
             return caches
-        try:
-            upool = self.pool_mgr.upool(pool_name)
-        except Exception:
-            return caches           # pool not backed on this platform
-        return upool.place(caches)
+        return self.pool_mgr.upool(pool_name).place(caches)
 
     def duty_cycle(self) -> Optional[float]:
         return self._duty
@@ -252,35 +252,37 @@ class ServeEngine:
         tok = sample_token(logits, key, temperature)[:, None]
 
         if self.monitor is None and on_step is None:
-            return self._generate_scan(caches, tok, key, s,
+            return self._generate_scan(caches, tok, logits, key, s,
                                        max_new_tokens, temperature,
                                        kv_pool)
-        return self._generate_monitored(caches, tok, key, s, b, max_len,
-                                        max_new_tokens, temperature,
-                                        kv_pool, rw_mix, on_step)
+        return self._generate_monitored(caches, tok, logits, key, s, b,
+                                        max_len, max_new_tokens,
+                                        temperature, kv_pool, rw_mix,
+                                        on_step)
 
-    def _generate_scan(self, caches, tok, key, s: int,
+    def _generate_scan(self, caches, tok, logits, key, s: int,
                        max_new_tokens: int, temperature: float,
                        kv_pool: str) -> GenerateResult:
         def body(carry, i):
-            caches, tok, key = carry
+            caches, tok, _logits, key = carry
             key, sub = jax.random.split(key)
             caches, logits = self._decode(self.params, caches, tok,
                                           s + i)
             nxt = sample_token(logits, sub, temperature)[:, None]
-            return (caches, nxt, key), tok[:, 0]
+            return (caches, nxt, logits, key), tok[:, 0]
 
         # prefill already sampled token 0; decode the remaining N-1
-        (caches, last, _), toks = jax.lax.scan(
-            body, (caches, tok, key),
+        (caches, last, logits, _), toks = jax.lax.scan(
+            body, (caches, tok, logits, key),
             jnp.arange(max_new_tokens - 1, dtype=jnp.int32))
         out = jnp.concatenate(
             [jnp.moveaxis(toks, 0, 1), last], axis=1) \
             if max_new_tokens > 1 else last
-        return GenerateResult(out, max_new_tokens, kv_pool)
+        return GenerateResult(out, max_new_tokens, kv_pool,
+                              last_logits=logits)
 
-    def _generate_monitored(self, caches, tok, key, s: int, b: int,
-                            max_len: int, max_new_tokens: int,
+    def _generate_monitored(self, caches, tok, logits, key, s: int,
+                            b: int, max_len: int, max_new_tokens: int,
                             temperature: float, kv_pool: str,
                             rw_mix: float, on_step) -> GenerateResult:
         """The python decode loop: token-identical to the scan path
@@ -329,7 +331,8 @@ class ServeEngine:
         out = jnp.concatenate(
             [jnp.stack(emitted, axis=1), tok], axis=1) \
             if max_new_tokens > 1 else tok
-        result = GenerateResult(out, max_new_tokens, kv_pool)
+        result = GenerateResult(out, max_new_tokens, kv_pool,
+                                last_logits=logits)
         if mon is not None:
             result.drift_events = list(mon.drift_events[d0:])
             result.migrations = list(mon.migrations[m0:])

@@ -1,6 +1,6 @@
-"""repro.compat shim behaviour on the installed JAX, plus the
-grep-based drift lint: version-sensitive JAX symbols must not appear
-outside compat.py (the ISSUE-1 "0 occurrences" acceptance criterion).
+"""repro.compat helpers on the installed JAX, plus the grep lint:
+the spellings compat.py owns, and APIs the installed JAX removed, must
+not appear anywhere else.
 """
 import os
 import re
@@ -16,7 +16,7 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
 # ---------------------------------------------------------------------------
-# Shim behaviour
+# Helpers on the installed JAX
 # ---------------------------------------------------------------------------
 
 
@@ -31,78 +31,67 @@ def test_make_mesh_from_devices():
     assert m.axis_names == ("engine",)
 
 
-def test_shard_map_resolves_and_runs():
+def test_shard_map_with_grouped_psum_traces():
+    """The installed ``jax.shard_map`` (``check_vma=False``, as the
+    ladder programs call it) traces and runs a compat grouped psum."""
     from jax.sharding import PartitionSpec as P
     mesh = compat.make_mesh((1,), ("d",))
-    f = compat.shard_map(lambda x: x * 2, mesh=mesh,
-                         in_specs=(P(),), out_specs=P())
+    f = jax.shard_map(lambda x: compat.psum_grouped(x * 2, "d"),
+                      mesh=mesh, in_specs=(P(),), out_specs=P(),
+                      check_vma=False)
     np.testing.assert_array_equal(
         np.asarray(f(jnp.ones((4,)))), 2 * np.ones((4,)))
 
 
-def test_pvary_is_safe_everywhere():
-    """compat.pvary must be a value-preserving no-op on every JAX —
-    exercised where the axis is actually bound (inside shard_map), so
-    newer JAX's real pvary has a mesh context to resolve against."""
-    from jax.sharding import PartitionSpec as P
-    mesh = compat.make_mesh((1,), ("data",))
-    # psum re-replicates the device-varying value pvary produces on
-    # newer JAX (identity on a 1-device axis), so one body works on
-    # every version
-    f = compat.shard_map(
-        lambda x: jax.lax.psum(compat.pvary(x, ("data",)), "data"),
-        mesh=mesh, in_specs=(P(),), out_specs=P())
-    x = jnp.ones((2,))
-    np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x))
+def test_pool_placement_lands_in_the_pool_memory_kind():
+    """Placement through a pool lands in the memory kind the pool
+    reports, and an array placed in host memory says so: no pool
+    quietly falls back to device memory under its own name."""
+    from repro.core.devicetree import TPU_V5E
+    from repro.core.pools import PoolManager
+    mgr = PoolManager(TPU_V5E)
+    for name in ("hbm", "host"):
+        pool = mgr.pool(name)
+        x = pool.place(jnp.ones((8, 128)))
+        kind = pool.effective_memory_kind()
+        assert x.sharding.memory_kind == (kind or jax.devices()[0]
+                                          .default_memory().kind)
+    assert mgr.pool("host").effective_memory_kind() in \
+        compat.device_memory_kinds(jax.devices()[0])
 
 
-def test_tpu_compiler_params_constructs():
-    p = compat.tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert p is not None
-    # unknown kwargs are dropped, not fatal (field drift tolerance)
-    p2 = compat.tpu_compiler_params(
-        dimension_semantics=("parallel",),
-        definitely_not_a_real_field_xyz=1)
-    assert p2 is not None
+def test_pool_with_unlisted_memory_kind_raises():
+    """A pool whose declared memory kind the device does not list is an
+    error, never a quiet placement in the default memory."""
+    import dataclasses
+
+    from repro.core.devicetree import TPU_V5E
+    from repro.core.pools import PoolError, PoolManager
+    node = dataclasses.replace(TPU_V5E.memories["host"],
+                               memory_kind="no_such_memory")
+    plat = dataclasses.replace(
+        TPU_V5E, memories={**TPU_V5E.memories, "host": node})
+    pool = PoolManager(plat).pool("host")
+    with pytest.raises(PoolError, match="no_such_memory"):
+        pool.effective_memory_kind()
+    with pytest.raises(PoolError):
+        pool.place(jnp.ones((8, 128)))
 
 
-def test_memory_kind_shardings_degrade_gracefully():
-    dev = jax.devices()[0]
-    s = compat.single_device_sharding(dev, "pinned_host")
-    x = jax.device_put(jnp.ones((2, 2)), s)
-    assert x.shape == (2, 2)
-    mesh = compat.make_mesh((1,), ("d",))
-    from jax.sharding import PartitionSpec as P
-    ns = compat.named_sharding(mesh, P(), "pinned_host")
-    assert ns.mesh is mesh
+def test_cost_of_reads_the_compiled_cost_analysis():
+    from repro.core.counters import cost_of
+    cost = cost_of(lambda x: x @ x, jnp.ones((8, 8)))
+    assert cost["HLO_FLOPS"] > 0
+    assert cost["PEAK_MEMORY"] > 0
 
 
-def test_optimization_barrier_preserves_values():
-    x = jnp.ones((2, 2))
-    y = jnp.float32(3.0)
-    xx, yy = compat.optimization_barrier((x, y))
-    np.testing.assert_array_equal(np.asarray(xx), np.asarray(x))
-    assert float(yy) == 3.0
-
-
-def test_cost_analysis_returns_dict():
-    compiled = jax.jit(lambda x: x @ x).lower(
-        jnp.ones((8, 8))).compile()
-    ca = compat.cost_analysis(compiled)
-    assert isinstance(ca, dict)
-    assert ca.get("flops", 0) > 0
-
-
-def test_device_clock_shim():
-    """The in-dispatch timestamp probe: a declared source, plausible
-    monotonic [s, ns] parts under jit, and strict ordering when the
-    stamp's VALUE is threaded into the dependent computation (the
-    async-fill contract the fused spmd ladder relies on)."""
-    src = compat.device_clock_source()
-    assert src in ("device", "callback", "none")
-    if src == "none":
-        pytest.skip("no timestamp source on this install")
+def test_device_clock_stamps():
+    """The in-dispatch timestamp probe: a host callback (so the rung
+    provenance says ``"callback"``), plausible monotonic [s, ns] parts
+    under jit, and strict ordering when the stamp's VALUE is threaded
+    into the dependent computation (the async-fill contract the fused
+    spmd ladder relies on)."""
+    assert compat.CLOCK_SOURCE == "callback"
 
     def f(x):
         t0 = compat.device_clock(x[0])
@@ -125,49 +114,57 @@ def test_donation_supported_probe():
     assert compat.donation_supported() == compat.donation_supported()
 
 
-def test_aot_trace_and_compile_shims():
-    """The AOT pipeline shims: one trace feeds both the jaxpr consumer
-    (the fence checker) and lower().compile(); the compiled executable
-    computes the same values; non-stageable callables degrade to None
-    instead of raising."""
-    f = jax.jit(lambda x: x * 2 + 1)
-    x = jnp.ones((8,))
-    traced = compat.aot_trace(f, x)
-    if traced is not None:
-        assert hasattr(traced, "jaxpr")
-    compiled = compat.aot_compile(f, x, traced=traced)
-    if compiled is None:
-        pytest.skip("no AOT lower/compile pipeline on this install")
-    np.testing.assert_allclose(np.asarray(compiled(x)),
-                               3.0 * np.ones(8))
-    # a bare Python callable has no AOT stages: None, not an exception
-    assert compat.aot_trace(lambda v: v, x) is None
-    assert compat.aot_compile(lambda v: v, x) is None
+def _reset_cache_config(old_dir):
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_compilation_cache_dir", old_dir)
+    compilation_cache.reset_cache()
 
 
-def test_persistent_cache_shim(tmp_path):
-    """compat.persistent_cache enables JAX's on-disk compile cache (and
-    reports honestly whether it took effect): a freshly-compiled
-    callback-free program lands in the directory."""
-    import os
-
-    old = getattr(jax.config, "jax_compilation_cache_dir", None)
-    enabled = compat.persistent_cache(str(tmp_path))
+def test_persistent_cache_writes_to_its_directory(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR unset, compat.persistent_cache
+    turns JAX's on-disk compile cache on at the given directory: a
+    freshly compiled callback-free program lands there."""
+    monkeypatch.delenv(compat.CACHE_ENV, raising=False)
+    old = jax.config.jax_compilation_cache_dir
     try:
-        assert enabled in (True, False)
-        if not enabled:
-            pytest.skip("persistent compilation cache unavailable")
+        assert compat.persistent_cache(str(tmp_path))
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
         x = jnp.ones((32, 32))
         jax.block_until_ready(jax.jit(lambda v: v @ v + 1.75)(x))
         assert any(n.endswith("-cache") for n in os.listdir(tmp_path))
     finally:
-        try:
-            jax.config.update("jax_compilation_cache_dir", old)
-        except Exception:
-            pass
+        _reset_cache_config(old)
 
 
-def test_psum_grouped_shim():
+def test_persistent_cache_never_overrides_the_environment(tmp_path,
+                                                          monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR places the cache, no repo code
+    sets the directory."""
+    monkeypatch.setenv(compat.CACHE_ENV, str(tmp_path / "from_env"))
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        compat.persistent_cache(str(tmp_path / "from_code"))
+        compat.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == old
+    finally:
+        _reset_cache_config(old)
+
+
+def test_default_compile_cache_is_a_fixed_ignored_path(monkeypatch):
+    """Without the environment variable, the entry points cache at one
+    fixed path inside the checkout, which git ignores."""
+    path = compat.default_cache_dir()
+    assert path == os.path.join(ROOT, ".jax_compile_cache")
+    with open(os.path.join(ROOT, ".gitignore"), encoding="utf-8") as f:
+        assert ".jax_compile_cache/" in f.read().split()
+    seen = []
+    monkeypatch.setattr(compat, "persistent_cache",
+                        lambda d: seen.append(d) or True)
+    compat.use_compile_cache()
+    assert seen == [path]
+
+
+def test_psum_grouped_lands_in_the_jaxpr():
     """compat.psum_grouped: a plain global all-reduce when no groups
     are given (executed here), and with groups the axis_index_groups
     partition must land in the traced program — trace-level is what
@@ -178,11 +175,11 @@ def test_psum_grouped_shim():
     mesh = compat.make_mesh((1,), ("engine",))
 
     def body(groups):
-        # check_rep=False: shard_map's replication-rewrite mode has no
-        # rule for grouped psum; the ladder programs trace this way too
-        return compat.shard_map(
+        # check_vma=False: shard_map's replication check has no rule
+        # for grouped psum; the ladder programs trace this way too
+        return jax.shard_map(
             lambda x: compat.psum_grouped(x, "engine", groups),
-            mesh=mesh, in_specs=(P(),), out_specs=P(), check_rep=False)
+            mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False)
 
     x = jnp.arange(4.0)
     np.testing.assert_array_equal(np.asarray(body(None)(x)),
@@ -191,7 +188,6 @@ def test_psum_grouped_shim():
     found = [e.params.get("axis_index_groups")
              for sub in jax.core.subjaxprs(jaxpr.jaxpr)
              for e in sub.eqns if "psum" in e.primitive.name]
-    # lists-of-group-indices normalise across releases; compare as sets
     assert found and tuple(map(tuple, found[0])) == ((0,),)
 
 
@@ -227,44 +223,31 @@ def test_exec_pipeline_module_size_lint():
 # Drift lint: grep the tree for version-sensitive symbols
 # ---------------------------------------------------------------------------
 
-# Symbols that have drifted across JAX releases.  Spelled with [] splits
-# so this file does not match itself.
+# Spelled with [] splits so this file does not match itself.
 _FORBIDDEN = [
-    r"jax\.sharding\.Axis" + r"Type",
+    # compat.make_mesh owns the mesh axis-type choice (Auto axes)
     r"\bAxis" + r"Type\b",
     r"axis_" + r"types\s*=",
+    # APIs the installed JAX removed or renamed
     r"\bTPUCompiler" + r"Params\b",
-    r"pltpu\.Compiler" + r"Params\b",
-    r"jax\.shard" + r"_map\b",
     r"jax\.experimental\s+import\s+shard" + r"_map",
     r"jax\.experimental\.shard" + r"_map",
-    r"jax\.lax\.pv" + r"ary\b",
-    # drift-prone method call; compat.cost_analysis(...) is the shim
-    r"(?<!compat)\.cost_an" + r"alysis\(\)",
-    r"SingleDeviceSharding\(.*memory" + r"_kind",
-    r"NamedSharding\(.*memory" + r"_kind",
-    # lax.switch's `operand=` kwarg is deprecated drift: operands are
-    # passed positionally everywhere.  Two spellings: same-line, and a
-    # bare continuation line (the historical bug had the kwarg on its
-    # own wrapped line, which a same-line pattern cannot see)
+    r"\bcheck_" + r"rep\s*=",
+    # lax.switch's `operand=` kwarg is gone: operands are passed
+    # positionally.  Two spellings: same-line, and a bare continuation
+    # line (the historical bug had the kwarg on its own wrapped line)
     r"lax\.switch\(.*oper" + r"and\s*=",
     r"^\s*oper" + r"and\s*=",
-    # optimization_barrier moved namespaces across releases; the shim
-    # in compat.py is the only allowed spelling
-    r"lax\.optimization_" + r"barrier\b",
-    # io_callback graduated from host_callback and its fill semantics
-    # are backend-dependent; compat.device_clock is the only consumer
+    # the rung clock is one host callback, owned by compat.device_clock
     r"\bio_call" + r"back\b",
-    # the persistent compilation cache's config spellings drifted
-    # (config keys on current JAX, compilation_cache.set_cache_dir on
-    # older); compat.persistent_cache is the only allowed consumer
+    # the persistent compilation cache is placed only by
+    # compat.persistent_cache, which honours JAX_COMPILATION_CACHE_DIR
     r"jax_compilation_" + r"cache_dir",
     r"jax_persistent_" + r"cache_min",
     r"\bset_cache_" + r"dir\b",
     r"jax\.experimental\.compilation_" + r"cache",
-    # grouped collectives: the axis_index_groups kwarg's spelling and
-    # validation rules drift across releases; compat.psum_grouped is
-    # the only allowed consumer (reading the param back OUT of a
+    # grouped collectives: compat.psum_grouped is the one spelling the
+    # packed fence checker reads back (reading the param OUT of a
     # traced jaxpr — params.get(...) — carries no "=" and stays legal)
     r"axis_index_" + r"groups\s*=",
 ]
@@ -275,6 +258,9 @@ _EXEMPT = (os.path.join("src", "repro", "compat.py"),
 
 
 def _py_files():
+    for f in sorted(os.listdir(ROOT)):          # chip_smoke.py & co.
+        if f.endswith(".py"):
+            yield os.path.join(ROOT, f)
     for d in _SCAN_DIRS:
         for root, _dirs, files in os.walk(os.path.join(ROOT, d)):
             for f in files:
@@ -282,7 +268,7 @@ def _py_files():
                     yield os.path.join(root, f)
 
 
-def test_no_version_sensitive_jax_symbols_outside_compat():
+def test_no_compat_owned_or_removed_jax_symbols_outside_compat():
     pats = [re.compile(p) for p in _FORBIDDEN]
     offenders = []
     for path in _py_files():
@@ -297,5 +283,5 @@ def test_no_version_sensitive_jax_symbols_outside_compat():
                             f"{rel}:{lineno}: {line.strip()}"
                             f"  [{pat.pattern}]")
     assert not offenders, (
-        "version-sensitive JAX symbols outside repro/compat.py "
-        "(route through the compat shim):\n" + "\n".join(offenders))
+        "compat-owned or removed JAX spellings outside repro/compat.py:"
+        "\n" + "\n".join(offenders))

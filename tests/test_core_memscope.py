@@ -38,6 +38,64 @@ def test_detect_platform():
         detect_platform("nope")
 
 
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+@pytest.mark.parametrize("kind,tree", [("TPU v5 lite", "tpu-v5e"),
+                                       ("TPU v5e", "tpu-v5e"),
+                                       ("TPU v9 imaginary", None)])
+def test_detect_platform_keys_a_tpu_by_device_kind(monkeypatch, kind, tree):
+    """On a TPU the tree comes from ``device_kind``; a kind the repo has
+    no tree for is an error, never a default."""
+    import jax
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", kind)])
+    if tree is None:
+        with pytest.raises(KeyError, match="no device tree"):
+            detect_platform()
+    else:
+        assert detect_platform().name == tree
+
+
+@pytest.mark.parametrize("backend,pool,strategy,nbytes,refused", [
+    ("simulate", "host", "r", 1 << 20, False),    # the model runs anything
+    ("interpret", "hbm", "r", 1 << 20, False),
+    ("interpret", "host", "i", 0, False),          # idle touches no memory
+    ("interpret", "peer", "r", 1 << 20, True),     # no kernel reaches a peer
+    ("interpret", "vmem", "r", 32 << 20, False),
+    ("interpret", "vmem", "r", 33 << 20, True),    # beyond what compiles
+    ("interpret", "vmem", "c", 1 << 20, True),     # no VMEM-resident copy
+    ("spmd", "vmem", "r", 1 << 20, True),          # rungs stream operands
+])
+def test_refusal_of_pairs_a_backend_cannot_run(backend, pool, strategy,
+                                               nbytes, refused):
+    c = CoreCoordinator(backend=backend)
+    assert (c.refusal(strategy, pool, nbytes) is not None) is refused
+
+
+def test_host_pool_probes_are_refused_where_host_memory_is_real():
+    """Where the device lists pinned_host, a host-pool probe would hand
+    a kernel a host-memory operand, which no probe kernel can take: the
+    coordinator refuses it with the reason instead of measuring HBM
+    under the host's name, and characterize records the refusal."""
+    c = CoreCoordinator(backend="interpret")
+    if c.pools.pool("host").effective_memory_kind() != "pinned_host":
+        pytest.skip("this device lists no pinned_host memory")
+    assert "pinned_host" in c.refusal("r", "host", 1 << 20)
+    with pytest.raises(ValidationError, match="cannot run on pool"):
+        c.run(ExperimentConfig(ActivitySpec("r", "host", 64 << 10),
+                               ActivitySpec("i", "hbm", 0), iters=1,
+                               scenarios=1))
+    db = characterize(c, pools=["hbm", "host"], buffer_bytes=64 << 10,
+                      obs_strategies=("r",), stress_strategies=("w",),
+                      iters=1)
+    assert set(db.meta["refused"]) == {"host:r"}
+    assert {k.obs_pool for k in db.surfaces} == {"hbm"}
+
+
 def test_platform_json_roundtrip():
     p2 = Platform.from_json(TPU_V5E.to_json())
     assert p2.memories["hbm"].peak_bw_gbps == 819.0
@@ -74,6 +132,29 @@ def test_upool_place():
     placed = up.place(tree)
     assert placed["x"].shape == (4, 4)
     assert up.name == "hbm"
+
+
+@pytest.mark.parametrize("pool", ["hbm", "host"])
+@pytest.mark.parametrize("with_mesh", [False, True])
+def test_upool_place_returns_landed_arrays(pool, with_mesh):
+    """A placement is complete when it returns: no copy into or out of
+    host memory is still in flight when the caller drops the arrays."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro import compat
+    up = PoolManager().upool(pool)
+    tree = {"k": jnp.ones((64, 128)), "v": jnp.zeros((64, 128))}
+    if with_mesh:
+        mesh = compat.make_mesh((1,), ("x",), devices=jax.devices()[:1])
+        placed = up.place(tree, mesh, {"k": P(), "v": P()})
+    else:
+        placed = up.place(tree)
+    want = up.pool.effective_memory_kind() or "device"
+    for leaf in jax.tree.leaves(placed):
+        assert leaf.is_ready()
+        assert leaf.sharding.memory_kind == want
 
 
 # ---------------------------------------------------------------------------

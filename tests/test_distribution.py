@@ -157,8 +157,8 @@ def test_compressed_psum_matches_f32_psum():
         return mean, exact
 
     g = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 64))}
-    fm = compat.shard_map(f, mesh=mesh, in_specs=({"w": P("data")},),
-                          out_specs=({"w": P("data")}, {"w": P("data")}))
+    fm = jax.shard_map(f, mesh=mesh, in_specs=({"w": P("data")},),
+                       out_specs=({"w": P("data")}, {"w": P("data")}))
     mean, exact = fm(g)
     scale = float(jnp.max(jnp.abs(g["w"]))) / 127.0
     np.testing.assert_allclose(np.asarray(mean["w"]),
@@ -220,7 +220,7 @@ def test_spmd_backend_executes_fenced_ladder_on_8_devices():
     spec = ScenarioSpec(
         "spmd-multi",
         (ObserverSpec("r", "hbm", (BUF,)),      # bandwidth observer
-         ObserverSpec("l", "host", (BUF,))),    # latency observer
+         ObserverSpec("l", "hbm", (BUF,))),     # latency observer
         (StressorSpec("w", "hbm", BUF),),
         iters=3, max_stressors=3)
 
@@ -238,7 +238,7 @@ def test_spmd_backend_executes_fenced_ladder_on_8_devices():
         assert run.execution["executed_rungs"] == [0, 1, 2, 3]
         assert run.execution["modeled_rungs"] == []
         assert run.execution["n_engines"] == 8
-        assert run.execution["timing_source"] == "device"
+        assert run.execution["timing_source"] == "callback"
         assert run.execution["dispatches"] == \
             1 + run.execution["remeasures"]
         assert len(run.execution["rung_time_spread_ns"]) == 4
@@ -257,13 +257,13 @@ def test_spmd_backend_executes_fenced_ladder_on_8_devices():
 
     # per-observer curves, executed provenance, in CurveDB
     db = characterize_matrix(c, [spec])
-    assert set(db.curves) == {"hbm:r|hbm:w", "host:l|hbm:w"}
+    assert set(db.curves) == {"hbm:r|hbm:w", "hbm:l|hbm:w"}
     for key in db.curves:
         assert len(db.curves[key]) == 4
         ex = db.provenance[key]["execution"]
         assert ex["backend"] == "spmd" and ex["fenced"]
         assert ex["executed_rungs"] == [0, 1, 2, 3]
     assert all(p.bandwidth_gbps > 0 for p in db.curves["hbm:r|hbm:w"])
-    assert all(p.latency_ns > 0 for p in db.curves["host:l|hbm:w"])
+    assert all(p.latency_ns > 0 for p in db.curves["hbm:l|hbm:w"])
     print("spmd ladder OK")
     """, n_devices=8)

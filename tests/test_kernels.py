@@ -228,3 +228,25 @@ def test_flash_attention_ragged_seq_vs_ref(s, causal, window):
     assert out.shape == q.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("n_lines", [8, 64])
+def test_chase_hbm_walks_a_stacked_buffer_chain_by_chain(n_lines):
+    """A stacked (G, n, 128) buffer walks its G chains one after another
+    inside one kernel and returns each chain's own final index."""
+    bufs = np.stack([chase.chain_buffer(n_lines, s) for s in (0, 1, 2)])
+    out = chase.chase_hbm(jnp.asarray(bufs), n_steps=n_lines - 1, **I)
+    assert [int(v) for v in out] == [
+        ref.chase_ref(b, n_lines - 1) for b in bufs]
+
+
+def test_interpret_mode_is_refused_in_a_tpu_process(monkeypatch):
+    """A TPU process never runs a kernel in interpret mode: the default
+    resolves to compiled, and an explicit request raises."""
+    from repro.kernels import ops
+    assert ops._interp(None) is (not ops.on_tpu())
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert ops._interp(None) is False
+    assert ops._interp(False) is False
+    with pytest.raises(ValueError, match="interpret"):
+        ops._interp(True)

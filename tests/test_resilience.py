@@ -223,7 +223,7 @@ def test_zero_fault_path_exact_accounting():
     for o in outs:
         assert o.med == [1000.0, 1000.0]
         t = o.timing
-        assert t["timing_source"] == "device"
+        assert t["timing_source"] == "callback"
         assert t["dispatches"] == 1 and t["remeasures"] == 0
         assert t["attempts"] == 1 and t["degraded_from"] is None
         assert t["fault_kind"] is None and t["noisy"] is False
@@ -259,7 +259,7 @@ def test_packed_degrades_to_unpacked():
     assert [d.packed for d in disp.planned_calls] == [True, False]
     assert st.degraded_ladders == 4
     for o in outs:
-        assert o.timing["timing_source"] == "device"
+        assert o.timing["timing_source"] == "callback"
         assert o.timing["degraded_from"] == "packed"
         assert o.timing["attempts"] == 2
         assert o.med == [1000.0, 1000.0]
@@ -280,7 +280,7 @@ def test_batched_split_isolates_failure_to_one_ladder():
     by_name = {o.entry.spec.name: o for o in outs}
     for n in ("a", "b", "d"):
         t = by_name[n].timing
-        assert t["timing_source"] == "device"
+        assert t["timing_source"] == "callback"
         assert t["degraded_from"] == "batched" and t["group_size"] == 1
     c = by_name["c"].timing
     assert c["timing_source"] == "host"
@@ -568,15 +568,16 @@ def test_chaos_sweep_completes_with_every_curve():
     from repro.core.coordinator import CoreCoordinator
     from repro.core.characterize import curvedb_from_result
     from repro.core.scenarios import (ObserverSpec, ScenarioSpec,
-                                      StressorSpec)
+                                      StressorSpec, TrafficShape)
 
     BUF = 64 << 10
-    specs = [ScenarioSpec(f"chaos-{o}-{s}-{p}",
+    specs = [ScenarioSpec(f"chaos-{o}-{s}-{dc}",
                           ObserverSpec(o, "hbm", (BUF,)),
-                          (StressorSpec(s, p, BUF),),
+                          (StressorSpec(s, "hbm", BUF,
+                                        TrafficShape.burst(dc)),),
                           iters=3, max_stressors=1)
              for o in ("r", "w") for s in ("r", "w")
-             for p in ("hbm", "host")]
+             for dc in (0.5, 1.0)]
     coord = CoreCoordinator(backend="spmd",
                             faults="mixed=0.35,seed=7", quality="off")
     res = coord.run_matrix(specs)
@@ -614,10 +615,10 @@ def test_sweep_journal_end_to_end_resume():
 
     BUF = 64 << 10
     specs = [ScenarioSpec(f"jrn-{i}", ObserverSpec(o, "hbm", (BUF,)),
-                          (StressorSpec("w", p, BUF),),
+                          (StressorSpec(s, "hbm", BUF),),
                           iters=3, max_stressors=1)
-             for i, (o, p) in enumerate(
-                 [("r", "hbm"), ("w", "hbm"), ("r", "host")])]
+             for i, (o, s) in enumerate(
+                 [("r", "w"), ("w", "w"), ("r", "y")])]
     tmp = tempfile.mkdtemp()
     jpath = os.path.join(tmp, "sweep.journal")
 
@@ -681,3 +682,26 @@ def test_env_fault_spec_reaches_dispatcher():
     res = c.run_matrix([spec])
     assert len(res.runs) == 1       # chaos on, curve still complete
     """, extra_env={"REPRO_FAULT_SPEC": "mixed=0.3,seed=7"})
+
+
+def test_compiler_refusal_is_raised_not_degraded():
+    """A program the compiler refuses is refused every time: the
+    resilience layer re-raises it with the group's context instead of
+    retrying, degrading to another program, or modeling the curve."""
+    from repro.core.exec.program import CompileError
+    disp = FakeDispatcher(default=CompileError("kernel ran out of VMEM"))
+    with pytest.raises(res.GroupExecutionError) as ei:
+        _run(disp, _plan(packed=True))
+    assert isinstance(ei.value.cause, CompileError)
+    assert len(disp.planned_calls) == 1       # no retry, no degradation
+
+
+def test_compile_traced_names_the_refused_program():
+    from repro.core.exec.program import CompileError, compile_traced
+
+    class _Refused:
+        def lower(self):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    with pytest.raises(CompileError, match="rung program 7.*Mosaic"):
+        compile_traced(_Refused(), "rung program 7")

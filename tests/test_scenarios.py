@@ -353,9 +353,17 @@ def test_interpret_matrix_runs_real_kernels():
 
 def test_batched_runner_fewer_dispatches_64():
     """>= 64-scenario sweep: the batched runner must dispatch
-    demonstrably fewer measured passes than the per-point loop."""
+    demonstrably fewer measured passes than the per-point loop.  Pools
+    that share one effective memory kind share its signature groups;
+    a pool whose kind no probe kernel can take (host memory, listed as
+    ``pinned_host`` here as on a chip) is refused, not measured."""
     c = CoreCoordinator(backend="interpret")
-    specs = scenario_matrix(pools=["hbm", "host"],
+    pools = [p for p in ("hbm", "host", "vmem")
+             if c.refusal("r", p, 64 << 10) is None]
+    assert (("host" in pools) ==
+            (c.pools.pool("host").effective_memory_kind() != "pinned_host"))
+    kinds = {c.pools.pool(p).effective_memory_kind() for p in pools}
+    specs = scenario_matrix(pools=pools,
                             buffer_bytes=64 << 10,
                             obs_strategies=("r", "w"),
                             stress_shapes=DEFAULT_STRESS_SHAPES[:8],
@@ -365,7 +373,7 @@ def test_batched_runner_fewer_dispatches_64():
     naive = c.run_matrix(specs, batched=False)
     assert naive.stats.measure_dispatches == len(specs)
     assert batched.stats.measure_dispatches < naive.stats.measure_dispatches
-    assert batched.stats.measure_dispatches <= 8
+    assert batched.stats.measure_dispatches <= 8 * len(kinds)
     # both modes measured every scenario
     assert batched.stats.n_scenarios == naive.stats.n_scenarios == len(specs)
     for run in batched.runs:
@@ -479,27 +487,49 @@ def test_multi_observer_spec_roundtrip_and_keys():
 
 
 def test_multi_observer_single_vmapped_pass():
-    """Two observers measuring two pools (whose placement lands in the
-    same physical memory on this container) collapse into ONE vmapped
-    measured pass, each yielding its own correctly-labeled curve."""
-    c = CoreCoordinator(backend="interpret")
+    """Two observers measuring two pools whose placement lands in the
+    same physical memory (equal effective memory kinds) collapse into
+    ONE vmapped measured pass, each yielding its own correctly-labeled
+    curve — here HBM split into two pools, as a partitioned deployment
+    exports it.  A pool in another memory that no probe kernel can take
+    (host memory) is refused with its reason."""
+    import dataclasses
+
+    from repro.core.devicetree import TPU_V5E
+    from repro.core.pools import PoolManager
+    hbm = TPU_V5E.memories["hbm"]
+    plat = dataclasses.replace(TPU_V5E, memories={
+        **TPU_V5E.memories,
+        "hbm-b": dataclasses.replace(hbm, name="hbm-b",
+                                     size_bytes=hbm.size_bytes // 2)})
+    c = CoreCoordinator(PoolManager(plat), plat, backend="interpret")
+    assert c.pools.pool("hbm-b").effective_memory_kind() == \
+        c.pools.pool("hbm").effective_memory_kind()
     spec = ScenarioSpec(
         "multi",
         (ObserverSpec("r", "hbm", (64 << 10,)),
-         ObserverSpec("r", "host", (64 << 10,))),
+         ObserverSpec("r", "hbm-b", (64 << 10,))),
         (StressorSpec("w", "hbm", 64 << 10),),
         iters=2, max_stressors=1)
     res = c.run_matrix([spec])
     assert res.stats.n_ladders == 2
     assert res.stats.measure_dispatches == 1     # one pass, two pools
     keys = {run.key for run in res.runs}
-    assert keys == {"hbm:r|hbm:w", "host:r|hbm:w"}
+    assert keys == {"hbm:r|hbm:w", "hbm-b:r|hbm:w"}
     for run in res.runs:
         assert run.scenarios[0].main.pool == run.observer.pool
         assert run.scenarios[0].main.elapsed_ns > 0
     # ...and per-observer curves land in CurveDB
     db = characterize_matrix(c, [spec])
     assert set(db.curves) == keys
+    if c.pools.pool("host").effective_memory_kind() == "pinned_host":
+        host = ScenarioSpec(
+            "host", (ObserverSpec("r", "hbm", (64 << 10,)),
+                     ObserverSpec("r", "host", (64 << 10,))),
+            (StressorSpec("w", "hbm", 64 << 10),),
+            iters=2, max_stressors=1)
+        with pytest.raises(ValidationError, match="pinned_host"):
+            c.run_matrix([host])
 
 
 def test_multi_observer_same_pool_keys_do_not_alias():

@@ -261,18 +261,25 @@ def test_probe_batch_dispatch_fences_every_probe():
     packed fence is NOT valid for any other mesh partition."""
     _run_forced("""
         import jax
-        from repro import compat
         from repro.core.coordinator import CoreCoordinator
         from repro.core.exec import plan as exec_plan
+        from repro.core.exec import program as exec_program
         from repro.core.exec.dispatch import DispatchStats
         from repro.core.exec.fence import measured_region_is_fenced
         from repro.core.exec.program import build_ladder_entry
         from repro.core.scenarios import (ObserverSpec, ScenarioSpec,
                                           StressorSpec, TrafficShape)
 
-        # keep the raw traceable fn (an AOT executable cannot be
-        # re-walked with different subsets below)
-        compat.aot_compile = lambda *a, **k: None
+        # keep the trace the program was compiled from (an AOT
+        # executable cannot be re-walked with different subsets below)
+        traces = []
+        _compile = exec_program.compile_traced
+
+        def _keep_trace(traced, what):
+            traces.append(traced)
+            return _compile(traced, what)
+
+        exec_program.compile_traced = _keep_trace
 
         BUF = 64 << 10
         coord = CoreCoordinator(backend="spmd")
@@ -297,10 +304,10 @@ def test_probe_batch_dispatch_fences_every_probe():
         # the packed probe program's sandwich is per-subset: the same
         # program is NOT a fence for a different partition
         assert not measured_region_is_fenced(
-            entry.call, entry.xf, entry.xi, subsets=((0, 2), (1, 3)))
+            None, jaxpr=traces[-1].jaxpr, subsets=((0, 2), (1, 3)))
         med, _s, fenced, aot = coord._dispatcher.run_planned(
             planned, 4, "jnp", "batched", stats)
-        assert fenced and not aot
+        assert fenced and aot
         assert stats.host_sync_dispatches == 1
         assert med.shape == (3, 1) and (med > 0).all()
         print("PROBE_FENCE_OK")

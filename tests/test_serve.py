@@ -560,11 +560,18 @@ def test_probe_sweep_journal_resume_value_identical():
     kw = dict(pools=["hbm", "host"], stress_pools=["hbm"], rw_ratio=0.7,
               inject_rate=0.9, buffer_bytes=64 << 10, iters=3,
               max_stressors=1)
+    # a pool no rung kernel can take (host memory, pinned_host here as
+    # on a chip) is refused and reported, never measured in HBM
+    n_keys = 2 * sum(coord.refusal("r", p, 64 << 10) is None
+                     for p in kw["pools"])
 
     # 1. a complete journaled probe sweep ...
     db1 = CurveDB(platform=coord.platform.name)
     keys1, st1 = refresh_surface_cells(coord, db1, journal=jpath, **kw)
-    assert len(keys1) == 4 and st1["resumed_ladders"] == 0
+    assert len(keys1) == n_keys and st1["resumed_ladders"] == 0
+    assert set(st1["refused"]) == {
+        f"{p}:{o}" for p in kw["pools"] for o in ("r", "l")
+        if coord.refusal(o, p, 64 << 10) is not None}
 
     # ... restores value-identically on the next run, executing nothing
     db2 = CurveDB(platform=coord.platform.name)
@@ -611,7 +618,7 @@ def test_probe_sweep_journal_resume_value_identical():
     res = rc.run(0.7, 0.9)
     assert not res.failed
     assert res.stats["resumed_ladders"] > 0, "resume re-measured all"
-    assert len(res.keys) == 4 and len(db3.surfaces) == 4
+    assert len(res.keys) == n_keys and len(db3.surfaces) == n_keys
     assert not os.path.exists(sidecar), "sidecar must be consumed"
 
     # 3. chaos faults: every dispatch attempt faults (rate 1.0 — the
@@ -627,6 +634,31 @@ def test_probe_sweep_journal_resume_value_identical():
                                 max_stressors=1)
     resf = rcf.run(0.7, 0.9)
     assert not resf.failed, resf.error
-    assert len(resf.keys) == 4
+    assert len(resf.keys) == n_keys
     assert resf.stats["faults_injected"] > 0, "chaos seed injected nothing"
     """)
+
+
+def test_make_host_mesh_refuses_more_devices_than_exist():
+    n = len(jax.devices())
+    assert make_host_mesh(1, 1).devices.size == 1
+    with pytest.raises(ValueError, match="needs"):
+        make_host_mesh(n + 1, 1)
+    with pytest.raises(ValueError, match="needs"):
+        make_host_mesh(1, n + 1)
+
+
+def test_place_caches_raises_for_a_pool_the_platform_lacks():
+    """The engine never keeps caches where they are because a pool is
+    not backed: an unknown pool raises."""
+    from repro.core.pools import PoolError
+    cfg = get_config("qwen2-1.5b").reduced()
+    mesh = make_host_mesh(1, 1)
+    rules = make_rules(cfg, mesh, global_batch=2, shape_kind="decode")
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    engine = eng.ServeEngine(cfg, params, rules, ServeConfig(),
+                             pool_mgr=PoolManager())
+    caches = {"k": jnp.zeros((2, 8))}
+    assert engine._place_caches(caches, "hbm")["k"].shape == (2, 8)
+    with pytest.raises(PoolError):
+        engine._place_caches(caches, "no-such-pool")

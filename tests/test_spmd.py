@@ -47,8 +47,8 @@ def test_checker_rejects_advisory_barrier():
         out = x * 2.0
         return out[None], ready
 
-    f = compat.shard_map(buggy, mesh=mesh, in_specs=(P("engine"),),
-                         out_specs=(P("engine"), P()))
+    f = jax.shard_map(buggy, mesh=mesh, in_specs=(P("engine"),),
+                      out_specs=(P("engine"), P()))
     assert not measured_region_is_fenced(f, np.ones((1, 8), np.float32))
 
 
@@ -120,8 +120,6 @@ def test_ladder_program_executes_with_monotone_clock():
     bracket every sample: stop strictly after start (the value-threaded
     device_clock fills must serialize), and consecutive samples must
     not overlap."""
-    if compat.device_clock_source() == "none":
-        pytest.skip("no in-dispatch timestamp source on this install")
     fns = [_spmd_branch_fn("r", None, ROWS, 4),
            _spmd_branch_fn("w", None, ROWS, 4)]
     K, S = 3, 2
@@ -159,8 +157,6 @@ def test_stacked_ladder_program_is_fenced_and_times_per_ladder():
     table = np.tile(np.asarray([[0], [1]], np.int32), (G, 1))
     _mesh, f = build_ladder_program(1, fns, table, samples=S)
     assert measured_region_is_fenced(f, *_operands(1))
-    if compat.device_clock_source() == "none":
-        return                       # structure verified; no stamps
     xf, xi = _operands(1)
     outs, t0s, t1s, xf2, xi2 = f(xf, xi)
     assert np.isfinite(np.asarray(outs)).all()
@@ -200,22 +196,20 @@ def test_stacked_checker_rejects_unfenced_stacked_scan():
                                       jnp.arange(G * K))
         return outs[None]
 
-    f = compat.shard_map(advisory_stack, mesh=mesh,
-                         in_specs=(P("engine"), P("engine")),
-                         out_specs=P("engine", None))
+    f = jax.shard_map(advisory_stack, mesh=mesh,
+                      in_specs=(P("engine"), P("engine")),
+                      out_specs=P("engine", None))
     assert not measured_region_is_fenced(f, *_operands(1))
 
 
 def test_fence_check_accepts_pretraced_jaxpr():
     """The single-trace AOT pipeline hands the checker an existing
-    ClosedJaxpr (compat.aot_trace) instead of paying a second
+    ClosedJaxpr (``jit(...).trace``) instead of paying a second
     make_jaxpr trace; both spellings must agree."""
     fns = [_spmd_branch_fn("r", None, ROWS, 2)]
     _mesh, f = build_rung_program(1, fns, [0])
     xf, xi = _operands(1)
-    traced = compat.aot_trace(f, xf, xi)
-    if traced is None:
-        pytest.skip("no AOT Traced stage on this install")
+    traced = f.trace(xf, xi)
     assert measured_region_is_fenced(f, jaxpr=traced.jaxpr)
     assert measured_region_is_fenced(f, xf, xi) \
         == measured_region_is_fenced(f, jaxpr=traced.jaxpr)
@@ -238,9 +232,9 @@ def test_ladder_checker_rejects_unfenced_scan():
         _c, outs = jax.lax.scan(step, jnp.float32(0.0), jnp.arange(3))
         return outs[None]
 
-    f = compat.shard_map(no_fence, mesh=mesh,
-                         in_specs=(P("engine"), P("engine")),
-                         out_specs=P("engine", None))
+    f = jax.shard_map(no_fence, mesh=mesh,
+                      in_specs=(P("engine"), P("engine")),
+                      out_specs=P("engine", None))
     assert not measured_region_is_fenced(f, *_operands(1))
 
     def advisory(xf, xi):
@@ -255,9 +249,9 @@ def test_ladder_checker_rejects_unfenced_scan():
                                       jnp.arange(3))
         return outs[None]
 
-    f2 = compat.shard_map(advisory, mesh=mesh,
-                          in_specs=(P("engine"), P("engine")),
-                          out_specs=P("engine", None))
+    f2 = jax.shard_map(advisory, mesh=mesh,
+                       in_specs=(P("engine"), P("engine")),
+                       out_specs=P("engine", None))
     assert not measured_region_is_fenced(f2, *_operands(1))
 
 
@@ -356,7 +350,7 @@ def test_packed_fence_subset_isolation():
                 xf = xf[0]
                 token = compat.psum_grouped(xf[0, 0], "engine",
                                             subsets)
-                xf, _t = compat.optimization_barrier(
+                xf, _t = jax.lax.optimization_barrier(
                     (xf + token * 0, token))
                 stolen = jax.lax.ppermute(
                     xf[0, 0], "engine",
@@ -364,10 +358,10 @@ def test_packed_fence_subset_isolation():
                 out = jnp.sum(xf) + stolen
                 done = compat.psum_grouped(out, "engine", subsets)
                 return (out + done * 0)[None]
-            f = compat.shard_map(per_engine, mesh=m,
-                                 in_specs=(P("engine"), P("engine")),
-                                 out_specs=P("engine"),
-                                 check_rep=False)
+            f = jax.shard_map(per_engine, mesh=m,
+                              in_specs=(P("engine"), P("engine")),
+                              out_specs=P("engine"),
+                              check_vma=False)
             return jax.jit(f)
         assert not measured_region_is_fenced(leaky(), xf, xi,
                                              subsets=subsets)
