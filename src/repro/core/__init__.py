@@ -4,7 +4,8 @@ devicetree   platform description + auto-detect (DTB analog)
 pools        Memory Pool Manager (genpool analog) + upool export
 workloads    Workload Library (Table-I access strategies)
 coordinator  Core Coordinator: scenario ladders + barrier sandwich
-counters     perf-counter analog (AOT cost analysis + wall timers)
+counters     perf-counter analog (AOT cost analysis, event names)
+spans        program spans on the profiler's host trace
 simulate     closed queueing-network model (contention at v5e scale)
 characterize performance curves + Little's-law MLP (CurveDB)
 placement    characterization-driven Placement Advisor (upool payoff)

@@ -40,6 +40,7 @@ from dataclasses import asdict, dataclass, field
 from itertools import product
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
+from repro.core import spans
 from repro.core.coordinator import CoreCoordinator, MatrixResult
 from repro.core.devicetree import Platform
 from repro.core.scenarios import (DEFAULT_INJECT_RATES, DEFAULT_RW_RATIOS,
@@ -687,6 +688,10 @@ def _stats_meta(result: MatrixResult, backend: str) -> Dict[str, Any]:
         "spmd_groups": result.stats.spmd_groups,
         "programs_built": result.stats.programs_built,
         "aot_compiles": result.stats.aot_compiles,
+        # JAX's backend compiles during the sweep: real XLA compiles,
+        # and executables loaded from the persistent compile cache
+        "xla_compiles": result.stats.xla_compiles,
+        "cache_loads": result.stats.cache_loads,
         # engine-subset width-packing (PR 7): ladders run side by side
         # on disjoint subsets, and the subset width they occupied
         "packed_ladders": result.stats.packed_ladders,
@@ -726,22 +731,24 @@ def curvedb_from_result(result: MatrixResult, platform: str, *,
     of 1-axis surfaces (no re-execution — callers that want both the
     runs and the DB pass their ``run_matrix`` result here instead of
     characterizing twice)."""
-    db = CurveDB(platform=platform)
-    db.meta = _stats_meta(result, backend)
-    for run in result.runs:
-        entry = _run_entry(run)
-        key = SurfaceKey.from_string(run.key)
-        prev = db.surfaces.get(key)
-        if prev is not None and {k: v for k, v in prev.provenance.items()
-                                 if k != "execution"} != entry:
-            # distinct scenarios/observers/buffers aliasing one key
-            # (e.g. shape tags rounding to the same spelling) must not
-            # silently overwrite curves
-            raise ValueError(
-                f"curve key collision: {run.key!r} produced by both "
-                f"{prev.provenance['name']!r} and {run.spec.name!r}")
-        entry["execution"] = run.execution
-        db.surfaces[key] = Surface.from_points(_run_points(run), entry)
+    with spans.span("curvedb", curves=len(result.runs)):
+        db = CurveDB(platform=platform)
+        db.meta = _stats_meta(result, backend)
+        for run in result.runs:
+            entry = _run_entry(run)
+            key = SurfaceKey.from_string(run.key)
+            prev = db.surfaces.get(key)
+            if prev is not None and {
+                    k: v for k, v in prev.provenance.items()
+                    if k != "execution"} != entry:
+                # distinct scenarios/observers/buffers aliasing one key
+                # (e.g. shape tags rounding to the same spelling) must
+                # not silently overwrite curves
+                raise ValueError(
+                    f"curve key collision: {run.key!r} produced by both "
+                    f"{prev.provenance['name']!r} and {run.spec.name!r}")
+            entry["execution"] = run.execution
+            db.surfaces[key] = Surface.from_points(_run_points(run), entry)
     return db
 
 

@@ -48,13 +48,15 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 
 from repro.core import simulate as sim
+from repro.core import spans
 from repro.core.devicetree import Platform, detect_platform
 from repro.core.exec import journal as exec_journal
 from repro.core.exec import plan as exec_plan
 from repro.core.exec import resilience as exec_resilience
 from repro.core.exec.assemble import (MatrixResult, ScenarioResult,
                                       ScenarioRun, assemble_runs)
-from repro.core.exec.dispatch import Dispatcher, DispatchStats
+from repro.core.exec.dispatch import (Dispatcher, DispatchStats,
+                                      count_compiles)
 from repro.core.exec.fence import (_shard_map_bodies,
                                    measured_region_is_fenced)
 from repro.core.exec.plan import effective_duty as _effective_duty
@@ -131,8 +133,6 @@ class ExperimentConfig:
     stress: ActivitySpec
     iters: int = 500
     scenarios: Optional[int] = None      # default: platform.n_engines
-    counters: Tuple[str, ...] = ("WALL_NS", "HLO_FLOPS", "HLO_BYTES",
-                                 "TRANSACTIONS", "NS_PER_TX")
 
 
 @dataclass
@@ -561,28 +561,29 @@ class CoreCoordinator:
         executed: Dict[Tuple[int, int], WorkloadResult] = {}
         fenced_by_triple: Dict[int, bool] = {}
         timing_by_triple: Dict[int, Dict[str, Any]] = {}
-        if self.backend in ("interpret", "tpu"):
-            # the measured pass runs the real Pallas kernel library
-            activity = "pallas"
-            measured = self._measure_triples(triples, batched, stats)
-        elif self.backend == "spmd":
-            activity = self.spmd_activity
-            executed, fenced_by_triple, timing_by_triple = \
-                self._execute_spmd(triples, stats, activity,
-                                   batched=batched, journal=journal)
-        else:
-            activity = "none"       # nothing executes on this backend
-
-        runs = assemble_runs(
-            triples, backend=self.backend, activity=activity,
-            stats=stats, depth_fn=self._ladder_depth,
-            model_fn=self._model_spec_scenario, measured=measured,
-            executed=executed, fenced_by_triple=fenced_by_triple,
-            timing_by_triple=timing_by_triple,
-            n_engines=(self._spmd_engines()
-                       if self.backend == "spmd" else None),
-            operand_kinds_fn=(self._operand_memory_kinds
-                              if self.backend == "spmd" else None))
+        with count_compiles(stats):
+            if self.backend in ("interpret", "tpu"):
+                # the measured pass runs the real Pallas kernel library
+                activity = "pallas"
+                measured = self._measure_triples(triples, batched, stats)
+            elif self.backend == "spmd":
+                activity = self.spmd_activity
+                executed, fenced_by_triple, timing_by_triple = \
+                    self._execute_spmd(triples, stats, activity,
+                                       batched=batched, journal=journal)
+            else:
+                activity = "none"       # nothing executes on this backend
+            with spans.span("assemble", ladders=len(triples)):
+                runs = assemble_runs(
+                    triples, backend=self.backend, activity=activity,
+                    stats=stats, depth_fn=self._ladder_depth,
+                    model_fn=self._model_spec_scenario, measured=measured,
+                    executed=executed, fenced_by_triple=fenced_by_triple,
+                    timing_by_triple=timing_by_triple,
+                    n_engines=(self._spmd_engines()
+                               if self.backend == "spmd" else None),
+                    operand_kinds_fn=(self._operand_memory_kinds
+                                      if self.backend == "spmd" else None))
         return MatrixResult(runs=runs, stats=stats)
 
     def _operand_memory_kinds(self, spec: ScenarioSpec,
@@ -603,24 +604,29 @@ class CoreCoordinator:
         measured: Dict[int, WorkloadResult] = {}
         if not batched:
             for i, (spec, obs, buf) in enumerate(triples):
-                wl = make_shaped_workload(
-                    obs.strategy, self.pools.pool(obs.pool), buf,
-                    obs.shape)
-                try:
-                    measured[i] = wl.run(spec.iters)
-                finally:
-                    wl.release()
+                with spans.measurement(strategy=obs.strategy, bytes=buf,
+                                       members=1, group=i):
+                    wl = make_shaped_workload(
+                        obs.strategy, self.pools.pool(obs.pool), buf,
+                        obs.shape)
+                    try:
+                        measured[i] = wl.run(spec.iters)
+                    finally:
+                        wl.release()
                 stats.measure_dispatches += 1
             return measured
 
-        groups = exec_plan.observer_groups(triples, self.pools)
-        for (strategy, shape, buf, iters, _kind, _vm), idxs in \
-                groups.items():
+        with spans.span("plan", ladders=len(triples)):
+            groups = exec_plan.observer_groups(triples, self.pools)
+        for group, ((strategy, shape, buf, iters, _kind, _vm), idxs) in \
+                enumerate(groups.items()):
             member_pools = [self.pools.pool(triples[i][1].pool)
                             for i in idxs]
-            results, dispatches = measure_group(
-                strategy, member_pools[0], buf, len(idxs), iters,
-                shape=shape, member_pools=member_pools)
+            with spans.measurement(strategy=strategy, bytes=buf,
+                                   members=len(idxs), group=group):
+                results, dispatches = measure_group(
+                    strategy, member_pools[0], buf, len(idxs), iters,
+                    shape=shape, member_pools=member_pools, stats=stats)
             stats.measure_dispatches += dispatches
             for i, res in zip(idxs, results):
                 measured[i] = res

@@ -1,4 +1,4 @@
-"""Performance-counter analog — AOT program analysis + wall-clock timers.
+"""Performance-counter analog — AOT program analysis.
 
 MEMSCOPE samples ARMv8 PMU events around the measured region.  A TPU
 exposes no user PMU, but an AOT-compiled XLA program is *fully analysable
@@ -12,12 +12,9 @@ Six "counters" per activity, mirroring the 6-counter/core ARM PMU limit.
 """
 from __future__ import annotations
 
-import time
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Tuple
 
 import jax
-import numpy as np
 
 
 MAX_COUNTERS = 6   # ARM PMU exposes 6 programmable counters per core
@@ -31,17 +28,6 @@ EVENTS = (
     "NS_PER_TX",        # wall / transactions
     "PEAK_MEMORY",      # memory_analysis temp+arg bytes
 )
-
-
-@dataclass
-class CounterSample:
-    events: Dict[str, float] = field(default_factory=dict)
-
-    def __getitem__(self, k: str) -> float:
-        return self.events[k]
-
-    def as_row(self) -> str:
-        return " ".join(f"{k}={v:.4g}" for k, v in self.events.items())
 
 
 def select_events(names: Tuple[str, ...]) -> Tuple[str, ...]:
@@ -69,31 +55,3 @@ def cost_of(fn: Callable, *args, **kw) -> Dict[str, float]:
             getattr(mem, "argument_size_in_bytes", 0) +
             getattr(mem, "output_size_in_bytes", 0))
     return {"HLO_FLOPS": flops, "HLO_BYTES": byts, "PEAK_MEMORY": peak}
-
-
-def sample(fn: Callable, *args, iters: int = 10, line_bytes: int = 512,
-           events: Tuple[str, ...] = EVENTS[:MAX_COUNTERS],
-           **kw) -> CounterSample:
-    """Run fn under the selected counters (compile excluded from timing)."""
-    events = select_events(tuple(events))
-    static = cost_of(fn, *args, **kw)
-    jfn = jax.jit(fn)
-    jfn(*args, **kw).block_until_ready()
-    t0 = time.perf_counter_ns()
-    for _ in range(iters):
-        out = jfn(*args, **kw)
-    jax.tree.map(
-        lambda x: x.block_until_ready() if hasattr(x, "block_until_ready")
-        else x, out)
-    wall = (time.perf_counter_ns() - t0) / iters
-
-    tx = static["HLO_BYTES"] / line_bytes
-    all_events = {
-        "WALL_NS": wall,
-        "HLO_FLOPS": static["HLO_FLOPS"],
-        "HLO_BYTES": static["HLO_BYTES"],
-        "TRANSACTIONS": tx,
-        "NS_PER_TX": wall / tx if tx else 0.0,
-        "PEAK_MEMORY": static["PEAK_MEMORY"],
-    }
-    return CounterSample({k: all_events[k] for k in events})

@@ -9,11 +9,12 @@ ladder's stamp pairs back to per-rung elapsed medians.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import time as _time
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -60,13 +61,18 @@ class DispatchStats:
     # sweep-level megabatching: distinct role-program signatures this
     # run stacked ladders under (0 on the non-batched paths)
     spmd_groups: int = 0
-    # spmd programs actually traced + compiled this run (cache
-    # misses), and how many of those went through the AOT
+    # programs built this run: spmd programs traced + compiled (cache
+    # misses), and the measured pass's fresh jit(vmap(...)) programs;
+    # and how many spmd programs went through the AOT
     # lower().compile() pipeline — together with
     # host_sync_dispatches these make the dispatch-vs-compile
     # attribution in BENCH_spmd.json explicit
     programs_built: int = 0
     aot_compiles: int = 0
+    # JAX's backend compiles during run_matrix (count_compiles): real
+    # XLA compiles, and executables loaded from the persistent cache
+    xla_compiles: int = 0
+    cache_loads: int = 0
     # engine-subset width-packing: ladders that ran side by side on a
     # disjoint engine subset of a packed dispatch, and the widest
     # subset used (0 when nothing packed this run)
@@ -92,6 +98,36 @@ class DispatchStats:
         one-sync-per-group equalities only hold then."""
         return not (self.faults_injected or self.retried_dispatches
                     or self.degraded_ladders or self.noisy_remeasures)
+
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+@contextlib.contextmanager
+def count_compiles(stats: DispatchStats) -> Iterator[None]:
+    """Count JAX's backend compiles while entered into ``stats``.  JAX
+    records one backend-compile event per executable it compiles or
+    loads from the persistent cache, and a cache-hit event inside it
+    for each load, so the loads are the hits and the XLA compiles the
+    rest.  The listeners are JAX's process-wide ones: compiles of
+    another thread in the same interval count too."""
+    seen = {BACKEND_COMPILE_EVENT: 0, CACHE_HIT_EVENT: 0}
+
+    def on_event(event: str, *_args, **_kw) -> None:
+        if event in seen:
+            seen[event] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+        jax.monitoring.unregister_event_duration_listener(on_event)
+        stats.cache_loads += seen[CACHE_HIT_EVENT]
+        stats.xla_compiles += (seen[BACKEND_COMPILE_EVENT]
+                               - seen[CACHE_HIT_EVENT])
 
 
 class ProgramCache:

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.devicetree import MemoryNode
 from repro.core.pools import Allocation, MemoryPool
 from repro.kernels import ops
@@ -88,7 +89,8 @@ class Workload:
 
     def release(self) -> None:
         if self.alloc is not None:
-            self.pool.free(self.alloc)
+            with spans.span("inputs"):
+                self.pool.free(self.alloc)
             self.alloc = None
 
     @property
@@ -157,18 +159,19 @@ def make_shaped_workload(strategy: str, pool: MemoryPool, buffer_bytes: int,
     strided chase, and bursty shapes wrap the base workload with
     duty-cycled accounting (the off phase is pure idle, so the
     time-averaged bandwidth scales by the duty cycle)."""
-    if shape is None or getattr(shape, "is_steady", True):
-        return make_workload(strategy, pool, buffer_bytes, **kw)
-    if shape.kind == "mixed":
-        return make_workload("b", pool, buffer_bytes,
-                             read_fraction=shape.read_fraction, **kw)
-    if shape.kind == "strided":
-        return make_workload("t", pool, buffer_bytes,
-                             stride=shape.stride, **kw)
-    if shape.kind == "burst":
-        wl = make_workload(strategy, pool, buffer_bytes, **kw)
-        return _duty_cycled(wl, shape.duty_cycle)
-    raise KeyError(f"unknown traffic shape kind {shape.kind!r}")
+    with spans.span("inputs"):
+        if shape is None or getattr(shape, "is_steady", True):
+            return make_workload(strategy, pool, buffer_bytes, **kw)
+        if shape.kind == "mixed":
+            return make_workload("b", pool, buffer_bytes,
+                                 read_fraction=shape.read_fraction, **kw)
+        if shape.kind == "strided":
+            return make_workload("t", pool, buffer_bytes,
+                                 stride=shape.stride, **kw)
+        if shape.kind == "burst":
+            wl = make_workload(strategy, pool, buffer_bytes, **kw)
+            return _duty_cycled(wl, shape.duty_cycle)
+        raise KeyError(f"unknown traffic shape kind {shape.kind!r}")
 
 
 def _duty_cycled(wl: Workload, duty: float) -> Workload:
@@ -208,7 +211,8 @@ _BATCH_BYTES_CAP = 1 << 30
 def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
                   n_members: int, iters: int, *, shape=None,
                   seeds: Optional[list] = None,
-                  member_pools: Optional[list] = None) -> Tuple[list, int]:
+                  member_pools: Optional[list] = None,
+                  stats=None) -> Tuple[list, int]:
     """Measure ``n_members`` same-signature observers with jit'd
     ``vmap`` passes over the stacked member buffers (chases keep
     per-member chains, so different seeds/strides stay distinct).
@@ -219,6 +223,9 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
     :meth:`MemoryPool.effective_memory_kind`, so this never stacks
     buffers that would really live in different memories).  Each
     member's result is labeled with its own pool name.
+
+    ``stats`` (the coordinator's ``DispatchStats``, optional) counts
+    each program built for a chunk in ``programs_built``.
 
     Returns ``(results, n_dispatches)``.  Normally one dispatch covers
     the whole group; groups whose stacked footprint would exceed the
@@ -247,7 +254,8 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
                    else list(range(start, start + g))),
             pool_names=([p.node.name for p in
                          member_pools[start:start + g]]
-                        if member_pools is not None else None)))
+                        if member_pools is not None else None),
+            stats=stats))
         dispatches += 1
     return results, dispatches
 
@@ -255,7 +263,7 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
 def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
                    n_members: int, iters: int, *, shape=None,
                    seeds: Optional[list] = None,
-                   pool_names: Optional[list] = None) -> list:
+                   pool_names: Optional[list] = None, stats=None) -> list:
     rows = _rows(buffer_bytes)
     g = n_members
     names = pool_names or [pool.node.name] * g
@@ -268,12 +276,15 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
 
     if strat in _VMAP_CHASES:
         seeds = seeds or list(range(g))
-        bufs = pool.place(jnp.asarray(
-            np.stack([ops.chain_buffer(rows, s) for s in seeds])))
+        with spans.span("inputs"):
+            bufs = pool.place(jnp.asarray(
+                np.stack([ops.chain_buffer(rows, s) for s in seeds])))
         steps = chase_steps(rows)
         if strat == "l" and vmem:
             batched = jax.jit(jax.vmap(
                 lambda b: ops.chase_vmem(b, n_steps=steps)))
+            if stats is not None:
+                stats.programs_built += 1
         else:
             # the HBM chase walks a stacked buffer's chains one after
             # another inside one kernel
@@ -283,17 +294,20 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
         # (test_batched_chase_latency_matches_naive guards it)
         per = (t / g) / duty
         kind = bufs.sharding.memory_kind
+        with spans.span("readback"):
+            ends = np.asarray(out)
         return [WorkloadResult(strat, name, buffer_bytes, iters,
                                rows * LINE_BYTES, per, transactions=steps,
                                checksum=float(c), memory_kind=kind,
                                chain_seed=sd)
-                for name, c, sd in zip(names, np.asarray(out), seeds)]
+                for name, c, sd in zip(names, ends, seeds)]
 
     if strat in _VMAP_READS:
         # every member streams the same content, so one plain reference
         # checks every member's checksum
-        x = pool.place(jnp.broadcast_to(
-            bw_buffer_init((rows, LANE), jnp.float32), (g, rows, LANE)))
+        with spans.span("inputs"):
+            x = pool.place(jnp.broadcast_to(
+                bw_buffer_init((rows, LANE), jnp.float32), (g, rows, LANE)))
         scale = 1.0
         useful = rows * LINE_BYTES
         if strat == "b":
@@ -317,6 +331,8 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
         else:
             batched = jax.jit(jax.vmap(
                 lambda a: ops.stream_read(a, block_rows=blk)))
+        if stats is not None:
+            stats.programs_built += 1
         t, out = _timed(batched, x, iters=iters)
         t *= scale
         per = (t / g) / duty
@@ -343,13 +359,14 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
 def _member_checksums(strat: str, out) -> np.ndarray:
     """Per-member checksum of a vmapped stream pass: the read sum, plus
     the destination's sum where the kernel writes one."""
-    if strat == "b":
-        acc, written = out
-        return (np.asarray(acc, np.float64)
-                + np.asarray(jnp.sum(written, axis=(1, 2)), np.float64))
-    if strat in ("c", "x"):
-        return np.asarray(jnp.sum(out, axis=(1, 2)), np.float64)
-    return np.asarray(out, np.float64)
+    with spans.span("readback"):
+        if strat == "b":
+            acc, written = out
+            return (np.asarray(acc, np.float64)
+                    + np.asarray(jnp.sum(written, axis=(1, 2)), np.float64))
+        if strat in ("c", "x"):
+            return np.asarray(jnp.sum(out, axis=(1, 2)), np.float64)
+        return np.asarray(out, np.float64)
 
 
 def chase_steps(rows: int) -> int:
@@ -375,14 +392,16 @@ def rows_for(buffer_bytes: int) -> int:
 def _timed(fn, *args, iters: int, **kw) -> Tuple[float, Any]:
     """Median-of-3 wall time for `iters` back-to-back calls (ns), and
     the last call's output."""
-    out = jax.block_until_ready(fn(*args, **kw))   # compile + warm
+    with spans.span("build"):
+        out = jax.block_until_ready(fn(*args, **kw))   # compile + warm
     samples = []
-    for _ in range(3):
-        t0 = time.perf_counter_ns()
-        for _ in range(iters):
-            out = fn(*args, **kw)
-        jax.block_until_ready(out)
-        samples.append((time.perf_counter_ns() - t0) / iters)
+    with spans.span("timed"):
+        for _ in range(3):
+            t0 = time.perf_counter_ns()
+            for _ in range(iters):
+                out = fn(*args, **kw)
+            jax.block_until_ready(out)
+            samples.append((time.perf_counter_ns() - t0) / iters)
     return float(np.median(samples)), out
 
 
@@ -424,16 +443,17 @@ def refusal(strategy: str, pool: MemoryPool,
 
 
 def _done(strategy: str, pool: MemoryPool, buffer_bytes: int, iters: int,
-          nbytes: int, t: float, out, *, src=None, transactions: int = 0,
-          checksum: Optional[float] = None) -> WorkloadResult:
+          nbytes: int, t: float, out, *, src=None, transactions: int = 0
+          ) -> WorkloadResult:
     """One registry workload's result, stamped with the kernel's output
-    checksum and the memory kind of its operand (``src``) or, for pure
-    writes, of its destination."""
+    checksum (the sum over every array of ``out``) and the memory kind
+    of its operand (``src``) or, for pure writes, of its destination."""
     where = src if src is not None else out
+    with spans.span("readback"):
+        checksum = sum(float(jnp.sum(o)) for o in jax.tree.leaves(out))
     return WorkloadResult(
         strategy, pool.node.name, buffer_bytes, iters, nbytes, t,
-        transactions,
-        checksum=float(jnp.sum(out)) if checksum is None else checksum,
+        transactions, checksum=checksum,
         memory_kind=where.sharding.memory_kind)
 
 
@@ -569,11 +589,11 @@ def _mk_mixed(pool, buffer_bytes, *, read_fraction: float = 0.5, **kw):
     rf = max(0.0, min(1.0, read_fraction))
 
     def run(iters):
-        t, (acc, written) = _timed(ops.stream_mixed, x, read_fraction=rf,
-                                   block_rows=min(512, rows), iters=iters)
+        t, out = _timed(ops.stream_mixed, x, read_fraction=rf,
+                        block_rows=min(512, rows), iters=iters)
+        # the checksum is the read sum plus the sum of what was written
         return _done("b", pool, buffer_bytes, iters,
-                     rows * LINE_BYTES * iters, t * iters, None, src=x,
-                     checksum=float(acc) + float(jnp.sum(written)))
+                     rows * LINE_BYTES * iters, t * iters, out, src=x)
 
     return Workload("b", pool, buffer_bytes,
                     f"mixed r/w stream (rf={rf:g})", run, alloc)
