@@ -217,6 +217,9 @@ class CoreCoordinator:
         self.fault_spec = exec_resilience.resolve_faults(faults)
         self.retry_policy = retry or exec_resilience.RetryPolicy()
         self.quality_gate = exec_resilience.resolve_gate(quality)
+        # the batched measured pass's jit(vmap(...)) programs, kept for
+        # the coordinator's lifetime (workloads.measure_group)
+        self._measured_programs: Dict[Tuple, Any] = {}
         # stage 3 of the exec pipeline: program/operand LRU, AOT
         # compile, dispatch + decode
         self._dispatcher = Dispatcher(self.spmd_cache_cap, spmd_samples,
@@ -626,7 +629,8 @@ class CoreCoordinator:
                                    members=len(idxs), group=group):
                 results, dispatches = measure_group(
                     strategy, member_pools[0], buf, len(idxs), iters,
-                    shape=shape, member_pools=member_pools, stats=stats)
+                    shape=shape, member_pools=member_pools, stats=stats,
+                    programs=self._measured_programs)
             stats.measure_dispatches += dispatches
             for i, res in zip(idxs, results):
                 measured[i] = res

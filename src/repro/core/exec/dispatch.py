@@ -54,15 +54,17 @@ class DispatchStats:
     # (warm + 3 timed); benchmarks/perf_harness.py holds each
     # contender to its number
     host_sync_dispatches: int = 0
-    # compiled spmd programs (+ placed operands) reused from the
-    # coordinator-level LRU cache — across rungs, ladders, AND
-    # back-to-back run_matrix calls on one coordinator
+    # programs reused from the coordinator: compiled spmd programs
+    # (+ placed operands) from its LRU cache — across rungs, ladders,
+    # AND back-to-back run_matrix calls — and the measured pass's
+    # jit(vmap(...)) programs it keeps (workloads.measure_group)
     program_cache_hits: int = 0
     # sweep-level megabatching: distinct role-program signatures this
     # run stacked ladders under (0 on the non-batched paths)
     spmd_groups: int = 0
-    # programs built this run: spmd programs traced + compiled (cache
-    # misses), and the measured pass's fresh jit(vmap(...)) programs;
+    # programs built this run (misses of the coordinator's programs):
+    # spmd programs traced + compiled, and the measured pass's
+    # jit(vmap(...)) programs the coordinator did not hold yet;
     # and how many spmd programs went through the AOT
     # lower().compile() pipeline — together with
     # host_sync_dispatches these make the dispatch-vs-compile

@@ -212,7 +212,8 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
                   n_members: int, iters: int, *, shape=None,
                   seeds: Optional[list] = None,
                   member_pools: Optional[list] = None,
-                  stats=None) -> Tuple[list, int]:
+                  stats=None, programs: Optional[dict] = None
+                  ) -> Tuple[list, int]:
     """Measure ``n_members`` same-signature observers with jit'd
     ``vmap`` passes over the stacked member buffers (chases keep
     per-member chains, so different seeds/strides stay distinct).
@@ -224,8 +225,13 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
     buffers that would really live in different memories).  Each
     member's result is labeled with its own pool name.
 
+    ``programs`` (optional) is the caller's table of the measured
+    pass's ``jit(vmap(...))`` programs (see :func:`_vmapped`): a
+    coordinator passes its own, so back-to-back sweeps build each
+    program once.  Without it every chunk builds its program afresh.
     ``stats`` (the coordinator's ``DispatchStats``, optional) counts
-    each program built for a chunk in ``programs_built``.
+    the programs built in ``programs_built`` and those found in
+    ``programs`` in ``program_cache_hits``.
 
     Returns ``(results, n_dispatches)``.  Normally one dispatch covers
     the whole group; groups whose stacked footprint would exceed the
@@ -255,7 +261,7 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
             pool_names=([p.node.name for p in
                          member_pools[start:start + g]]
                         if member_pools is not None else None),
-            stats=stats))
+            stats=stats, programs=programs))
         dispatches += 1
     return results, dispatches
 
@@ -263,7 +269,8 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
 def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
                    n_members: int, iters: int, *, shape=None,
                    seeds: Optional[list] = None,
-                   pool_names: Optional[list] = None, stats=None) -> list:
+                   pool_names: Optional[list] = None, stats=None,
+                   programs: Optional[dict] = None) -> list:
     rows = _rows(buffer_bytes)
     g = n_members
     names = pool_names or [pool.node.name] * g
@@ -281,10 +288,8 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
                 np.stack([ops.chain_buffer(rows, s) for s in seeds])))
         steps = chase_steps(rows)
         if strat == "l" and vmem:
-            batched = jax.jit(jax.vmap(
-                lambda b: ops.chase_vmem(b, n_steps=steps)))
-            if stats is not None:
-                stats.programs_built += 1
+            batched = _vmapped(ops.chase_vmem, bufs, programs, stats,
+                               n_steps=steps)
         else:
             # the HBM chase walks a stacked buffer's chains one after
             # another inside one kernel
@@ -313,26 +318,20 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
         if strat == "b":
             rf = (shape.read_fraction
                   if shape is not None and shape.kind == "mixed" else 0.5)
-            batched = jax.jit(jax.vmap(
-                lambda a: ops.stream_mixed(a, read_fraction=rf,
-                                           block_rows=blk)))
+            kernel, static = ops.stream_mixed, dict(read_fraction=rf,
+                                                    block_rows=blk)
         elif strat == "c":
-            batched = jax.jit(jax.vmap(
-                lambda a: ops.stream_copy(a, block_rows=blk)))
+            kernel, static = ops.stream_copy, dict(block_rows=blk)
             useful = 2 * rows * LINE_BYTES
         elif strat == "x":
-            batched = jax.jit(jax.vmap(
-                lambda a: ops.stream_rmw(a, block_rows=blk)))
+            kernel, static = ops.stream_rmw, dict(block_rows=blk)
             useful = 2 * rows * LINE_BYTES
         elif vmem and strat == "r":
-            batched = jax.jit(jax.vmap(
-                lambda a: ops.vmem_read(a, repeats=8)))
+            kernel, static = ops.vmem_read, dict(repeats=8)
             scale = 1.0 / 8.0               # 8 on-chip re-reads per call
         else:
-            batched = jax.jit(jax.vmap(
-                lambda a: ops.stream_read(a, block_rows=blk)))
-        if stats is not None:
-            stats.programs_built += 1
+            kernel, static = ops.stream_read, dict(block_rows=blk)
+        batched = _vmapped(kernel, x, programs, stats, **static)
         t, out = _timed(batched, x, iters=iters)
         t *= scale
         per = (t / g) / duty
@@ -354,6 +353,32 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
     import dataclasses
     return [res if name == res.pool else dataclasses.replace(res, pool=name)
             for name in names]
+
+
+def _vmapped(kernel, operand, programs: Optional[dict], stats,
+             **static) -> Callable:
+    """The ``jit(vmap(kernel))`` program over the stacked ``operand``.
+
+    ``programs`` keeps one program per kernel, static arguments, and
+    operand shape, dtype and memory kind: everything that defines the
+    compiled program, so a hit traces, lowers and compiles nothing.
+    Strategies that run the same kernel at the same arguments share
+    one entry.  A miss builds the program and, given ``programs``,
+    keeps it.  ``stats`` counts misses in ``programs_built`` and hits
+    in ``program_cache_hits``."""
+    key = (kernel, tuple(sorted(static.items())), operand.shape,
+           operand.dtype, operand.sharding.memory_kind)
+    fn = programs.get(key) if programs is not None else None
+    if fn is not None:
+        if stats is not None:
+            stats.program_cache_hits += 1
+        return fn
+    fn = jax.jit(jax.vmap(functools.partial(kernel, **static)))
+    if programs is not None:
+        programs[key] = fn
+    if stats is not None:
+        stats.programs_built += 1
+    return fn
 
 
 def _member_checksums(strat: str, out) -> np.ndarray:

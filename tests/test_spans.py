@@ -15,17 +15,22 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
 from repro import compat
 from repro.core import spans
 from repro.core.characterize import characterize_specs, curvedb_from_result
 from repro.core.coordinator import CoreCoordinator
+from repro.core.scenarios import (ObserverSpec, ScenarioSpec, StressorSpec,
+                                  TrafficShape)
+from repro.core.workloads import VMEM_KERNEL_BYTES
 
 OBSERVERS = ("r", "s", "w", "l")
-# observers whose group is measured by a fresh jit(vmap(...)) program
-# (at 64 KiB the read and the chase are VMEM-resident); "w" runs the
-# registry workload, whose kernel is jitted once per process
+# observers whose group is measured by a jit(vmap(...)) program that a
+# fresh coordinator builds (at 64 KiB the read and the chase are
+# VMEM-resident, so the three programs differ); "w" runs the registry
+# workload, whose kernel is jitted once per process
 VMAPPED = ("r", "s", "l")
 MEASUREMENT_ARGS = {"strategy", "bytes", "members", "group"}
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -121,7 +126,7 @@ def test_compile_counters(tmp_path, batched):
     st = result.stats
     assert st.programs_built == (len(VMAPPED) if batched else 0)
     assert st.xla_compiles + st.cache_loads == n_compile_events
-    # a fresh jit(vmap(...)) program is compiled or loaded when built
+    # a program the coordinator builds is compiled or loaded
     assert n_compile_events >= st.programs_built
     for key in ("programs_built", "xla_compiles", "cache_loads"):
         assert db.meta[key] == getattr(st, key), key
@@ -159,8 +164,9 @@ for _ in range(2):
 
 def test_cache_loads_are_split_from_compiles(tmp_path):
     """With the persistent compile cache on, the first sweep writes its
-    fresh programs to it and a second sweep, whose programs are fresh
-    again, loads each of them: no XLA compile, one load a program."""
+    programs to it and a second sweep on a fresh coordinator, which
+    builds its programs again, loads each of them: no XLA compile, one
+    load a program."""
     env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env[compat.CACHE_ENV] = str(tmp_path)
     r = subprocess.run([sys.executable, "-c", _CACHED_SWEEPS % (OBSERVERS,)],
@@ -173,6 +179,107 @@ def test_cache_loads_are_split_from_compiles(tmp_path):
     assert second["xla_compiles"] == 0
     assert second["cache_loads"] == second["programs_built"] == \
         second["events"]
+
+
+def _specs(coord, observers, buffer_bytes=64 << 10):
+    specs, refused = characterize_specs(
+        coord, pools=["hbm"], buffer_bytes=buffer_bytes,
+        obs_strategies=observers, stress_strategies=("w",), iters=2)
+    assert not refused
+    return specs
+
+
+def _measured(result):
+    """Each curve's key with the checksum and operand memory kind of
+    every point."""
+    return [(run.key, [(sc.main.checksum, sc.main.memory_kind)
+                       for sc in run.scenarios]) for run in result.runs]
+
+
+def test_second_sweep_reuses_the_measured_programs():
+    """Back-to-back batched sweeps on one coordinator: the second builds
+    no program, traces, compiles and loads nothing, and measures the
+    same checksums in the same memory as the first."""
+    coord = CoreCoordinator(backend="interpret")
+    specs = _specs(coord, OBSERVERS)
+    first = coord.run_matrix(specs, batched=True)
+    second = coord.run_matrix(specs, batched=True)
+    assert first.stats.programs_built == len(VMAPPED)
+    st = second.stats
+    assert st.programs_built == 0
+    assert st.program_cache_hits == first.stats.programs_built
+    assert st.xla_compiles + st.cache_loads == 0
+    assert _measured(second) == _measured(first)
+    meta = curvedb_from_result(second, coord.platform.name,
+                               backend=coord.backend).meta
+    assert meta["programs_built"] == 0
+    assert meta["program_cache_hits"] == len(VMAPPED)
+
+
+def _mixed_checksum(buffer_bytes, read_fraction):
+    """The mixed stream's checksum from numpy: the sum of the blocks it
+    reads of the sequential-integer buffer, plus one for every element
+    of the blocks it writes (the kernel keeps >= 8 blocks, and one of
+    each kind)."""
+    rows = buffer_bytes // 512
+    blk = max(b for b in range(1, rows // 8 + 1) if rows % b == 0)
+    nb = rows // blk
+    n_r = max(1, min(nb - 1, int(round(nb * read_fraction))))
+    x = np.arange(rows * 128, dtype=np.float64)
+    return float(x[:n_r * blk * 128].sum() + (nb - n_r) * blk * 128)
+
+
+def _spec(name, observer):
+    return ScenarioSpec(name, observer, (StressorSpec("w", "hbm", 64 << 10),),
+                        iters=2)
+
+
+def _key_read_fractions(coord):
+    """Two read fractions of the mixed stream: two programs, each
+    checked against its own fraction's reference."""
+    buf = 64 << 10
+    fractions = (0.5, 0.75)
+    specs = [_spec(f"b{rf}", ObserverSpec(
+        "b", "hbm", (buf,), TrafficShape(kind="mixed", read_fraction=rf)))
+        for rf in fractions]
+    result = coord.run_matrix(specs, batched=True)
+    for run, rf in zip(result.runs, fractions):
+        assert run.scenarios[0].main.checksum == \
+            pytest.approx(_mixed_checksum(buf, rf), rel=1e-6)
+    return result, 2, 0
+
+
+def _key_ladder_sizes(coord):
+    """One observer swept over two buffer sizes: two programs."""
+    spec = _spec("s", ObserverSpec("s", "hbm", (64 << 10, 128 << 10)))
+    return coord.run_matrix([spec], batched=True), 2, 0
+
+
+def _key_stream_reads_share(coord):
+    """Above the VMEM-kernel size "r" and "s" both run the HBM stream
+    read at the same block: one program for both groups."""
+    specs = _specs(coord, ("r", "s"), buffer_bytes=VMEM_KERNEL_BYTES + 512)
+    return coord.run_matrix(specs, batched=True), 1, 1
+
+
+def _key_fresh_coordinator(coord):
+    """Another coordinator holds none of this one's programs."""
+    specs = _specs(coord, OBSERVERS)
+    CoreCoordinator(backend="interpret").run_matrix(specs, batched=True)
+    return coord.run_matrix(specs, batched=True), len(VMAPPED), 0
+
+
+@pytest.mark.parametrize("case", [_key_read_fractions, _key_ladder_sizes,
+                                  _key_stream_reads_share,
+                                  _key_fresh_coordinator],
+                         ids=lambda f: f.__name__[len("_key_"):])
+def test_measured_program_key(case):
+    """What the key of the coordinator's measured-pass programs keeps
+    apart (static arguments, operand shapes, coordinators) and what it
+    shares (one kernel at the same arguments, whatever the strategy)."""
+    result, built, hits = case(CoreCoordinator(backend="interpret"))
+    assert result.stats.programs_built == built
+    assert result.stats.program_cache_hits == hits
 
 
 def test_span_names_are_the_programs_own():
