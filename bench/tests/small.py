@@ -21,18 +21,16 @@ def char_cell(traffic: str, sizes=(256 << 10,)) -> harness.Cell:
 
 
 def serve_cell() -> harness.Cell:
-    """The qwen2 serving configuration under the ``qwen2-deck`` traffic
-    mix, built from those files alone, with its end-to-end metrics."""
-    config = harness.load_json("configs", "qwen2-1.5b")
-    traffic = harness.load_json("traffic", "qwen2-deck")
-    spec = {"name": "serve.qwen2-deck", "config": config["name"],
-            "traffic": traffic["name"], "chips": 1}
-    config = dict(config, **SERVE_SIZES, advisor=dict(
-        config["advisor"], buffer_bytes=256 << 10, iters=2))
-    traffic = dict(traffic, deck=[[32, 2], [64, 1]], batch=4, new_tokens=8)
-    return harness.Cell(spec["name"], spec, config, traffic,
-                        {"gen_tok_s": "tokens/s", "call_p90_ms": "ms",
-                         "setup_s": "s"}, {})
+    """The ``serve.qwen2-deck`` cell, from its files and
+    ``BENCHMARK.json``, with a small qwen2-shaped model, a short deck of
+    short prompts and a small characterization for the advisor; its
+    arrival rate is the traffic file's."""
+    cell = harness.load_cell("serve.qwen2-deck")
+    cell.config = dict(cell.config, **SERVE_SIZES, advisor=dict(
+        cell.config["advisor"], buffer_bytes=256 << 10, iters=2))
+    cell.traffic = dict(cell.traffic, deck=[[32, 2], [64, 1]], batch=4,
+                        new_tokens=8)
+    return cell
 
 
 def program_config(cfg: dict):

@@ -151,3 +151,75 @@ def test_recorded_tpu_trace():
     for name in ("stream_read_roofline", "device_idle.curves"):
         value = harness.load_module("metrics", name).read(ctx)
         assert value is not None and 0 < value < 100, (name, value)
+
+
+def test_idle_in_call_lies_within_the_device_idle_share():
+    """``idle_in_call.serve`` counts the device's idle time inside the
+    ``bench.generate`` spans only, so it is at most ``device_idle.serve``,
+    which also counts the wait for the next call to be due."""
+    from bench import harness
+    ops = [_ev("fusion.1", 100, 300), _ev("fusion.2", 500, 600)]
+    host = [_ev(tr.WINDOW_SPAN, 0, 1000), _ev("bench.generate", 50, 450),
+            _ev("bench.generate", 480, 700), _ev("bench.wait", 700, 1000),
+            _ev("PjitFunction(scan)", 300, 450)]
+    t = tr.Trace([tr.Device("/device:TPU:0", ops, [])], host, (0, 1000))
+    ctx = harness.RunContext(harness.load_cell("serve.qwen2-deck"), 1,
+                             True, "TPU v5 lite", 1, trace=t)
+    total = harness.load_module("metrics", "device_idle.serve").read(ctx)
+    in_call = harness.load_module("metrics", "idle_in_call.serve").read(ctx)
+    # idle [0, 100], [300, 500], [600, 1000]; inside the calls [50, 100],
+    # [300, 450], [480, 500], [600, 700]
+    assert total == pytest.approx(70.0)
+    assert in_call == pytest.approx(32.0)
+    assert in_call <= total
+    # the recorded CPU trace has calls but no device plane; a trace with
+    # a device and no call has nothing to read either
+    ctx.trace = tr.load(os.path.join(DATA, "cpu_small.xplane.pb"))
+    for name in ("device_idle.serve", "idle_in_call.serve"):
+        assert harness.load_module("metrics", name).read(ctx) is None
+    ctx.trace = tr.Trace(t.devices, host[:1], (0, 1000))
+    assert harness.load_module("metrics", "idle_in_call.serve").read(
+        ctx) is None
+
+
+def test_recorded_tpu_serving_trace():
+    """A ``--trace 1`` run of ``serve.qwen2-deck`` recorded on a TPU v5e
+    (3 calls, seed 3160000013).  To keep the file small, each operation
+    nested inside another (the decode steps inside the scan's ``while``),
+    the operations' own stats, the async line and the HLO metadata plane
+    were left out; the busy union is unchanged by that.  The prompt
+    lengths are dealt again by the driver from the seed.  Every reader
+    of the cell finds something and reads what the run printed."""
+    import numpy as np
+    from bench import harness
+    from bench.drivers import serve
+    t = tr.load(os.path.join(DATA, "tpu_serve_qwen2_deck.xplane.pb"))
+    assert [d.name for d in t.devices] == ["/device:TPU:0"]
+    assert len([h for h in t.host if h.name == "bench.generate"]) == 3
+    cell = harness.load_cell("serve.qwen2-deck")
+    state = serve.State(None, cell.config, cell.traffic,
+                        np.random.default_rng(3160000013))
+    for length in (256, 512, 1024, 2048):      # set-up's warm-up calls
+        serve._prompts(state, length)
+    lens = []
+    for _ in range(3):
+        lens.append(serve._prompt_len(state))
+        serve._prompts(state, lens[-1])
+    assert lens == [256, 512, 512]
+    ctx = harness.RunContext(
+        cell, 3160000013, True, "TPU v5 lite", 1, trace=t,
+        counters={"jit_misses": 3}, window=harness.Window(
+            2.1295031350000215, 3, 3, 0, {}, data={
+                "prompt_lens": lens, "batch": 8, "new_tokens": 64}))
+    got = {k: v["value"] for k, v in
+           harness.read_per_layer(ctx, strict=True).items()}
+    assert got == pytest.approx({
+        "prefill_ms": 53.073074333333345,
+        "decode_hbm_roofline": 87.81236596262058,
+        "serve_mfu": 7.6341714217349965, "compiles_per_call": 1.0,
+        "device_idle.serve": 53.13849511913487,
+        "idle_in_call.serve": 26.11864681546947})
+    assert got["idle_in_call.serve"] <= got["device_idle.serve"]
+    bd = tr.breakdown(t)
+    assert bd["device_ops"][0][0] == "while.38"
+    assert bd["idle_gaps"][0][0].startswith("bench.wait")
