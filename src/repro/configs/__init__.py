@@ -8,7 +8,7 @@ from repro.configs.base import (  # noqa: F401
 # Import every arch module so registration side effects run.
 from repro.configs import (  # noqa: F401
     gemma3_4b, gemma3_1b, qwen2_1_5b, glm4_9b, phi35_moe, olmoe_1b_7b,
-    musicgen_large, internvl2_26b, mamba2_370m, jamba_52b,
+    musicgen_large, internvl2_26b, mamba2_370m, jamba_52b, jamba2_3b,
 )
 
 ALL_ARCHS = [
@@ -22,4 +22,5 @@ ALL_ARCHS = [
     "internvl2-26b",
     "mamba2-370m",
     "jamba-v0.1-52b",
+    "jamba2-3b",
 ]

@@ -63,12 +63,17 @@ class MoEConfig:
 
 @dataclass(frozen=True)
 class SSMConfig:
+    # "ssd": the Mamba-2 mixer (scalar A per head, inner norm);
+    # "mamba1": Jamba's Mamba-1 mixer (A per channel and state, a
+    # low-rank dt, RMS-normed dt/B/C, no inner norm) — models/mamba.py
+    kind: str = "ssd"
     d_state: int = 128
     d_conv: int = 4
     expand: int = 2
-    head_dim: int = 64
-    n_groups: int = 1
+    head_dim: int = 64    # ssd only
+    n_groups: int = 1     # ssd only
     chunk: int = 256  # SSD chunk length for training/prefill
+    dt_rank: int = 0      # mamba1 only
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model
@@ -187,7 +192,8 @@ class ModelConfig:
             )
         ssm = None
         if self.ssm is not None:
-            ssm = replace(self.ssm, d_state=16, head_dim=16, chunk=8)
+            ssm = replace(self.ssm, d_state=16, head_dim=16, chunk=8,
+                          dt_rank=min(self.ssm.dt_rank, 8))
         n_layers = 2
         attn_every, attn_offset = self.attn_every, self.attn_offset
         if self.family == "hybrid":
@@ -236,7 +242,12 @@ class ModelConfig:
             e, f = self.moe.n_experts, self.moe.d_ff_expert
             per_layer_moe = d * e + e * 3 * d * f  # router + experts
         per_layer_ssm = 0
-        if self.ssm is not None:
+        if self.ssm is not None and self.ssm.kind == "mamba1":
+            di, ds, r = self.ssm.d_inner(d), self.ssm.d_state, self.ssm.dt_rank
+            per_layer_ssm = (d * 2 * di + self.ssm.d_conv * di + di
+                             + di * (r + 2 * ds) + r * di + di
+                             + di * ds + di + r + 2 * ds + di * d)
+        elif self.ssm is not None:
             di = self.ssm.d_inner(d)
             nh = self.ssm.n_heads(d)
             ng, ds = self.ssm.n_groups, self.ssm.d_state

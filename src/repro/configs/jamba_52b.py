@@ -5,9 +5,9 @@
 
 Layer pattern (period 8): attention at index 4 of each period, Mamba
 elsewhere (1:7 attn:mamba). MoE replaces the FFN on every other layer
-(odd indices). Jamba v0.1 uses Mamba-1 selective scan; we implement the
-Mamba layers with the SSD scan (diagonal-A case) — see DESIGN.md
-§Arch-applicability for the recorded adaptation.
+(odd indices). The Mamba layers are Jamba's Mamba-1 mixer as published
+(d_state 16, d_conv 4, expand 2, dt_rank 256, RMS-normed dt/B/C, A per
+channel and state; ``models/mamba.py``).
 """
 from repro.configs.base import ModelConfig, MoEConfig, SSMConfig, register
 
@@ -28,6 +28,7 @@ CONFIG = register(ModelConfig(
     attn_offset=4,
     moe=MoEConfig(n_experts=16, top_k=2, d_ff_expert=14336,
                   moe_every=2, moe_offset=1, capacity_factor=1.25),
-    ssm=SSMConfig(d_state=16, d_conv=4, expand=2, head_dim=64, n_groups=1),
+    ssm=SSMConfig(kind="mamba1", d_state=16, d_conv=4, expand=2,
+                  dt_rank=256),
     source="arXiv:2403.19887",
 ))

@@ -42,6 +42,9 @@ class MemObject:
     bytes_per_step: float          # streaming traffic it generates
     dependent_accesses: float = 0.0  # serialized (latency-bound) accesses
     pinned_pool: Optional[str] = None  # force placement (escape hatch)
+    # read share of this object's own traffic: the surface's rw_ratio
+    # coordinate at which its cost is read (None: the contention's)
+    rw_ratio: Optional[float] = None
 
 
 @dataclass(frozen=True)
@@ -142,7 +145,8 @@ class PlacementAdvisor:
         kw = dict(stress_pool=contention.stress_pool,
                   stress_strat=contention.stress_strategy,
                   shape_tag=contention.stress_shape_tag,
-                  rw_ratio=contention.rw_ratio,
+                  rw_ratio=(contention.rw_ratio if obj.rw_ratio is None
+                            else obj.rw_ratio),
                   inject_rate=contention.inject_rate,
                   qualifier=self.qualifier)
         if self.pessimistic:
@@ -296,10 +300,18 @@ class ReadviseDecision:
 
 
 def kv_cache_object(name: str, size_bytes: int,
-                    bytes_read_per_token: float) -> MemObject:
+                    bytes_read_per_token: float,
+                    rw_ratio: Optional[float] = None) -> MemObject:
     """Decode reads the whole cache once per generated token."""
     return MemObject(name=name, size_bytes=size_bytes,
-                     bytes_per_step=bytes_read_per_token)
+                     bytes_per_step=bytes_read_per_token, rw_ratio=rw_ratio)
+
+
+def recurrent_state_object(name: str, size_bytes: int) -> MemObject:
+    """A state-space layer's state: each decode step reads it whole and
+    writes it back, a read share of one half."""
+    return MemObject(name=name, size_bytes=size_bytes,
+                     bytes_per_step=2.0 * size_bytes, rw_ratio=0.5)
 
 
 def optimizer_state_object(name: str, size_bytes: int) -> MemObject:

@@ -1,5 +1,5 @@
-"""Program spans: named intervals of the characterize path on the
-profiler's host trace.
+"""Program spans: named intervals of the characterize path, and of the
+serving engine's cache placement, on the profiler's host trace.
 
 Each span is a ``jax.profiler.TraceAnnotation`` named ``memscope.<name>``.
 While a profiler runs, it lands in the host plane on the same clock as
@@ -25,6 +25,9 @@ timed      the timed samples whose median becomes ``elapsed_ns``
 readback   checksums brought to the host
 assemble   assembling runs, the queueing-model solves included
 curvedb    turning a matrix result into a CurveDB
+place      a ``generate`` call's cache advice and placement
+           (arguments ``kv_bytes``, ``state_bytes``, ``kv_pool``,
+           ``state_pool``)
 =========  ==================================================
 """
 from __future__ import annotations
@@ -37,7 +40,7 @@ import jax
 
 PREFIX = "memscope."
 NAMES = ("plan", "inputs", "build", "timed", "readback", "assemble",
-         "curvedb")
+         "curvedb", "place")
 
 Arg = Union[str, int]
 _IDENTITY: contextvars.ContextVar[Dict[str, Arg]] = contextvars.ContextVar(

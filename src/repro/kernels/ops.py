@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.kernels import chase as _chase
 from repro.kernels import compute_probe as _probe
 from repro.kernels import flash_attention as _flash
+from repro.kernels import selective_scan as _scan
 from repro.kernels import stream as _stream
 
 
@@ -141,3 +142,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return _flash.flash_attention(
         q, k, v, causal=causal, window=window, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, interpret=_interp(interpret))
+
+
+# --- selective scan ----------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def selective_scan(x, dt, A, B, C, init_state, *,
+                   interpret: Optional[bool] = None):
+    """Mamba-1 prefill scan: (y (batch, T, D) f32, final state)."""
+    return _scan.selective_scan(x, dt, A, B, C, init_state,
+                                interpret=_interp(interpret))

@@ -1,12 +1,32 @@
-"""Mamba-2 (SSD, state-space duality) layers.
+"""State-space mixers: Mamba-2 (SSD) and Jamba's Mamba-1.
 
-Training/prefill uses the chunked SSD algorithm (arXiv:2405.21060 §6):
-within-chunk "attention-like" matmuls (MXU-friendly — this is the
-hardware adaptation of the selective scan) plus a ``lax.scan`` recurrence
-over chunk states. Decode is the O(1) recurrent update.
+``cfg.ssm.kind`` picks the mixer; :func:`mamba_init`,
+:func:`mamba_layer` and :func:`mamba_cache_shapes` dispatch on it.
 
-State layout: (B, H, P, N) with H = ssm heads (TP over "model"),
-P = head_dim, N = d_state. Conv cache: (B, K-1, conv_channels).
+SSD (``"ssd"``, arXiv:2405.21060): training/prefill uses the chunked SSD
+algorithm (§6): within-chunk "attention-like" matmuls (MXU-friendly)
+plus a ``lax.scan`` recurrence over chunk states. Decode is the O(1)
+recurrent update. State layout: (B, H, P, N) with H = ssm heads (TP over
+"model"), P = head_dim, N = d_state. Conv cache: (B, K-1, conv_channels).
+
+Mamba-1 (``"mamba1"``, arXiv:2312.00752, as Jamba publishes it,
+arXiv:2403.19887 and Hugging Face ``JambaMambaMixer``)::
+
+    x, z    = in_proj(h)
+    x       = silu(causal_depthwise_conv(x) + conv_bias)
+    dt, B, C = split(x_proj(x), [dt_rank, N, N]); each RMS-normed
+    delta   = softplus(dt_proj(dt) + dt_bias)
+    s_t     = exp(delta_t * A) * s_{t-1} + (delta_t * x_t) B_t,  A = -exp(A_log)
+    y_t     = s_t . C_t + D * x_t
+    out     = out_proj(y * silu(z))
+
+A is per channel and state (d_inner, N); no projection bias, no inner
+norm.  The state (B, d_inner, N) and the scan stay in f32.  Prefill runs
+the Pallas selective scan (``kernels/selective_scan.py``), which keeps
+the state on chip and never writes a (token, channel, state) tensor;
+training runs the same recurrence as a ``lax.scan`` over positions (it
+has a gradient); decode is the one-step update.  Conv cache:
+(B, K-1, d_inner).
 """
 from __future__ import annotations
 
@@ -16,7 +36,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import dense_init, rmsnorm
+from repro.kernels import ops
+from repro.models.common import dense_init, rmsnorm, rmsnorm_init
 
 Params = Dict[str, Any]
 
@@ -29,19 +50,26 @@ def _dims(cfg: ModelConfig):
     return s, d, di, nh, s.n_groups, s.d_state, s.d_conv, s.head_dim
 
 
+def _dt_bias(key, shape, dtype):
+    """The inverse softplus of a dt drawn log-uniform in [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32) *
+                 (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
 def mamba_init(key, cfg: ModelConfig, dtype) -> Params:
+    if cfg.ssm.kind == "mamba1":
+        return mamba1_init(key, cfg, dtype)
     s, d, di, nh, ng, ds, k, hp = _dims(cfg)
     conv_ch = di + 2 * ng * ds
     proj_out = 2 * di + 2 * ng * ds + nh
     ks = jax.random.split(key, 4)
-    dt = jnp.exp(jax.random.uniform(ks[2], (nh,), jnp.float32) *
-                 (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
     return {
         "w_zxbcdt": dense_init(ks[0], (d, proj_out), dtype),
         "conv_w": dense_init(ks[1], (k, conv_ch), dtype, fan_in=k),
         "conv_b": jnp.zeros((conv_ch,), dtype),
         "A_log": jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
-        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(jnp.float32),
+        "dt_bias": _dt_bias(ks[2], (nh,), jnp.float32),
         "ssm_D": jnp.ones((nh,), jnp.float32),
         "ssm_norm": jnp.zeros((di,), dtype),
         "w_ssm_out": dense_init(ks[3], (di, d), dtype),
@@ -158,6 +186,8 @@ def ssd_step(state, x, dt, A, B, C):
 def mamba_layer(params, x, *, cfg: ModelConfig, mode: str,
                 cache: Optional[Params] = None):
     """x: (B,S,D) -> (y (B,S,D), new_cache or None)."""
+    if cfg.ssm.kind == "mamba1":
+        return mamba1_layer(params, x, cfg=cfg, mode=mode, cache=cache)
     s_cfg, d, di, nh, ng, ds, k, hp = _dims(cfg)
     b, s, _ = x.shape
     zxbcdt = jnp.einsum("bsd,de->bse", x, params["w_zxbcdt"])
@@ -213,6 +243,97 @@ def mamba_layer(params, x, *, cfg: ModelConfig, mode: str,
 
 
 def mamba_cache_shapes(cfg: ModelConfig, batch: int):
+    if cfg.ssm.kind == "mamba1":
+        di = cfg.ssm.d_inner(cfg.d_model)
+        return {"ssm": (batch, di, cfg.ssm.d_state),
+                "conv": (batch, cfg.ssm.d_conv - 1, di)}
     s, d, di, nh, ng, ds, k, hp = _dims(cfg)
     return {"ssm": (batch, nh, hp, ds), "conv": (batch, k - 1,
                                                  di + 2 * ng * ds)}
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1 (Jamba's mixer)
+# ---------------------------------------------------------------------------
+
+
+def mamba1_init(key, cfg: ModelConfig, dtype) -> Params:
+    s, d = cfg.ssm, cfg.d_model
+    di, n, r = s.d_inner(d), s.d_state, s.dt_rank
+    ks = jax.random.split(key, 6)
+    a = jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (di, n))
+    return {
+        "w_xz": dense_init(ks[0], (d, 2, di), dtype, fan_in=d),
+        "conv_w": dense_init(ks[1], (s.d_conv, di), dtype, fan_in=s.d_conv),
+        "conv_b": jnp.zeros((di,), dtype),
+        "w_dbc": dense_init(ks[2], (di, r + 2 * n), dtype),
+        "dt_norm": rmsnorm_init(r, dtype),
+        "b_norm": rmsnorm_init(n, dtype),
+        "c_norm": rmsnorm_init(n, dtype),
+        "w_dt": dense_init(ks[3], (r, di), dtype),
+        "dt_bias": _dt_bias(ks[4], (di,), dtype),
+        "A_log": jnp.log(a).astype(dtype),
+        "ssm_D": jnp.ones((di,), dtype),
+        "w_ssm_out": dense_init(ks[5], (di, d), dtype),
+    }
+
+
+def selective_step(state, x, dt, A, B, C):
+    """One step of the Mamba-1 recurrence.  state: (B, D, N); x, dt:
+    (B, D); A: (D, N); B, C: (B, N).  Returns (y (B, D), new state)."""
+    new = jnp.exp(dt[:, :, None] * A) * state + \
+        (dt * x)[:, :, None] * B[:, None, :]
+    return jnp.sum(new * C[:, None, :], axis=-1), new
+
+
+def selective_scan_seq(x, dt, A, B, C, init_state):
+    """The recurrence over positions, one ``lax.scan`` step each.
+    x, dt: (B, S, D); B, C: (B, S, N).  Returns (y (B, S, D), final)."""
+    def step(s, inp):
+        y, s = selective_step(s, *inp[:2], A, *inp[2:])
+        return s, y
+
+    final, ys = jax.lax.scan(step, init_state, tuple(
+        jnp.moveaxis(v, 1, 0) for v in (x, dt, B, C)))
+    return jnp.moveaxis(ys, 0, 1), final
+
+
+def mamba1_layer(params, x, *, cfg: ModelConfig, mode: str,
+                 cache: Optional[Params] = None):
+    """x: (B,S,D) -> (y (B,S,D), new_cache or None)."""
+    s_cfg = cfg.ssm
+    di, n, r = s_cfg.d_inner(cfg.d_model), s_cfg.d_state, s_cfg.dt_rank
+    b = x.shape[0]
+    f32 = jnp.float32
+    xz = jnp.einsum("bsd,dke->bske", x, params["w_xz"])
+    xs, z = xz[:, :, 0], xz[:, :, 1]
+    xs, conv_tail = _causal_conv(
+        xs, params["conv_w"], params["conv_b"],
+        prev=cache["conv"] if cache is not None else None)
+    dbc = jnp.einsum("bse,er->bsr", xs, params["w_dbc"])
+    dt = rmsnorm(dbc[..., :r], params["dt_norm"], cfg.norm_eps)
+    Bm = rmsnorm(dbc[..., r:r + n], params["b_norm"], cfg.norm_eps).astype(f32)
+    Cm = rmsnorm(dbc[..., r + n:], params["c_norm"], cfg.norm_eps).astype(f32)
+    dt = jax.nn.softplus(
+        jnp.einsum("bsr,re->bse", dt, params["w_dt"],
+                   preferred_element_type=f32)
+        + params["dt_bias"].astype(f32))
+    A = -jnp.exp(params["A_log"].astype(f32))
+    state = (cache["ssm"].astype(f32) if cache is not None
+             else jnp.zeros((b, di, n), f32))
+
+    if mode == "decode":
+        y, state = selective_step(state, xs[:, 0].astype(f32), dt[:, 0], A,
+                                  Bm[:, 0], Cm[:, 0])
+        y = y[:, None]
+    elif mode == "prefill":
+        y, state = ops.selective_scan(xs, dt, A, Bm, Cm, state)
+    else:
+        y, state = selective_scan_seq(xs.astype(f32), dt, A, Bm, Cm, state)
+    y = y + params["ssm_D"].astype(f32) * xs.astype(f32)
+    y = y.astype(x.dtype) * jax.nn.silu(z)
+    out = jnp.einsum("bse,ed->bsd", y, params["w_ssm_out"])
+    new_cache = None
+    if mode in ("prefill", "decode"):
+        new_cache = {"ssm": state, "conv": conv_tail}
+    return out, new_cache

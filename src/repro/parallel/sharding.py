@@ -89,11 +89,17 @@ class ShardingRules:
             return P(_maybe(m, shape[0], mesh), None, None)
         if leaf == "w_zxbcdt":                           # (D, zxbcdt)
             return P(None, _maybe(m, shape[-1], mesh))
+        if leaf == "w_xz":                               # (D, 2, d_inner)
+            return P(None, None, _maybe(m, shape[-1], mesh))
+        if leaf == "w_dbc":                              # (d_inner, R+2N)
+            return P(_maybe(m, shape[0], mesh), None)
+        if leaf == "w_dt":                               # (R, d_inner)
+            return P(None, _maybe(m, shape[-1], mesh))
         if leaf == "w_ssm_out":                          # (d_inner, D)
             return P(_maybe(m, shape[0], mesh), None)
         if leaf == "conv_w":                             # (K, channels)
             return P(None, _maybe(m, shape[-1], mesh))
-        if leaf in ("A_log", "dt_bias", "ssm_D"):        # (H,)
+        if leaf in ("A_log", "dt_bias", "ssm_D"):  # (H,) | (d_inner[, N])
             return P(_maybe(m, shape[0], mesh))
         if leaf == "ssm_norm":                           # (d_inner,)
             return P(_maybe(m, shape[0], mesh))
@@ -177,7 +183,12 @@ class ShardingRules:
         return P(self.batch if self.batch else None, None, None, None)
 
     def ssm_state_spec(self) -> P:
-        """(B, H, P, N) recurrent state — heads TP."""
+        """(B, H, P, N) recurrent state — heads TP; Mamba-1's
+        (B, d_inner, N) — channels TP."""
+        if self.cfg.ssm is not None and self.cfg.ssm.kind == "mamba1":
+            di = self.cfg.ssm.d_inner(self.cfg.d_model)
+            return P(self.batch if self.batch else None,
+                     _maybe(self.tp, di, self.mesh), None)
         h = _maybe(self.tp, self.cfg.ssm.n_heads(self.cfg.d_model),
                    self.mesh) if self.cfg.ssm else self.tp
         return P(self.batch if self.batch else None, h, None, None)

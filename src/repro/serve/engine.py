@@ -6,7 +6,10 @@ pool it belongs in under the expected contention (HBM normally; host DRAM
 when HBM capacity is the binding constraint — the long-context regime),
 and materialises it through the chosen upool.  This is the paper's
 Fig. 14 loop (characterize -> place -> run) applied to an inference
-server.
+server.  A hybrid model holds two kinds of cache side by side — the KV
+cache of its attention layers and the recurrent state of its state-space
+layers — and the engine advises and places them as two objects, each at
+its own read/write mix (:func:`choose_cache_pools`).
 
 The loop also closes *online*: pass a
 :class:`repro.serve.monitor.ServeMonitor` and the engine times every
@@ -28,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ServeConfig
+from repro.core import spans
 from repro.models import lm
 from repro.parallel.sharding import ShardingRules
 from repro.train.step import make_constrain
@@ -40,12 +44,32 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
+CACHE_KINDS = ("kv", "state")
+
+
+def cache_kind(group: Params) -> str:
+    """``kv`` for an attention layer's cache, ``state`` for a state-space
+    layer's (its recurrent state and conv tail)."""
+    return "kv" if "k" in group else "state"
+
+
+def _groups(caches: Params):
+    """Every layer's cache group of a cache pytree, as (top, name,
+    group): ``caches[top][name]`` is one layer position's cache."""
+    for top, grp in caches.items():
+        for name, group in grp.items():
+            yield top, name, group
+
+
 def cache_bytes(cfg: ModelConfig, batch: int, max_len: int,
-                kv_dtype=jnp.bfloat16) -> int:
+                kv_dtype=jnp.bfloat16, kind: Optional[str] = None) -> int:
+    """Bytes of the decode caches, or of one ``kind`` of them."""
     import math
     struct = lm.cache_struct(cfg, batch, max_len, kv_dtype)
     return sum(int(s.dtype.itemsize) * math.prod(s.shape)
-               for s in jax.tree.leaves(struct))
+               for _t, _n, group in _groups(struct)
+               if kind in (None, cache_kind(group))
+               for s in jax.tree.leaves(group))
 
 
 def decode_rw_mix(batch: int, max_len: int) -> float:
@@ -114,6 +138,46 @@ def choose_kv_pool(cfg: ModelConfig, batch: int, max_len: int, *,
     return plan.pool_of("kv")
 
 
+def choose_cache_pools(cfg: ModelConfig, batch: int, max_len: int, *,
+                       advisor=None, scfg: Optional[ServeConfig] = None,
+                       pool_mgr=None,
+                       hbm_free_bytes: Optional[int] = None,
+                       rw_mix: Optional[float] = None,
+                       inject_rate: Optional[float] = None
+                       ) -> Dict[str, str]:
+    """The pool of each kind of cache the model holds (``kv``,
+    ``state``).  A model with one kind is advised as one object, exactly
+    as :func:`choose_kv_pool` advises it.  A hybrid model's two kinds are
+    advised together, as two objects that compete for the same
+    capacities: the KV cache, which grows with the context and of which
+    each decode step reads nearly all (:func:`decode_rw_mix`), and the
+    recurrent state, fixed in size, which each step reads and rewrites
+    whole (read share 0.5)."""
+    sizes = {k: cache_bytes(cfg, batch, max_len, kind=k)
+             for k in CACHE_KINDS}
+    kinds = [k for k in CACHE_KINDS if sizes[k]]
+    scfg = scfg or ServeConfig()
+    if len(kinds) == 1 or advisor is None or scfg.kv_placement != "auto":
+        pool = choose_kv_pool(cfg, batch, max_len, advisor=advisor,
+                              scfg=scfg, pool_mgr=pool_mgr,
+                              hbm_free_bytes=hbm_free_bytes, rw_mix=rw_mix,
+                              inject_rate=inject_rate)
+        return dict.fromkeys(kinds, pool)
+    from repro.core.placement import (ContentionSpec, kv_cache_object,
+                                      recurrent_state_object)
+    if rw_mix is None:
+        rw_mix = decode_rw_mix(batch, max_len)
+    objs = [kv_cache_object("kv", sizes["kv"],
+                            bytes_read_per_token=float(sizes["kv"]),
+                            rw_ratio=rw_mix),
+            recurrent_state_object("state", sizes["state"])]
+    plan = advisor.advise(
+        objs, ContentionSpec(0, rw_ratio=rw_mix, inject_rate=inject_rate),
+        capacities=pool_capacities(advisor, pool_mgr=pool_mgr,
+                                   hbm_free_bytes=hbm_free_bytes))
+    return {o.name: plan.pool_of(o.name) for o in objs}
+
+
 # ---------------------------------------------------------------------------
 # Step builders
 # ---------------------------------------------------------------------------
@@ -164,7 +228,8 @@ def sample_token(logits, key, temperature: float = 0.0):
 class GenerateResult:
     tokens: Any                 # (B, T)
     steps: int
-    kv_pool: str                # the pool the caches ENDED in
+    kv_pool: str                # the pool the KV caches ENDED in (the
+                                # state's, for a model with no KV cache)
     # (B, V) f32 logits the last emitted token was sampled from
     last_logits: Any = None
     # online-loop provenance (monitored decode only; empty otherwise)
@@ -197,6 +262,10 @@ class ServeEngine:
         # jitted prefill per max_len: repeated generate calls at the
         # same shape reuse ONE trace (the seed re-jitted every call)
         self._prefill_cache: Dict[int, Callable] = {}
+        # jitted decode loop per (steps, temperature): an eager lax.scan
+        # with a fresh body was traced again, and its program fetched
+        # from the compile cache, on every call
+        self._loop_cache: Dict[Tuple[int, float], Callable] = {}
         # observed decode duty cycle (EWMA across generate calls): the
         # inject_rate coordinate the engine feeds back into placement
         self._duty: Optional[float] = None
@@ -210,6 +279,29 @@ class ServeEngine:
             self._prefill_cache[max_len] = fn
         return fn
 
+    def _decode_loop(self, n_steps: int, temperature: float) -> Callable:
+        """``n_steps`` decode steps in one program.  It is named ``scan``
+        so that, jitted, it compiles as the module ``jit_scan``, the name
+        an eager ``lax.scan`` of the steps compiled as."""
+        fn = self._loop_cache.get((n_steps, temperature))
+        if fn is None:
+            decode = self._decode
+
+            def scan(params, caches, tok, logits, key, start):
+                def body(carry, i):
+                    caches, tok, _logits, key = carry
+                    key, sub = jax.random.split(key)
+                    caches, logits = decode(params, caches, tok, start + i)
+                    nxt = sample_token(logits, sub, temperature)[:, None]
+                    return (caches, nxt, logits, key), tok[:, 0]
+
+                return jax.lax.scan(body, (caches, tok, logits, key),
+                                    jnp.arange(n_steps, dtype=jnp.int32))
+
+            fn = jax.jit(scan, donate_argnums=(1,))
+            self._loop_cache[(n_steps, temperature)] = fn
+        return fn
+
     # -- placement -----------------------------------------------------------
     def _place_caches(self, caches: Params, pool_name: str) -> Params:
         """Materialise the cache pytree in ``pool_name`` via its upool.
@@ -220,6 +312,18 @@ class ServeEngine:
         if self.pool_mgr is None:
             return caches
         return self.pool_mgr.upool(pool_name).place(caches)
+
+    def _place_by_kind(self, caches: Params, pools: Dict[str, str]
+                       ) -> Params:
+        """Each layer's cache in the pool ``pools`` gives its kind; one
+        placement of the whole pytree where every kind shares a pool."""
+        if len(set(pools.values())) <= 1:
+            return self._place_caches(caches, next(iter(pools.values())))
+        out: Params = {top: {} for top in caches}
+        for top, name, group in _groups(caches):
+            out[top][name] = self._place_caches(group,
+                                                pools[cache_kind(group)])
+        return out
 
     def duty_cycle(self) -> Optional[float]:
         return self._duty
@@ -240,13 +344,21 @@ class ServeEngine:
         b, s = tokens.shape
         max_len = s + max_new_tokens
         rw_mix = decode_rw_mix(b, max_len)
-        kv_pool = choose_kv_pool(cfg, b, max_len, advisor=self.advisor,
-                                 scfg=self.scfg, pool_mgr=self.pool_mgr,
-                                 rw_mix=rw_mix, inject_rate=self._duty)
 
         caches, logits = self._prefill(max_len)(self.params, tokens,
                                                 frontend)
-        caches = self._place_caches(caches, kv_pool)
+        # advised while the device runs the prefill; placing waits for it
+        with spans.span("place", **{
+                f"{k}_bytes": cache_bytes(cfg, b, max_len, kind=k)
+                for k in CACHE_KINDS}) as span:
+            pools = choose_cache_pools(
+                cfg, b, max_len, advisor=self.advisor, scfg=self.scfg,
+                pool_mgr=self.pool_mgr, rw_mix=rw_mix,
+                inject_rate=self._duty)
+            span.set_metadata(**{f"{k}_pool": pools.get(k, "")
+                                 for k in CACHE_KINDS})
+            caches = self._place_by_kind(caches, pools)
+        kv_pool = pools.get("kv", pools.get("state"))
 
         key = jax.random.PRNGKey(seed)
         tok = sample_token(logits, key, temperature)[:, None]
@@ -263,18 +375,10 @@ class ServeEngine:
     def _generate_scan(self, caches, tok, logits, key, s: int,
                        max_new_tokens: int, temperature: float,
                        kv_pool: str) -> GenerateResult:
-        def body(carry, i):
-            caches, tok, _logits, key = carry
-            key, sub = jax.random.split(key)
-            caches, logits = self._decode(self.params, caches, tok,
-                                          s + i)
-            nxt = sample_token(logits, sub, temperature)[:, None]
-            return (caches, nxt, logits, key), tok[:, 0]
-
         # prefill already sampled token 0; decode the remaining N-1
-        (caches, last, logits, _), toks = jax.lax.scan(
-            body, (caches, tok, logits, key),
-            jnp.arange(max_new_tokens - 1, dtype=jnp.int32))
+        (caches, last, logits, _), toks = self._decode_loop(
+            max_new_tokens - 1, temperature)(
+            self.params, caches, tok, logits, key, jnp.int32(s))
         out = jnp.concatenate(
             [jnp.moveaxis(toks, 0, 1), last], axis=1) \
             if max_new_tokens > 1 else last

@@ -164,4 +164,4 @@ def test_active_params_moe():
 def test_long500k_applicability():
     runnable = {a for a in ALL_ARCHS if get_config(a).sub_quadratic}
     assert runnable == {"gemma3-4b", "gemma3-1b", "mamba2-370m",
-                        "jamba-v0.1-52b"}
+                        "jamba-v0.1-52b", "jamba2-3b"}

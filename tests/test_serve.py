@@ -34,7 +34,8 @@ SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 N_DEV = max(2, int(os.environ.get("REPRO_SPMD_DEVICES", "8")))
 
 PROMPT, NEW = 12, 4
-ARCHS = ["qwen2-1.5b", "gemma3-1b", "mamba2-370m", "jamba-v0.1-52b"]
+ARCHS = ["qwen2-1.5b", "gemma3-1b", "mamba2-370m", "jamba-v0.1-52b",
+         "jamba2-3b"]
 
 
 def _logits_all(cfg, params, tokens):
@@ -200,6 +201,43 @@ def test_prefill_trace_cached_across_generate_calls(monkeypatch):
     assert cont.rw_ratio == pytest.approx(
         eng.decode_rw_mix(2, PROMPT + NEW))
     assert caps is None                  # no manager, no free-bytes hint
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "jamba2-3b"])
+def test_decode_loop_compiles_once_across_generate_calls(arch):
+    """The decode loop is one jitted program per (steps, temperature),
+    kept across calls: a repeated call compiles nothing and loads
+    nothing from the compile cache (an eager ``lax.scan`` of the steps
+    was traced again and fetched from the cache on every call), and the
+    program keeps the module name ``jit_scan``."""
+    cfg = get_config(arch).reduced()
+    rules = make_rules(cfg, make_host_mesh(1, 1), global_batch=2,
+                       shape_kind="decode")
+    params = lm.init_params(cfg, jax.random.PRNGKey(1))
+    engine = eng.ServeEngine(cfg, params, rules, ServeConfig())
+    prompts = (jnp.arange(2 * PROMPT, dtype=jnp.int32).reshape(2, PROMPT)
+               * 3) % cfg.vocab_size
+    out1 = engine.generate(prompts, max_new_tokens=NEW)
+    misses = []
+
+    def on_duration(event, *_a, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            misses.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        out2 = engine.generate(prompts, max_new_tokens=NEW)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert misses == []
+    np.testing.assert_array_equal(np.asarray(out1.tokens),
+                                  np.asarray(out2.tokens))
+    assert list(engine._loop_cache) == [(NEW - 1, 0.0)]
+    loop = engine._decode_loop(NEW - 1, 0.0)
+    caches, logits = engine._prefill(PROMPT + NEW)(params, prompts)
+    text = loop.lower(params, caches, prompts[:, :1], logits,
+                      jax.random.PRNGKey(0), jnp.int32(PROMPT)).as_text()
+    assert text.startswith("module @jit_scan")
 
 
 def test_choose_kv_pool_derives_capacities():

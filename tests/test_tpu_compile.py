@@ -145,6 +145,24 @@ def test_probe_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_selective_scan_compiles_for_v5e_at_jamba2_widths(one_chip):
+    """The Mamba-1 prefill scan as the mixer calls it on a chip, at
+    jamba2-3b's widths (d_inner 5120, d_state 16) over a 16,384-token
+    prompt; its instruction carries the kernel's name, which the
+    benchmark's trace readers find it by."""
+    from repro.kernels import selective_scan
+    t, d, n = 16384, 5120, 16
+    shapes = [((1, t, d), jnp.bfloat16), ((1, t, d), jnp.float32),
+              ((d, n), jnp.float32), ((1, t, n), jnp.float32),
+              ((1, t, n), jnp.float32), ((1, d, n), jnp.float32)]
+    compiled = jax.jit(functools.partial(
+        selective_scan.selective_scan, interpret=False)).lower(
+        *[jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+          for shp, dt in shapes]).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%selective_scan" in text
+
+
 def test_vmem_kernels_are_not_handed_more_than_compiles(one_chip):
     """The residency cap the workloads hand VMEM kernels is what the
     compile above proved: one more block of rows is refused by the
