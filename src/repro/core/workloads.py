@@ -284,8 +284,7 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
     if strat in _VMAP_CHASES:
         seeds = seeds or list(range(g))
         with spans.span("inputs"):
-            bufs = pool.place(jnp.asarray(
-                np.stack([ops.chain_buffer(rows, s) for s in seeds])))
+            bufs = chase_operand(pool, rows, seeds)
         steps = chase_steps(rows)
         if strat == "l" and vmem:
             batched = _vmapped(ops.chase_vmem, bufs, programs, stats,
@@ -353,6 +352,14 @@ def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
     import dataclasses
     return [res if name == res.pool else dataclasses.replace(res, pool=name)
             for name in names]
+
+
+def chase_operand(pool: MemoryPool, rows: int, seeds) -> jax.Array:
+    """The stacked ``(len(seeds), rows, 128)`` int32 chase operand in
+    ``pool``'s memory: each seed's Sattolo successors drawn on the host,
+    their line layout written on the device."""
+    return pool.place(ops.lay_lines(
+        np.stack([ops.make_chain(rows, s) for s in seeds])))
 
 
 def _vmapped(kernel, operand, programs: Optional[dict], stats,
@@ -628,11 +635,13 @@ def _chase_workload(letter: str, pool, buffer_bytes: int, chain, kernel,
                     description: str, seed: Optional[int] = None
                     ) -> Workload:
     """A pointer-chase workload whose chain buffer is placed through the
-    pool (so a latency is measured in the pool's own memory)."""
+    pool (so a latency is measured in the pool's own memory).  ``chain``
+    gives a row count's successors; their line layout is built on the
+    device."""
     rows = _rows(buffer_bytes)
 
     def init(_shape, _dtype):
-        return jnp.asarray(chain(rows))
+        return ops.lay_lines(chain(rows))
 
     alloc = pool.alloc((rows, LANE), jnp.int32, init=init,
                        tag=f"lat:{letter}")
@@ -655,7 +664,7 @@ def _mk_strided(pool, buffer_bytes, *, stride: int = 8, **kw):
     """strided pointer chase (constant hop distance, non-cacheable)"""
     return _chase_workload(
         "t", pool, buffer_bytes,
-        lambda rows: ops.strided_chain_buffer(rows, stride), ops.chase_hbm,
+        lambda rows: ops.make_strided_chain(rows, stride), ops.chase_hbm,
         f"strided pointer-chase (x{stride})")
 
 
@@ -668,7 +677,7 @@ def _mk_l(pool, buffer_bytes, *, seed: int = 0, **kw):
     vmem = _fits_vmem(buffer_bytes) or pool.node.kind == "vmem"
     return _chase_workload(
         "l", pool, buffer_bytes,
-        lambda rows: ops.chain_buffer(rows, seed),
+        lambda rows: ops.make_chain(rows, seed),
         ops.chase_vmem if vmem else ops.chase_hbm,
         "pointer-chase latency", seed)
 
@@ -678,7 +687,7 @@ def _mk_m(pool, buffer_bytes, *, seed: int = 0, **kw):
     """non-cacheable pointer chase — module latency"""
     return _chase_workload(
         "m", pool, buffer_bytes,
-        lambda rows: ops.chain_buffer(rows, seed), ops.chase_hbm,
+        lambda rows: ops.make_chain(rows, seed), ops.chase_hbm,
         "non-cacheable pointer-chase", seed)
 
 

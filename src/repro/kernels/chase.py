@@ -20,6 +20,15 @@ Two TPU-native variants:
 
 Line layout: (n_lines, 128) int32 — one 512-byte lane-row per "cache
 line"; element [i, 0] holds the successor of line i.
+
+Chains are built in two halves.  ``make_chain`` draws the Sattolo
+permutation on the host in bulk: every step's swap target in one
+``Generator.integers`` call, the swaps replayed with array operations.
+It returns, bit for bit, the permutation that the textbook loop draws
+from the same seed, which is what the chase's checksum is checked
+against (a different cycle is a wrong result, not a faster one).
+``lay_lines`` then writes the line layout on the device from the
+successors, so only 4 bytes a line cross from the host.
 """
 from __future__ import annotations
 
@@ -44,17 +53,45 @@ LANE = 128
 
 def make_chain(n_lines: int, seed: int = 0) -> np.ndarray:
     """Sattolo cyclic permutation: following next[i] from 0 visits every
-    line exactly once before returning to 0."""
+    line exactly once before returning to 0.
+
+    Bit for bit the permutation of the loop ``for i in n-1 .. 1: j =
+    rng.integers(0, i); swap p[i], p[j]``, and it must stay so: a
+    chase's final index is checked exactly against that loop's cycle.
+    No per-line Python step: one call draws every ``j`` in the loop's
+    order (array bounds draw exactly as the scalar calls do), and the
+    swaps are replayed in closed form.  Position i is final after step
+    i, and holds what position j[i] held just before it: the value that
+    the next larger step g(i) aiming at j[i] put there, else j[i]
+    itself.  A step k leaves at j[k] what position k held before step
+    k: V(k), which is V(f(k)) for the smallest step f(k) aiming at k,
+    else k.  Line 0 ends holding V(0)."""
     rng = np.random.default_rng(seed)
-    p = np.arange(n_lines)
-    for i in range(n_lines - 1, 0, -1):
-        j = rng.integers(0, i)
-        p[i], p[j] = p[j], p[i]
+    if n_lines < 2:
+        return np.arange(n_lines, dtype=np.int32)
+    j = np.zeros(n_lines, np.int64)
+    j[:0:-1] = rng.integers(0, np.arange(n_lines - 1, 0, -1))
+    # steps grouped by target, ascending within a group
+    steps = np.argsort(j[1:], kind="stable") + 1
+    target = j[steps]
+    first = np.ones(n_lines - 1, bool)
+    first[1:] = target[1:] != target[:-1]
+    # V by pointer doubling along k -> f(k); f(k) > k, so the longest
+    # path (n - 1 hops) is resolved after bit_length(n - 1) rounds
+    v = np.arange(n_lines)
+    v[target[first]] = steps[first]
+    for _ in range((n_lines - 1).bit_length()):
+        v = v[v]
+    p = j.copy()
+    later = ~first[1:]               # steps[a + 1] is g(steps[a])
+    p[steps[:-1][later]] = v[steps[1:][later]]
+    p[0] = v[0]
     return p.astype(np.int32)
 
 
 def chain_buffer(n_lines: int, seed: int = 0) -> np.ndarray:
-    """(n_lines, 128) int32 buffer with the successor in lane 0."""
+    """(n_lines, 128) int32 buffer with the successor in lane 0, on the
+    host (``lay_lines`` builds the same layout on the device)."""
     buf = np.zeros((n_lines, LANE), np.int32)
     buf[:, 0] = make_chain(n_lines, seed)
     return buf
@@ -82,6 +119,15 @@ def strided_chain_buffer(n_lines: int, stride: int) -> np.ndarray:
     buf = np.zeros((n_lines, LANE), np.int32)
     buf[:, 0] = make_strided_chain(n_lines, stride)
     return buf
+
+
+@jax.jit
+def lay_lines(succ: jnp.ndarray) -> jnp.ndarray:
+    """(..., n_lines) int32 successors -> the (..., n_lines, 128) line
+    layout with the successor in lane 0, written on the device in one
+    elementwise pass (a lane-0 scatter compiles to a transposed copy
+    of the whole buffer, then a pad)."""
+    return jnp.where(jnp.arange(LANE) == 0, succ[..., None], 0)
 
 
 # ---------------------------------------------------------------------------

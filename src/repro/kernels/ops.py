@@ -121,6 +121,7 @@ make_chain = _chase.make_chain
 chain_buffer = _chase.chain_buffer
 make_strided_chain = _chase.make_strided_chain
 strided_chain_buffer = _chase.strided_chain_buffer
+lay_lines = _chase.lay_lines
 
 
 # --- compute probe -------------------------------------------------------------

@@ -126,6 +126,28 @@ def test_chain_is_single_cycle():
         assert idx == 0 and len(seen) == n
 
 
+@pytest.mark.parametrize("n", [1, 2, 3, 4, 17, 512, 4097, 131072])
+@pytest.mark.parametrize("seed", [0, 1, 7, 2**31 - 1])
+def test_chain_is_the_reference_sattolo_bit_for_bit(n, seed):
+    """The bulk-drawn chain is exactly the permutation the plain Sattolo
+    loop draws from the same seed: the benchmark checks every chase's
+    final index against that loop."""
+    from bench.reference import probes
+    got = chase.make_chain(n, seed)
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, probes.sattolo(n, seed))
+
+
+@pytest.mark.parametrize("shape", [(1,), (64,), (3, 257)])
+def test_lay_lines_matches_the_host_layout(shape):
+    succ = np.arange(np.prod(shape), dtype=np.int32).reshape(shape)[..., ::-1]
+    buf = np.zeros(shape + (chase.LANE,), np.int32)
+    buf[..., 0] = succ
+    out = chase.lay_lines(succ)
+    assert out.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(out), buf)
+
+
 @pytest.mark.parametrize("stride", [1, 4, 8, 50])
 def test_strided_chain_is_single_cycle(stride):
     for n in (1, 2, 7, 64, 100):

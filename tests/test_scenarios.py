@@ -300,6 +300,44 @@ def test_batched_chase_latency_matches_naive():
                                                   rel=1.0)
 
 
+@pytest.mark.parametrize("pool_name", ["hbm", "host"])
+@pytest.mark.parametrize("g", [1, 3])
+def test_chase_operand_is_the_host_layout_in_the_pool_memory(pool_name, g):
+    """The stacked chase operand built on the device holds what the
+    host-side chain buffers hold, in the pool's own memory kind."""
+    import jax
+    import numpy as np
+
+    from repro.core.devicetree import TPU_V5E
+    from repro.core.pools import PoolManager
+    from repro.core.workloads import chase_operand
+    from repro.kernels.chase import chain_buffer
+    pool = PoolManager(TPU_V5E).pool(pool_name)
+    seeds = [5, 0, 11][:g]
+    out = jax.block_until_ready(chase_operand(pool, 512, seeds))
+    np.testing.assert_array_equal(
+        np.asarray(out), np.stack([chain_buffer(512, s) for s in seeds]))
+    assert out.sharding.memory_kind == (
+        pool.effective_memory_kind()
+        or jax.devices()[0].default_memory().kind)
+
+
+@pytest.mark.parametrize("strategy", ["l", "m"])
+def test_batched_chase_ends_where_the_reference_walk_ends(strategy):
+    """The batched chase pass, fed the bulk-drawn chains, returns each
+    member's reference final index."""
+    from bench.reference import probes
+    from repro.core.pools import PoolManager
+    from repro.core.workloads import measure_group
+    nbytes, seeds = 64 << 10, [3, 2**31 - 1]
+    res, _ = measure_group(strategy, PoolManager().pool("hbm"), nbytes,
+                           len(seeds), 10, seeds=seeds)
+    vmem = probes.uses_vmem_kernel(nbytes, "hbm")
+    assert [r.checksum for r in res] == [
+        probes.checksum(strategy, nbytes, vmem, s) for s in seeds]
+    assert [r.chain_seed for r in res] == seeds
+
+
 def test_copy_stress_between_read_and_write(shaped_db):
     """Copy traffic (1.5 Tx/line) must hurt more than pure reads
     (1 Tx/line) and no more than allocating writes (2 Tx/line)."""
