@@ -42,7 +42,7 @@ SRC = os.path.join(ROOT, "src")
 MiB = 1 << 20
 STREAM_BYTES = 256 * MiB          # hbm streams and chase chains
 VMEM_BYTES = 16 * MiB             # the vmem pool's resident kernels
-PROBES = tuple("rswxycblmti")     # every probe letter of the registry
+PROBES = tuple("rswxycblmti")     # every letter of workloads.STRATEGIES
 CHAR_ITERS = 20                   # passes per timing sample (chases: /10)
 
 ARCH = "qwen2-1.5b"

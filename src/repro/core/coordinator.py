@@ -57,25 +57,15 @@ from repro.core.exec.assemble import (MatrixResult, ScenarioResult,
                                       ScenarioRun, assemble_runs)
 from repro.core.exec.dispatch import (Dispatcher, DispatchStats,
                                       count_compiles)
-from repro.core.exec.fence import (_shard_map_bodies,
-                                   measured_region_is_fenced)
-from repro.core.exec.plan import effective_duty as _effective_duty
-from repro.core.exec.program import (_SPMD_CHASES, _SPMD_STREAM_2X,
-                                     build_ladder_program,
-                                     build_rung_operands,
+from repro.core.exec.fence import measured_region_is_fenced
+from repro.core.exec.program import (build_ladder_program,
                                      build_rung_program,
-                                     build_scenario_program,
-                                     spmd_branch_fn)
+                                     build_scenario_program)
 from repro.core.pools import MemoryPool, PoolManager
 from repro.core.scenarios import (ObserverSpec, ScenarioSpec, StressorSpec,
                                   TrafficShape)
-from repro.core.workloads import (WorkloadResult, make_shaped_workload,
+from repro.core.workloads import (STRATEGIES, WorkloadResult,
                                   measure_group, refusal)
-
-# long-standing import surface: tests and benchmarks reach these via
-# the coordinator module (the implementations moved to repro.core.exec)
-_spmd_branch_fn = spmd_branch_fn
-_build_rung_operands = build_rung_operands
 
 __all__ = [
     "ActivitySpec", "CoreCoordinator", "DispatchStats",
@@ -140,14 +130,8 @@ class ExperimentResult:
     config: ExperimentConfig
     scenarios: List[ScenarioResult] = field(default_factory=list)
 
-    def bandwidth_curve(self) -> List[Tuple[int, float]]:
-        return [(s.n_stressors,
-                 s.modeled_bw_gbps or s.main.bandwidth_gbps)
-                for s in self.scenarios]
-
-    def latency_curve(self) -> List[Tuple[int, float]]:
-        return [(s.n_stressors, s.modeled_lat_ns or s.main.latency_ns)
-                for s in self.scenarios]
+    bandwidth_curve = ScenarioRun.bandwidth_curve
+    latency_curve = ScenarioRun.latency_curve
 
 
 class ValidationError(ValueError):
@@ -242,9 +226,8 @@ class CoreCoordinator:
 
     # -- Experiment Instantiator ----------------------------------------
     def validate(self, cfg: ExperimentConfig) -> None:
-        from repro.core.workloads import _REGISTRY
         for which, spec in (("main", cfg.main), ("stress", cfg.stress)):
-            if spec.strategy not in _REGISTRY:
+            if spec.strategy not in STRATEGIES:
                 raise ValidationError(
                     f"{which}: unknown strategy {spec.strategy!r}")
             pool = self.pools.pool(spec.pool)   # raises PoolError if absent
@@ -289,63 +272,21 @@ class CoreCoordinator:
 
     # -- scenario ladder ----------------------------------------------------
     def run(self, cfg: ExperimentConfig) -> ExperimentResult:
+        """The ladder of one observer against one stressor: a one-spec
+        :meth:`run_matrix`."""
         self.validate(cfg)
-        n_scen = cfg.scenarios if cfg.scenarios is not None \
-            else self.platform.n_engines
-        result = ExperimentResult(cfg)
-
-        main_pool = self.pools.pool(cfg.main.pool)
-        stress_pool = self.pools.pool(cfg.stress.pool)
-
-        measured: Optional[WorkloadResult] = None
-        if self.backend in ("interpret", "tpu"):
-            wl = make_shaped_workload(cfg.main.strategy, main_pool,
-                                      cfg.main.buffer_bytes,
-                                      cfg.main.shape())
-            try:
-                measured = wl.run(cfg.iters)
-            finally:
-                wl.release()
-
-        for k in range(n_scen):
-            modeled = self._model_scenario(cfg, main_pool, stress_pool, k)  # noqa: E501
-            main_res = measured if measured is not None else WorkloadResult(
-                cfg.main.strategy, cfg.main.pool, cfg.main.buffer_bytes,
-                cfg.iters, 0, 0.0, 0)
-            result.scenarios.append(ScenarioResult(
-                n_stressors=k,
-                main=main_res,
-                modeled_bw_gbps=modeled[0],
-                modeled_lat_ns=modeled[1],
-                stress_bw_gbps=modeled[2],
-            ))
-        # per-scenario/experiment teardown (paper §III-A step 6) is done by
-        # wl.release() above; pools stay clean for the next experiment.
-        return result
-
-    def _model_scenario(self, cfg: ExperimentConfig, main_pool: MemoryPool,
-                        stress_pool: MemoryPool,
-                        k: int) -> Tuple[float, float, float]:
-        obs_node = self._model_node(cfg.main, main_pool,
-                                    other=cfg.stress, other_engines=k)
-        stress_node = self._model_node(cfg.stress, stress_pool,
-                                       other=cfg.main, other_engines=1)
-        classes = [sim.ActivityClass(
-            "obs", obs_node, cfg.main.strategy, 1,
-            read_fraction=cfg.main.read_fraction,
-            duty_cycle=cfg.main.duty_cycle, stride=cfg.main.stride)]
-        if k and cfg.stress.strategy != "i":
-            classes.append(sim.ActivityClass(
-                "stress", stress_node, cfg.stress.strategy, k,
-                read_fraction=cfg.stress.read_fraction,
-                duty_cycle=cfg.stress.duty_cycle,
-                stride=cfg.stress.stride))
-        res = sim.simulate_scenario(self.platform, classes)
-        obs = res.get("obs")
-        stress = res.get("stress")
-        return (obs.bw_gbps if obs else 0.0,
-                obs.lat_ns if obs else 0.0,
-                stress.bw_gbps if stress else 0.0)
+        main, stress = cfg.main, cfg.stress
+        spec = ScenarioSpec(
+            f"{main.describe()}-vs-{stress.describe()}",
+            ObserverSpec(main.strategy, main.pool, (main.buffer_bytes,),
+                         main.shape() or TrafficShape()),
+            (StressorSpec(stress.strategy, stress.pool, stress.buffer_bytes,
+                          stress.shape() or TrafficShape()),),
+            iters=cfg.iters,
+            max_stressors=(None if cfg.scenarios is None
+                           else cfg.scenarios - 1))
+        run, = self.run_matrix([spec]).runs
+        return ExperimentResult(cfg, scenarios=run.scenarios)
 
     # -- cache semantics ------------------------------------------------------
     _CACHEABLE = ("r", "w", "l", "c", "b")
@@ -385,18 +326,11 @@ class CoreCoordinator:
             return vmem
         return node
 
-    # -- ladder sweep used by characterize.py ------------------------------
-    def ladder(self, main: ActivitySpec, stress: ActivitySpec,
-               iters: int = 500) -> ExperimentResult:
-        return self.run(ExperimentConfig(main=main, stress=stress,
-                                         iters=iters))
-
     # ==================================================================
     # ScenarioSpec matrix execution (the v2 characterization engine)
     # ==================================================================
 
     def validate_spec(self, spec: ScenarioSpec) -> None:
-        from repro.core.workloads import _REGISTRY
         # exact-duplicate observers would alias one curve key per
         # buffer and silently overwrite each other's ladders in
         # CurveDB — reject up front (observers differing in ANY field
@@ -412,7 +346,7 @@ class CoreCoordinator:
                     f"the first occurrence's keys")
             seen.add(obs)
         for obs in spec.observers:
-            if obs.strategy not in _REGISTRY:
+            if obs.strategy not in STRATEGIES:
                 raise ValidationError(
                     f"{spec.name}: unknown observer strategy "
                     f"{obs.strategy!r}")
@@ -424,7 +358,7 @@ class CoreCoordinator:
                         f"{obs.pool} ({pool.available}B free)")
                 self._check_runnable(spec.name, obs.strategy, pool, b)
         for s in spec.stressors:
-            if s.strategy not in _REGISTRY:
+            if s.strategy not in STRATEGIES:
                 raise ValidationError(
                     f"{spec.name}: unknown stressor strategy "
                     f"{s.strategy!r}")
@@ -494,8 +428,8 @@ class CoreCoordinator:
                     duty_cycle=act.duty_cycle, stride=act.stride))
         res = sim.simulate_scenario(self.platform, classes)
         obs = res.get("obs")
-        stress_bw = sum(r.bw_gbps for n, r in res.items()
-                        if n.startswith("stress"))
+        stress_bw = sum((r.bw_gbps for n, r in res.items()
+                         if n.startswith("stress")), 0.0)
         return (obs.bw_gbps if obs else 0.0,
                 obs.lat_ns if obs else 0.0,
                 stress_bw)
@@ -520,11 +454,10 @@ class CoreCoordinator:
         The measured observer pass is where executable backends spend
         their dispatches; ``batched=True`` groups same-signature
         observers (strategy, shape, row count, residency, effective
-        memory placement) and measures each group with ONE jit'd
-        vmapped pass, instead of the naive one-dispatch-per-scenario
-        Python loop.  Multi-observer scenarios contribute one ladder
-        per (observer, buffer) and their observers join the same
-        signature groups.
+        memory placement) and measures each group with ONE pass,
+        where ``batched=False`` gives every ladder its own.
+        Multi-observer scenarios contribute one ladder per (observer,
+        buffer) and their observers join the same signature groups.
 
         Backends: ``simulate``/``interpret``/``tpu`` model the
         contention ladder per rung (interpret/tpu additionally measure
@@ -601,36 +534,27 @@ class CoreCoordinator:
     def _measure_triples(self, triples, batched: bool,
                          stats: DispatchStats) -> Dict[int, WorkloadResult]:
         """The measured observer pass over all (spec, observer, buffer)
-        triples (uncontended: single real device).  Grouping comes from
-        the SAME planner as the spmd backend
-        (:func:`repro.core.exec.plan.observer_groups`)."""
+        triples (uncontended: single real device).  Batched grouping
+        comes from the SAME planner as the spmd backend
+        (:func:`repro.core.exec.plan.observer_groups`); unbatched, each
+        triple is a group of one."""
         measured: Dict[int, WorkloadResult] = {}
-        if not batched:
-            for i, (spec, obs, buf) in enumerate(triples):
-                with spans.measurement(strategy=obs.strategy, bytes=buf,
-                                       members=1, group=i):
-                    wl = make_shaped_workload(
-                        obs.strategy, self.pools.pool(obs.pool), buf,
-                        obs.shape)
-                    try:
-                        measured[i] = wl.run(spec.iters)
-                    finally:
-                        wl.release()
-                stats.measure_dispatches += 1
-            return measured
-
-        with spans.span("plan", ladders=len(triples)):
-            groups = exec_plan.observer_groups(triples, self.pools)
-        for group, ((strategy, shape, buf, iters, _kind, _vm), idxs) in \
-                enumerate(groups.items()):
+        if batched:
+            with spans.span("plan", ladders=len(triples)):
+                groups = list(exec_plan.observer_groups(
+                    triples, self.pools).values())
+        else:
+            groups = [[i] for i in range(len(triples))]
+        for group, idxs in enumerate(groups):
+            spec, obs, buf = triples[idxs[0]]
             member_pools = [self.pools.pool(triples[i][1].pool)
                             for i in idxs]
-            with spans.measurement(strategy=strategy, bytes=buf,
+            with spans.measurement(strategy=obs.strategy, bytes=buf,
                                    members=len(idxs), group=group):
                 results, dispatches = measure_group(
-                    strategy, member_pools[0], buf, len(idxs), iters,
-                    shape=shape, member_pools=member_pools, stats=stats,
-                    programs=self._measured_programs)
+                    obs.strategy, member_pools[0], buf, len(idxs),
+                    spec.iters, shape=obs.shape, member_pools=member_pools,
+                    stats=stats, programs=self._measured_programs)
             stats.measure_dispatches += dispatches
             for i, res in zip(idxs, results):
                 measured[i] = res

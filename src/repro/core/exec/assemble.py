@@ -15,9 +15,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.exec.dispatch import DispatchStats
 from repro.core.exec.plan import effective_duty
-from repro.core.exec.program import _SPMD_CHASES, _SPMD_STREAM_2X
 from repro.core.scenarios import ObserverSpec, ScenarioSpec
-from repro.core.workloads import (LINE_BYTES, WorkloadResult,
+from repro.core.workloads import (LINE_BYTES, STRATEGIES, WorkloadResult,
                                   resolve_strategy, rows_for as _wl_rows)
 
 
@@ -75,16 +74,16 @@ def observer_result(obs: ObserverSpec, buf: int, iters: int,
     obs_rows = _wl_rows(buf)
     strat = resolve_strategy(obs.strategy, obs.shape)
     n_active = max(1, int(round(iters * effective_duty(obs.shape))))
-    if strat in _SPMD_CHASES:
+    if STRATEGIES[strat].chase:
         # elapsed spans n_active full traversals: bytes and
         # transactions both scale with it (latency = elapsed/tx)
         return WorkloadResult(strat, obs.pool, buf, iters,
                               obs_rows * LINE_BYTES * n_active,
                               elapsed,
                               transactions=obs_rows * n_active)
-    mult = 2 if strat in _SPMD_STREAM_2X else 1
     return WorkloadResult(strat, obs.pool, buf, iters,
-                          mult * obs_rows * LINE_BYTES * n_active,
+                          STRATEGIES[strat].lines * obs_rows * LINE_BYTES
+                          * n_active,
                           elapsed, 0)
 
 

@@ -28,7 +28,8 @@ from dataclasses import dataclass, replace
 from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.scenarios import ObserverSpec, ScenarioSpec
-from repro.core.workloads import resolve_strategy, rows_for as _wl_rows
+from repro.core.workloads import (STRATEGIES, chase_stride,
+                                  resolve_strategy, rows_for as _wl_rows)
 
 # ---------------------------------------------------------------------------
 
@@ -458,8 +459,8 @@ def _chain_req(role) -> Optional[Tuple]:
     strategy, shape, rows, _iters = role
     strat = resolve_strategy(strategy, shape)
     if strat == "t":
-        return ("stride", getattr(shape, "stride", 8) or 8, rows)
-    if strat in ("l", "m"):
+        return ("stride", chase_stride(shape), rows)
+    if STRATEGIES[strat].chase:
         return ("cycle", rows)
     return None
 

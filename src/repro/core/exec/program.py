@@ -24,7 +24,8 @@ from repro import compat
 from repro.core.exec.fence import measured_region_is_fenced
 from repro.core.exec.plan import (PlannedDispatch, effective_duty,
                                   merge_probe_operand_roles)
-from repro.core.workloads import LINE_BYTES, resolve_strategy
+from repro.core.workloads import (LINE_BYTES, STRATEGIES, block_rows,
+                                  chase_stride, resolve_strategy)
 
 
 class CompileError(RuntimeError):
@@ -38,10 +39,6 @@ def compile_traced(traced, what: str):
         return traced.lower().compile()
     except Exception as exc:
         raise CompileError(f"{what}: {exc}") from exc
-
-
-_SPMD_CHASES = ("l", "m", "t")      # latency walks: dependent gathers
-_SPMD_STREAM_2X = ("c", "x")        # copy/rmw touch two lines per line
 
 
 def build_rung_operands(roles, n_eng: int,
@@ -58,10 +55,10 @@ def build_rung_operands(roles, n_eng: int,
         (n_eng, rows_max, LINE_BYTES // 4)).copy()
     xi = np.zeros((n_eng, rows_max, LINE_BYTES // 4), np.int32)
     for e, (strategy, shape, rows, _ri) in enumerate(roles):
-        if resolve_strategy(strategy, shape) in _SPMD_CHASES:
-            if resolve_strategy(strategy, shape) == "t":
-                chain = kops.strided_chain_buffer(
-                    rows, getattr(shape, "stride", 8) or 8)
+        strat = resolve_strategy(strategy, shape)
+        if STRATEGIES[strat].chase:
+            if strat == "t":
+                chain = kops.strided_chain_buffer(rows, chase_stride(shape))
             else:
                 chain = kops.chain_buffer(rows, seed=e)
             xi[e, :rows, :chain.shape[1]] = chain
@@ -98,7 +95,7 @@ def spmd_branch_fn(strategy: str, shape, rows: int, iters: int,
             return jax.lax.fori_loop(0, n * 8, body, xf[0, 0] * 1e-30)
         return idle
 
-    if strat in _SPMD_CHASES:
+    if STRATEGIES[strat].chase:
         def chase(xf, xi):
             chain = xi[:rows, 0]
 
@@ -162,10 +159,11 @@ def _pallas_branch_fn(strat: str, shape, rows: int, n: int):
     from repro.core.workloads import _fits_vmem
 
     interp = not kops.on_tpu()
-    blk = min(512, rows)
+    blk = block_rows(rows)
 
-    if strat in _SPMD_CHASES:
-        vmem = strat == "l" and _fits_vmem(rows * LINE_BYTES)
+    if STRATEGIES[strat].chase:
+        vmem = (STRATEGIES[strat].vmem is not None
+                and _fits_vmem(rows * LINE_BYTES))
         kern = _kchase.chase_vmem if vmem else _kchase.chase_hbm
 
         def chase(xf, xi):
@@ -199,9 +197,9 @@ def _pallas_branch_fn(strat: str, shape, rows: int, n: int):
             def body(_, x):
                 # write-allocate: read + write back, carried so pass
                 # t+1 depends on pass t's stores — deliberate for 'w'
-                # too (a cacheable write allocates the line).  Useful-
-                # bytes stays the registry's convention: 'w' counts
-                # written lines (1x), 'x' both (2x, _SPMD_STREAM_2X).
+                # too (a cacheable write allocates the line).  Useful
+                # bytes follow the strategy table: 'w' counts written
+                # lines (1x), 'x' both (2x).
                 return _kstream.rmw_hbm(x, block_rows=blk,
                                         interpret=interp)
 

@@ -33,6 +33,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from repro.core.devicetree import MemoryNode, Platform
+from repro.core.workloads import STRATEGIES
 
 log = logging.getLogger(__name__)
 
@@ -60,7 +61,7 @@ _READ_TRAFFIC, _WRITE_TRAFFIC = 1.0, 2.0
 # transactions in flight (this is what makes dc-zva streams the most
 # aggressive stressor in Fig. 8/13).
 def strategy_mlp(strategy: str, node: MemoryNode) -> int:
-    if strategy in ("l", "m", "t"):
+    if STRATEGIES[strategy].chase:
         return 1
     if strategy == "i":
         return 0
@@ -114,7 +115,7 @@ class ActivityClass:
         z = self.node.base_latency_ns
         stride = self.stride
         if stride <= 1 and self.strategy == "t":
-            stride = _DEFAULT_T_STRIDE    # the t workload's default hop
+            stride = STRATEGIES["t"].stride   # the t workload's default
         if stride > 1:
             z *= 1.0 + _STRIDE_LATENCY_ALPHA * math.log2(
                 min(stride, _STRIDE_SATURATION))
@@ -126,7 +127,6 @@ class ActivityClass:
 # already misses the row buffer / defeats the prefetcher)
 _STRIDE_LATENCY_ALPHA = 0.12
 _STRIDE_SATURATION = 64
-_DEFAULT_T_STRIDE = 8     # matches workloads._mk_strided's default
 
 
 @dataclass

@@ -1,17 +1,18 @@
-"""Workload Library — registry of micro-benchmark activities (Table I).
+"""Workload library — the access strategies of Table I and the one
+measured pass that runs them.
 
-Each workload is keyed by its access-strategy letter and binds a memory
-pool + buffer size to a runnable activity.  Workloads carry:
+``STRATEGIES`` holds, per access-strategy letter, everything a
+measurement of it needs: its HBM-streaming kernel and, for the
+cacheable strategies (r/w/l), a VMEM-resident kernel; the static
+arguments each takes; the operand it is handed (the paper's
+configurable buffer initialisation: sequential ints for bandwidth
+sanity-checking, a Sattolo chain for latency); the lines it moves per
+buffer line; and whether members of a group carry distinct stacked
+operands or share one measurement.  :func:`measure_group` measures any
+number of same-signature observers of any letter from that table.
 
-* a **buffer initialiser** (the paper's configurable init: sequential
-  ints for bandwidth sanity-checking, a Sattolo chain for latency);
-* an **executable** (jit'd Pallas kernel, interpret=True off-TPU) used by
-  the ``interpret``/``tpu`` backends;
-* the **queueing-class parameters** (strategy letter, traffic multiplier,
-  MLP) consumed by the ``simulate`` backend.
-
-The cacheable strategies (r/w/l) become VMEM-resident kernels when the
-buffer fits the VMEM budget and HBM-streaming kernels otherwise — the
+The cacheable strategies become VMEM-resident kernels when the buffer
+fits the VMEM budget and HBM-streaming kernels otherwise — the
 software-managed-hierarchy analog of "whether the buffer fits in L2",
 which is exactly how the paper's Fig. 5 buffer-size sweeps behave.
 """
@@ -19,7 +20,7 @@ from __future__ import annotations
 
 import functools
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -27,8 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import spans
-from repro.core.devicetree import MemoryNode
-from repro.core.pools import Allocation, MemoryPool
+from repro.core.pools import MemoryPool
 from repro.kernels import ops
 
 LANE = 128
@@ -39,7 +39,12 @@ VMEM_BUDGET = 64 << 20         # "cache size": cacheable buffers <= this
 # block, double-buffered when vmapped, must fit the kernels' scoped
 # VMEM limit (kernels.stream.VMEM_LIMIT_BYTES) on a v5e
 VMEM_KERNEL_BYTES = 32 << 20
-_VMEM_KERNELS = ("r", "w", "l")   # strategies with a VMEM-resident kernel
+BLOCK_ROWS = 512               # rows of one (rows, 128) stream block
+
+
+def block_rows(rows: int) -> int:
+    """The stream kernels' block for a buffer of ``rows`` lines."""
+    return min(BLOCK_ROWS, rows)
 
 
 @dataclass
@@ -73,34 +78,111 @@ class WorkloadResult:
         return self.elapsed_ns / self.transactions
 
 
-@dataclass
-class Workload:
-    """A bound activity: strategy letter + pool + buffer."""
-    strategy: str
-    pool: MemoryPool
-    buffer_bytes: int
-    description: str
-    run_fn: Callable[[int], WorkloadResult]
-    alloc: Optional[Allocation] = None
-    is_memory_bound: bool = True
+# ---------------------------------------------------------------------------
+# The strategy table
+# ---------------------------------------------------------------------------
 
-    def run(self, iters: int = 500) -> WorkloadResult:
-        return self.run_fn(iters)
 
-    def release(self) -> None:
-        if self.alloc is not None:
-            with spans.span("inputs"):
-                self.pool.free(self.alloc)
-            self.alloc = None
+@dataclass(frozen=True)
+class Kernel:
+    """A probe kernel as the measured pass calls it: ``ops.<name>``,
+    looked up at call time, with the static arguments named in
+    ``takes`` filled in per measurement (``rows``, ``block_rows``,
+    ``n_steps``, ``read_fraction``) and those in ``fixed`` constant.
+    ``repeats`` in ``fixed`` is the passes one call makes over the
+    buffer: the time is per pass, and so is the output of a kernel that
+    adds up its passes (``summed``).  A stacked operand is vmapped over
+    unless the kernel walks the stack itself (``vmapped=False``)."""
+    name: str
+    takes: Tuple[str, ...] = ()
+    fixed: Tuple[Tuple[str, Any], ...] = ()
+    summed: bool = False
+    vmapped: bool = True
 
     @property
-    def node(self) -> MemoryNode:
-        return self.pool.node
+    def fn(self) -> Callable:
+        return getattr(ops, self.name)
+
+    @property
+    def passes(self) -> int:
+        return dict(self.fixed).get("repeats", 1)
 
 
-# ---------------------------------------------------------------------------
-# Buffer initialisers (paper: "Configurable Buffer Initialization")
-# ---------------------------------------------------------------------------
+@dataclass(frozen=True)
+class Strategy:
+    """One access strategy.  ``buffer`` is what its kernel is handed:
+    ``"lines"`` (the sequential float buffer), ``"chain"`` (a Sattolo
+    successor chain per member seed), ``"strided"`` (the constant-hop
+    chain of ``stride``), ``"identity"`` (the MXU loop's 128x128
+    matrix), or None (the kernel makes its own output)."""
+    description: str
+    hbm: Kernel
+    vmem: Optional[Kernel] = None      # VMEM-resident kernel (r w l)
+    buffer: Optional[str] = None
+    lines: int = 1                     # lines moved per buffer line
+    stride: int = 0                    # default hop of a strided chase
+
+    @property
+    def chase(self) -> bool:
+        """A dependent-load walk: its time is per transaction."""
+        return self.buffer in ("chain", "strided")
+
+    @property
+    def stacked(self) -> bool:
+        """Members carry distinct operands, stacked into one pass;
+        otherwise one measurement serves every member of a group."""
+        return self.buffer in ("lines", "chain")
+
+
+_READ = Kernel("stream_read", ("block_rows",))
+_WRITE = Kernel("stream_write", ("rows", "block_rows"))
+_CHASE = Kernel("chase_hbm", ("n_steps",), vmapped=False)
+
+STRATEGIES: Dict[str, Strategy] = {
+    "r": Strategy("sequential cacheable read", _READ,
+                  Kernel("vmem_read", fixed=(("repeats", 8),),
+                         summed=True), buffer="lines"),
+    "s": Strategy("non-cacheable read", _READ, buffer="lines"),
+    "w": Strategy("sequential cacheable write", _WRITE,
+                  Kernel("vmem_write", ("rows",), (("repeats", 8),))),
+    "y": Strategy("write-streaming (no write-allocate)", _WRITE),
+    "x": Strategy("non-cacheable write (allocate: read and write)",
+                  Kernel("stream_rmw", ("block_rows",)), buffer="lines",
+                  lines=2),
+    "c": Strategy("copy stream", Kernel("stream_copy", ("block_rows",)),
+                  buffer="lines", lines=2),
+    "b": Strategy("mixed read/write stream",
+                  Kernel("stream_mixed", ("read_fraction", "block_rows")),
+                  buffer="lines"),
+    "l": Strategy("pointer-chase latency (cacheable)", _CHASE,
+                  Kernel("chase_vmem", ("n_steps",)), buffer="chain"),
+    "m": Strategy("non-cacheable pointer chase", _CHASE, buffer="chain"),
+    "t": Strategy("strided pointer chase", _CHASE, buffer="strided",
+                  stride=8),
+    "i": Strategy("memory-idle MXU busy loop",
+                  Kernel("mxu_probe", fixed=(("iters", 64),)),
+                  buffer="identity", lines=0),
+}
+
+
+def resolve_strategy(strategy: str, shape=None) -> str:
+    """The strategy letter a (strategy, TrafficShape) pair actually
+    executes as: mixed shapes run the ``b`` mixed-stream workload,
+    strided shapes the ``t`` strided chase, everything else the plain
+    strategy.  The single source of truth for this mapping — the
+    measured pass and the spmd branch builder both consume it, so every
+    backend executes the same kernel class for a given spec."""
+    kind = getattr(shape, "kind", "steady") if shape is not None \
+        else "steady"
+    return {"mixed": "b", "strided": "t"}.get(kind, strategy)
+
+
+def chase_stride(shape) -> int:
+    """The hop of a strided chase: a strided shape's own, else the
+    ``t`` strategy's default."""
+    if getattr(shape, "kind", None) == "strided":
+        return shape.stride
+    return STRATEGIES["t"].stride
 
 
 def bw_buffer_init(shape, dtype):
@@ -110,101 +192,11 @@ def bw_buffer_init(shape, dtype):
 
 
 # ---------------------------------------------------------------------------
-# Factory
+# The measured pass
 # ---------------------------------------------------------------------------
 
-_REGISTRY: Dict[str, Callable[..., Workload]] = {}
-
-
-def register_strategy(letter: str):
-    def deco(fn):
-        _REGISTRY[letter] = fn
-        return fn
-    return deco
-
-
-def strategies() -> Dict[str, str]:
-    return {k: (v.__doc__ or "").strip().splitlines()[0]
-            for k, v in sorted(_REGISTRY.items())}
-
-
-def make_workload(strategy: str, pool: MemoryPool, buffer_bytes: int,
-                  **kw) -> Workload:
-    if strategy not in _REGISTRY:
-        raise KeyError(
-            f"unknown access strategy {strategy!r}; have "
-            f"{sorted(_REGISTRY)}")
-    return _REGISTRY[strategy](pool, buffer_bytes, **kw)
-
-
-def resolve_strategy(strategy: str, shape=None) -> str:
-    """The strategy letter a (strategy, TrafficShape) pair actually
-    executes as: mixed shapes run the ``b`` mixed-stream workload,
-    strided shapes the ``t`` strided chase, everything else the plain
-    strategy.  The single source of truth for this mapping — the
-    batched group measurement below and the coordinator's spmd branch
-    builder both consume it, so every backend executes the same kernel
-    class for a given spec."""
-    kind = getattr(shape, "kind", "steady") if shape is not None \
-        else "steady"
-    return {"mixed": "b", "strided": "t"}.get(kind, strategy)
-
-
-def make_shaped_workload(strategy: str, pool: MemoryPool, buffer_bytes: int,
-                         shape=None, **kw) -> Workload:
-    """Bind a (strategy, TrafficShape) pair to an executable workload.
-
-    Steady shapes resolve to the plain strategy; mixed ratios map onto
-    the ``b`` mixed-stream workload, strided shapes onto the ``t``
-    strided chase, and bursty shapes wrap the base workload with
-    duty-cycled accounting (the off phase is pure idle, so the
-    time-averaged bandwidth scales by the duty cycle)."""
-    with spans.span("inputs"):
-        if shape is None or getattr(shape, "is_steady", True):
-            return make_workload(strategy, pool, buffer_bytes, **kw)
-        if shape.kind == "mixed":
-            return make_workload("b", pool, buffer_bytes,
-                                 read_fraction=shape.read_fraction, **kw)
-        if shape.kind == "strided":
-            return make_workload("t", pool, buffer_bytes,
-                                 stride=shape.stride, **kw)
-        if shape.kind == "burst":
-            wl = make_workload(strategy, pool, buffer_bytes, **kw)
-            return _duty_cycled(wl, shape.duty_cycle)
-        raise KeyError(f"unknown traffic shape kind {shape.kind!r}")
-
-
-def _duty_cycled(wl: Workload, duty: float) -> Workload:
-    import dataclasses
-    base_run = wl.run_fn
-
-    def run(iters):
-        res = base_run(iters)
-        idle_ns = res.elapsed_ns * (1.0 - duty) / duty
-        return dataclasses.replace(res, elapsed_ns=res.elapsed_ns + idle_ns)
-
-    wl.run_fn = run
-    wl.description = f"{wl.description} (duty={duty:g})"
-    return wl
-
-
-# ---------------------------------------------------------------------------
-# Batched group measurement (the matrix runner's fast path)
-# ---------------------------------------------------------------------------
-
-# observer strategies whose measured pass maps over a stacked input
-# array, so G same-shape scenarios collapse into ONE jit'd vmapped
-# dispatch (read-like paths; chases keep per-member Sattolo chains) —
-# write-like paths and the deterministic strided chase ('t', whose
-# members are bit-identical) carry no distinct batched input, so their
-# group measures once and shares the result.
-_VMAP_READS = ("r", "s", "c", "x", "b")
-_VMAP_CHASES = ("l", "m")
-
-
-# batched measurement stacks member buffers into one array; cap the
-# stack so a big group cannot out-allocate the device (the naive path
-# only ever holds ONE member buffer)
+# a stacked pass holds every member's buffer at once; cap the stack so
+# a big group cannot out-allocate the device
 _BATCH_BYTES_CAP = 1 << 30
 
 
@@ -214,9 +206,10 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
                   member_pools: Optional[list] = None,
                   stats=None, programs: Optional[dict] = None
                   ) -> Tuple[list, int]:
-    """Measure ``n_members`` same-signature observers with jit'd
-    ``vmap`` passes over the stacked member buffers (chases keep
-    per-member chains, so different seeds/strides stay distinct).
+    """Measure ``n_members`` same-signature observers.  Strategies with
+    stacked operands run one pass over the stacked member buffers
+    (chases keep per-member chains, so different seeds stay distinct);
+    the others measure once and share the result.
 
     ``member_pools`` (optional, len ``n_members``) supports
     *heterogeneous* groups: observers from different pools whose
@@ -234,20 +227,15 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
     ``programs`` in ``program_cache_hits``.
 
     Returns ``(results, n_dispatches)``.  Normally one dispatch covers
-    the whole group; groups whose stacked footprint would exceed the
-    batch byte cap or the pool's free space split into chunks (the
-    naive path only ever holds ONE member buffer, so the batched path
-    must not out-allocate it unboundedly), each chunk one dispatch.
-    The group's wall time is split evenly (members are identical up to
-    buffer content, and on hardware they run as concurrent engines of
-    one fused pass)."""
-    strat = resolve_strategy(strategy, shape)
-    if strat not in _VMAP_READS + _VMAP_CHASES:
-        # write-like path stacks no buffers: one measurement serves
-        # the whole group regardless of member size
+    the whole group; stacked groups whose footprint would exceed the
+    batch byte cap or the pool's free space split into chunks, each
+    chunk one dispatch.  A stacked group's wall time is split evenly
+    (members are identical up to buffer content, and on hardware they
+    run as concurrent engines of one fused pass)."""
+    if not STRATEGIES[resolve_strategy(strategy, shape)].stacked:
         chunk = n_members
     else:
-        member_bytes = _rows(buffer_bytes) * LINE_BYTES
+        member_bytes = rows_for(buffer_bytes) * LINE_BYTES
         budget = min(_BATCH_BYTES_CAP, max(pool.available, member_bytes))
         chunk = max(1, min(n_members, budget // member_bytes))
     results: list = []
@@ -267,91 +255,80 @@ def measure_group(strategy: str, pool: MemoryPool, buffer_bytes: int,
 
 
 def _measure_chunk(strategy: str, pool: MemoryPool, buffer_bytes: int,
-                   n_members: int, iters: int, *, shape=None,
-                   seeds: Optional[list] = None,
-                   pool_names: Optional[list] = None, stats=None,
-                   programs: Optional[dict] = None) -> list:
-    rows = _rows(buffer_bytes)
-    g = n_members
-    names = pool_names or [pool.node.name] * g
-    vmem = _fits_vmem(buffer_bytes) or pool.node.kind == "vmem"
-    blk = min(512, rows)
+                   n_members: int, iters: int, *, shape, seeds: list,
+                   pool_names: Optional[list], stats,
+                   programs: Optional[dict]) -> list:
     strat = resolve_strategy(strategy, shape)
-
+    entry = STRATEGIES[strat]
+    rows = rows_for(buffer_bytes)
+    g = n_members if entry.stacked else 1
+    names = pool_names or [pool.node.name] * n_members
+    vmem = _fits_vmem(buffer_bytes) or pool.node.kind == "vmem"
+    kernel = entry.vmem if (vmem and entry.vmem is not None) else entry.hbm
     duty = shape.duty_cycle if (shape is not None
                                 and shape.kind == "burst") else 1.0
 
-    if strat in _VMAP_CHASES:
-        seeds = seeds or list(range(g))
-        with spans.span("inputs"):
-            bufs = chase_operand(pool, rows, seeds)
-        steps = chase_steps(rows)
-        if strat == "l" and vmem:
-            batched = _vmapped(ops.chase_vmem, bufs, programs, stats,
-                               n_steps=steps)
-        else:
-            # the HBM chase walks a stacked buffer's chains one after
-            # another inside one kernel
-            batched = functools.partial(ops.chase_hbm, n_steps=steps)
-        t, out = _timed(batched, bufs, iters=max(1, iters // 10))
-        # /g: the g chains execute back-to-back within the pass
-        # (test_batched_chase_latency_matches_naive guards it)
-        per = (t / g) / duty
-        kind = bufs.sharding.memory_kind
-        with spans.span("readback"):
-            ends = np.asarray(out)
-        return [WorkloadResult(strat, name, buffer_bytes, iters,
-                               rows * LINE_BYTES, per, transactions=steps,
-                               checksum=float(c), memory_kind=kind,
-                               chain_seed=sd)
-                for name, c, sd in zip(names, ends, seeds)]
+    x = _operand(entry, pool, rows, seeds, shape)
+    have = {"rows": rows, "block_rows": block_rows(rows),
+            "n_steps": chase_steps(rows),
+            "read_fraction": (shape.read_fraction if shape is not None
+                              and shape.kind == "mixed" else 0.5)}
+    static = {**{a: have[a] for a in kernel.takes}, **dict(kernel.fixed)}
+    if entry.stacked and kernel.vmapped:
+        fn = _vmapped(kernel.fn, x, programs, stats, **static)
+    else:
+        fn = functools.partial(kernel.fn, **static)
+    calls = max(1, iters // 10) if entry.chase else iters
+    t, out = _timed(fn, *(() if x is None else (x,)), iters=calls)
+    sums = _checksums(out, entry.stacked)
+    if kernel.summed:
+        sums = sums / kernel.passes
+    # /g: the g members execute back-to-back within the pass
+    # (test_batched_chase_latency_matches_naive guards it for chases)
+    per = t / kernel.passes / g / duty
+    if entry.chase:
+        nbytes, elapsed, tx = rows * LINE_BYTES, per, chase_steps(rows)
+    else:
+        nbytes = entry.lines * rows * LINE_BYTES * iters
+        elapsed, tx = per * iters, 0
+    # the idle loop touches no memory: no buffer, no memory kind
+    kind = (None if not entry.lines
+            else (x if x is not None else out).sharding.memory_kind)
 
-    if strat in _VMAP_READS:
-        # every member streams the same content, so one plain reference
-        # checks every member's checksum
-        with spans.span("inputs"):
-            x = pool.place(jnp.broadcast_to(
-                bw_buffer_init((rows, LANE), jnp.float32), (g, rows, LANE)))
-        scale = 1.0
-        useful = rows * LINE_BYTES
-        if strat == "b":
-            rf = (shape.read_fraction
-                  if shape is not None and shape.kind == "mixed" else 0.5)
-            kernel, static = ops.stream_mixed, dict(read_fraction=rf,
-                                                    block_rows=blk)
-        elif strat == "c":
-            kernel, static = ops.stream_copy, dict(block_rows=blk)
-            useful = 2 * rows * LINE_BYTES
-        elif strat == "x":
-            kernel, static = ops.stream_rmw, dict(block_rows=blk)
-            useful = 2 * rows * LINE_BYTES
-        elif vmem and strat == "r":
-            kernel, static = ops.vmem_read, dict(repeats=8)
-            scale = 1.0 / 8.0               # 8 on-chip re-reads per call
-        else:
-            kernel, static = ops.stream_read, dict(block_rows=blk)
-        batched = _vmapped(kernel, x, programs, stats, **static)
-        t, out = _timed(batched, x, iters=iters)
-        t *= scale
-        per = (t / g) / duty
-        sums = _member_checksums(strat, out) * scale
-        kind = x.sharding.memory_kind
-        return [WorkloadResult(strat, name, buffer_bytes, iters,
-                               useful * iters, per * iters, 0,
-                               checksum=float(c), memory_kind=kind)
-                for name, c in zip(names, sums)]
+    def result(name, checksum, seed):
+        return WorkloadResult(
+            strat, name, buffer_bytes if entry.lines else 0, iters, nbytes,
+            elapsed, tx, checksum=float(checksum), memory_kind=kind,
+            chain_seed=seed)
 
-    # write-like paths (w/x/y/i...): no batched input array — one
-    # measurement, shared by every identical member (relabeled with
-    # each member's own pool for heterogeneous groups).
-    wl = make_shaped_workload(strategy, pool, buffer_bytes, shape)
-    try:
-        res = wl.run(iters)
-    finally:
-        wl.release()
-    import dataclasses
-    return [res if name == res.pool else dataclasses.replace(res, pool=name)
-            for name in names]
+    if not entry.stacked:
+        # one measurement serves every member, labeled with its pool
+        return [result(name, sums[0], None) for name in names]
+    return [result(name, c, sd if entry.buffer == "chain" else None)
+            for name, c, sd in zip(names, sums, seeds)]
+
+
+def _operand(entry: Strategy, pool: MemoryPool, rows: int, seeds,
+             shape) -> Optional[jax.Array]:
+    """The operand ``entry``'s kernel is handed, in ``pool``'s memory:
+    stacked over the members for stacked strategies."""
+    if entry.buffer is None:
+        return None
+    with spans.span("inputs"):
+        if entry.buffer == "lines":
+            # every member streams the same content, so one plain
+            # reference checks every member's checksum
+            return pool.place(jnp.broadcast_to(
+                bw_buffer_init((rows, LANE), jnp.float32),
+                (len(seeds), rows, LANE)))
+        if entry.buffer == "chain":
+            return chase_operand(pool, rows, seeds)
+        if entry.buffer == "strided":
+            return pool.place(ops.lay_lines(
+                ops.make_strided_chain(rows, chase_stride(shape))))
+        # the identity keeps every power exact whatever the MXU's input
+        # precision, so the checksum (the trace, 128) checks the kernel
+        return jnp.eye(LANE, dtype=jnp.float32)
 
 
 def chase_operand(pool: MemoryPool, rows: int, seeds) -> jax.Array:
@@ -388,17 +365,20 @@ def _vmapped(kernel, operand, programs: Optional[dict], stats,
     return fn
 
 
-def _member_checksums(strat: str, out) -> np.ndarray:
-    """Per-member checksum of a vmapped stream pass: the read sum, plus
-    the destination's sum where the kernel writes one."""
+def _checksums(out, stacked: bool) -> np.ndarray:
+    """Each member's checksum: the sum of every array the kernel
+    returned, over all but the leading member axis of a stacked pass
+    (a read's sum, plus the destination's sum where it writes one; a
+    chase's final index).  A shared measurement gives one."""
     with spans.span("readback"):
-        if strat == "b":
-            acc, written = out
-            return (np.asarray(acc, np.float64)
-                    + np.asarray(jnp.sum(written, axis=(1, 2)), np.float64))
-        if strat in ("c", "x"):
-            return np.asarray(jnp.sum(out, axis=(1, 2)), np.float64)
-        return np.asarray(out, np.float64)
+        total = np.zeros(1)
+        for o in jax.tree.leaves(out):
+            if not stacked:
+                o = jnp.sum(o)
+            elif o.ndim > 1:
+                o = jnp.sum(o, axis=tuple(range(1, o.ndim)))
+            total = total + np.asarray(o, np.float64)
+        return total
 
 
 def chase_steps(rows: int) -> int:
@@ -407,18 +387,12 @@ def chase_steps(rows: int) -> int:
     return max(1, rows - 1)
 
 
-def _rows(buffer_bytes: int) -> int:
-    rows = max(1, buffer_bytes // LINE_BYTES)
-    # keep divisible by the largest block we use
-    block = 512 if rows >= 512 else rows
-    return (rows // block) * block or rows
-
-
 def rows_for(buffer_bytes: int) -> int:
-    """Public spelling of the buffer->line-rows mapping every backend
-    shares (block-aligned row count for a byte budget); the spmd rung
-    builder and the batched measured pass must agree on it exactly."""
-    return _rows(buffer_bytes)
+    """The buffer->line-rows mapping every backend shares: whole blocks
+    of a byte budget; the spmd rung builder and the measured pass must
+    agree on it exactly."""
+    rows = max(1, buffer_bytes // LINE_BYTES)
+    return (rows // block_rows(rows)) * block_rows(rows) or rows
 
 
 def _timed(fn, *args, iters: int, **kw) -> Tuple[float, Any]:
@@ -459,7 +433,7 @@ def refusal(strategy: str, pool: MemoryPool,
         return ("no probe kernel reaches another chip's memory; its "
                 "operands would live in local HBM")
     if pool.node.kind == "vmem":
-        if strategy not in _VMEM_KERNELS:
+        if STRATEGIES[strategy].vmem is None:
             return (f"strategy {strategy!r} has no VMEM-resident kernel; "
                     f"on the vmem pool it would stream HBM")
         if buffer_bytes > VMEM_KERNEL_BYTES:
@@ -472,240 +446,3 @@ def refusal(strategy: str, pool: MemoryPool,
                 "('Unimplemented DMA from host to vmem'), and the Pallas "
                 "interpreter cannot mix host and device memory")
     return None
-
-
-def _done(strategy: str, pool: MemoryPool, buffer_bytes: int, iters: int,
-          nbytes: int, t: float, out, *, src=None, transactions: int = 0
-          ) -> WorkloadResult:
-    """One registry workload's result, stamped with the kernel's output
-    checksum (the sum over every array of ``out``) and the memory kind
-    of its operand (``src``) or, for pure writes, of its destination."""
-    where = src if src is not None else out
-    with spans.span("readback"):
-        checksum = sum(float(jnp.sum(o)) for o in jax.tree.leaves(out))
-    return WorkloadResult(
-        strategy, pool.node.name, buffer_bytes, iters, nbytes, t,
-        transactions, checksum=checksum,
-        memory_kind=where.sharding.memory_kind)
-
-
-def _operand(alloc: Allocation, init, shape, dtype):
-    """The allocation's placed array.  A pool with no memory kind (vmem)
-    places nothing, so its operand is made in default memory."""
-    return alloc.array if alloc.array is not None else init(shape, dtype)
-
-
-# ---- bandwidth strategies ---------------------------------------------------
-
-
-@register_strategy("r")
-def _mk_r(pool, buffer_bytes, **kw):
-    """sequential reads (cacheable) — read bandwidth"""
-    rows = _rows(buffer_bytes)
-    alloc = pool.alloc((rows, LANE), jnp.float32, init=bw_buffer_init,
-                       tag="bw:r")
-    x = _operand(alloc, bw_buffer_init, (rows, LANE), jnp.float32)
-    vmem = _fits_vmem(buffer_bytes) or pool.node.kind == "vmem"
-
-    def run(iters):
-        if vmem:
-            t, out = _timed(ops.vmem_read, x, repeats=8, iters=iters)
-            t, out = t / 8, out / 8
-        else:
-            t, out = _timed(ops.stream_read, x, block_rows=min(512, rows),
-                            iters=iters)
-        return _done("r", pool, buffer_bytes, iters,
-                     rows * LINE_BYTES * iters, t * iters, out, src=x)
-
-    return Workload("r", pool, buffer_bytes,
-                    "sequential cacheable read", run, alloc)
-
-
-@register_strategy("w")
-def _mk_w(pool, buffer_bytes, **kw):
-    """sequential writes (cacheable, write-allocate) — write bandwidth"""
-    rows = _rows(buffer_bytes)
-    alloc = pool.alloc((rows, LANE), jnp.float32, tag="bw:w")
-    vmem = _fits_vmem(buffer_bytes) or pool.node.kind == "vmem"
-
-    def run(iters):
-        if vmem:
-            t, out = _timed(ops.vmem_write, rows=rows, repeats=8,
-                            iters=iters)
-            t = t / 8
-        else:
-            t, out = _timed(ops.stream_write, rows=rows,
-                            block_rows=min(512, rows), iters=iters)
-        return _done("w", pool, buffer_bytes, iters,
-                     rows * LINE_BYTES * iters, t * iters, out)
-
-    return Workload("w", pool, buffer_bytes,
-                    "sequential cacheable write", run, alloc)
-
-
-@register_strategy("s")
-def _mk_s(pool, buffer_bytes, **kw):
-    """non-cacheable sequential read (always streams from the module)"""
-    rows = _rows(buffer_bytes)
-    alloc = pool.alloc((rows, LANE), jnp.float32, init=bw_buffer_init,
-                       tag="bw:s")
-    x = _operand(alloc, bw_buffer_init, (rows, LANE), jnp.float32)
-
-    def run(iters):
-        t, out = _timed(ops.stream_read, x, block_rows=min(512, rows),
-                        iters=iters)
-        return _done("s", pool, buffer_bytes, iters,
-                     rows * LINE_BYTES * iters, t * iters, out, src=x)
-
-    return Workload("s", pool, buffer_bytes, "non-cacheable read", run,
-                    alloc)
-
-
-@register_strategy("x")
-def _mk_x(pool, buffer_bytes, **kw):
-    """non-cacheable write (write-allocate: line read+written)"""
-    rows = _rows(buffer_bytes)
-    alloc = pool.alloc((rows, LANE), jnp.float32, init=bw_buffer_init,
-                       tag="bw:x")
-    x = _operand(alloc, bw_buffer_init, (rows, LANE), jnp.float32)
-
-    def run(iters):
-        t, out = _timed(ops.stream_rmw, x, block_rows=min(512, rows),
-                        iters=iters)
-        return _done("x", pool, buffer_bytes, iters,
-                     2 * rows * LINE_BYTES * iters, t * iters, out, src=x)
-
-    return Workload("x", pool, buffer_bytes,
-                    "non-cacheable write (allocate)", run, alloc)
-
-
-@register_strategy("y")
-def _mk_y(pool, buffer_bytes, **kw):
-    """write-streaming (no write-allocate — the dc zva analog)"""
-    rows = _rows(buffer_bytes)
-    alloc = pool.alloc((rows, LANE), jnp.float32, tag="bw:y")
-
-    def run(iters):
-        t, out = _timed(ops.stream_write, rows=rows,
-                        block_rows=min(512, rows), iters=iters)
-        return _done("y", pool, buffer_bytes, iters,
-                     rows * LINE_BYTES * iters, t * iters, out)
-
-    return Workload("y", pool, buffer_bytes, "write-streaming", run, alloc)
-
-
-@register_strategy("c")
-def _mk_c(pool, buffer_bytes, **kw):
-    """copy stream (read every line, write it elsewhere) — STREAM copy"""
-    rows = _rows(buffer_bytes)
-    alloc = pool.alloc((rows, LANE), jnp.float32, init=bw_buffer_init,
-                       tag="bw:c")
-    x = _operand(alloc, bw_buffer_init, (rows, LANE), jnp.float32)
-
-    def run(iters):
-        t, out = _timed(ops.stream_copy, x, block_rows=min(512, rows),
-                        iters=iters)
-        return _done("c", pool, buffer_bytes, iters,
-                     2 * rows * LINE_BYTES * iters, t * iters, out, src=x)
-
-    return Workload("c", pool, buffer_bytes, "copy stream", run, alloc)
-
-
-@register_strategy("b")
-def _mk_mixed(pool, buffer_bytes, *, read_fraction: float = 0.5, **kw):
-    """mixed read/write blocks at a configurable r:w ratio"""
-    rows = _rows(buffer_bytes)
-    alloc = pool.alloc((rows, LANE), jnp.float32, init=bw_buffer_init,
-                       tag="bw:b")
-    x = _operand(alloc, bw_buffer_init, (rows, LANE), jnp.float32)
-    rf = max(0.0, min(1.0, read_fraction))
-
-    def run(iters):
-        t, out = _timed(ops.stream_mixed, x, read_fraction=rf,
-                        block_rows=min(512, rows), iters=iters)
-        # the checksum is the read sum plus the sum of what was written
-        return _done("b", pool, buffer_bytes, iters,
-                     rows * LINE_BYTES * iters, t * iters, out, src=x)
-
-    return Workload("b", pool, buffer_bytes,
-                    f"mixed r/w stream (rf={rf:g})", run, alloc)
-
-
-def _chase_workload(letter: str, pool, buffer_bytes: int, chain, kernel,
-                    description: str, seed: Optional[int] = None
-                    ) -> Workload:
-    """A pointer-chase workload whose chain buffer is placed through the
-    pool (so a latency is measured in the pool's own memory).  ``chain``
-    gives a row count's successors; their line layout is built on the
-    device."""
-    rows = _rows(buffer_bytes)
-
-    def init(_shape, _dtype):
-        return ops.lay_lines(chain(rows))
-
-    alloc = pool.alloc((rows, LANE), jnp.int32, init=init,
-                       tag=f"lat:{letter}")
-    buf = _operand(alloc, init, (rows, LANE), jnp.int32)
-    steps = chase_steps(rows)
-
-    def run(iters):
-        t, out = _timed(kernel, buf, n_steps=steps,
-                        iters=max(1, iters // 10))
-        res = _done(letter, pool, buffer_bytes, iters, rows * LINE_BYTES,
-                    t, out, src=buf, transactions=steps)
-        res.chain_seed = seed
-        return res
-
-    return Workload(letter, pool, buffer_bytes, description, run, alloc)
-
-
-@register_strategy("t")
-def _mk_strided(pool, buffer_bytes, *, stride: int = 8, **kw):
-    """strided pointer chase (constant hop distance, non-cacheable)"""
-    return _chase_workload(
-        "t", pool, buffer_bytes,
-        lambda rows: ops.make_strided_chain(rows, stride), ops.chase_hbm,
-        f"strided pointer-chase (x{stride})")
-
-
-# ---- latency strategies -----------------------------------------------------
-
-
-@register_strategy("l")
-def _mk_l(pool, buffer_bytes, *, seed: int = 0, **kw):
-    """data-dependent pointer chase (cacheable) — latency"""
-    vmem = _fits_vmem(buffer_bytes) or pool.node.kind == "vmem"
-    return _chase_workload(
-        "l", pool, buffer_bytes,
-        lambda rows: ops.make_chain(rows, seed),
-        ops.chase_vmem if vmem else ops.chase_hbm,
-        "pointer-chase latency", seed)
-
-
-@register_strategy("m")
-def _mk_m(pool, buffer_bytes, *, seed: int = 0, **kw):
-    """non-cacheable pointer chase — module latency"""
-    return _chase_workload(
-        "m", pool, buffer_bytes,
-        lambda rows: ops.make_chain(rows, seed), ops.chase_hbm,
-        "non-cacheable pointer-chase", seed)
-
-
-# ---- memory-idle -------------------------------------------------------------
-
-
-@register_strategy("i")
-def _mk_idle(pool, buffer_bytes, **kw):
-    """memory-idle MXU busy loop (zero memory traffic)"""
-    # the identity keeps every power exact whatever the MXU's input
-    # precision, so the checksum (the trace, 128) checks the kernel
-    a = jnp.eye(128, dtype=jnp.float32)
-
-    def run(iters):
-        t, out = _timed(lambda aa: ops.mxu_probe(aa, iters=64), a,
-                        iters=iters)
-        return WorkloadResult("i", pool.node.name, 0, iters, 0, t * iters,
-                              0, checksum=float(jnp.sum(out)))
-
-    return Workload("i", pool, 0, "memory-idle busy loop", run, None,
-                    is_memory_bound=False)

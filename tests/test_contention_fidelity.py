@@ -313,9 +313,9 @@ def test_pallas_branch_fns_execute_and_stay_fenced(strategy):
     to a finite result, and the measured region remains structurally
     fenced — the dataflow edge from the start-barrier psum reaches
     every pallas_call's operands."""
-    from repro.core.coordinator import (_spmd_branch_fn,
-                                        build_rung_program,
+    from repro.core.coordinator import (build_rung_program,
                                         measured_region_is_fenced)
+    from repro.core.exec.program import spmd_branch_fn as _spmd_branch_fn
     from repro.core.scenarios import TrafficShape
 
     shape = {"b": TrafficShape.mixed(1, 1),
@@ -334,7 +334,8 @@ def test_pallas_activity_programs_contain_pallas_calls():
     structural, not a label."""
     import jax
 
-    from repro.core.coordinator import _spmd_branch_fn, build_rung_program
+    from repro.core.coordinator import build_rung_program
+    from repro.core.exec.program import spmd_branch_fn as _spmd_branch_fn
 
     def has_pallas(activity):
         fns = [_spmd_branch_fn("r", None, ROWS, 2, activity=activity)]
@@ -747,9 +748,9 @@ def test_spmd_ladder_refuses_pinned_single_device():
 def test_jnp_fallback_branches_still_fenced():
     """The compat fallback (pure-jnp loops) keeps the original fence
     guarantee — the checker extension must not regress it."""
-    from repro.core.coordinator import (_spmd_branch_fn,
-                                        build_rung_program,
+    from repro.core.coordinator import (build_rung_program,
                                         measured_region_is_fenced)
+    from repro.core.exec.program import spmd_branch_fn as _spmd_branch_fn
 
     fns = [_spmd_branch_fn("r", None, ROWS, 2, activity="jnp"),
            _spmd_branch_fn("w", None, ROWS, 2, activity="jnp")]

@@ -209,8 +209,8 @@ def test_spmd_backend_executes_fenced_ladder_on_8_devices():
     from repro.core.characterize import characterize_matrix
     from repro.core.coordinator import (CoreCoordinator,
                                         build_rung_program,
-                                        measured_region_is_fenced,
-                                        _spmd_branch_fn)
+                                        measured_region_is_fenced)
+    from repro.core.exec.program import spmd_branch_fn as _spmd_branch_fn
     from repro.core.scenarios import (ObserverSpec, ScenarioSpec,
                                       StressorSpec)
     import numpy as np

@@ -284,15 +284,11 @@ def test_strided_chase_modeled_distinctly():
 def test_batched_chase_latency_matches_naive():
     """The batched chase pass splits group wall time /g — valid only if
     the g chains execute back-to-back within the vmapped pass.  Guard
-    that assumption by comparing against the naive single-chase path."""
+    that assumption by comparing against a group of one chase."""
     from repro.core.pools import PoolManager
-    from repro.core.workloads import make_workload, measure_group
+    from repro.core.workloads import measure_group
     mgr = PoolManager()
-    wl = make_workload("l", mgr.pool("hbm"), 64 << 10)
-    try:
-        naive = wl.run(10)
-    finally:
-        wl.release()
+    (naive,), _ = measure_group("l", mgr.pool("hbm"), 64 << 10, 1, 10)
     batched, _ = measure_group("l", mgr.pool("hbm"), 64 << 10, 6, 10)
     # loose bound: wall-clock noise under full-suite load is real, but
     # a broken /g split (g=6 here) would still be ~6x off

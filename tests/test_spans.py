@@ -29,8 +29,8 @@ from repro.core.workloads import VMEM_KERNEL_BYTES
 OBSERVERS = ("r", "s", "w", "l")
 # observers whose group is measured by a jit(vmap(...)) program that a
 # fresh coordinator builds (at 64 KiB the read and the chase are
-# VMEM-resident, so the three programs differ); "w" runs the registry
-# workload, whose kernel is jitted once per process
+# VMEM-resident, so the three programs differ); "w" shares one
+# unvmapped measurement, whose kernel is jitted once per process
 VMAPPED = ("r", "s", "l")
 MEASUREMENT_ARGS = {"strategy", "bytes", "members", "group"}
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -112,8 +112,8 @@ def test_spans_cover_the_sweep_in_order_and_flat(tmp_path, batched):
             assert set(args) == MEASUREMENT_ARGS, (name, args)
             assert args["bytes"] == 64 << 10 and args["members"] == 1
         want = ["inputs", "build", "timed", "readback"]
-        if args["strategy"] == "w" or not batched:
-            want.append("inputs")           # the registry frees its buffer
+        if args["strategy"] == "w":
+            want.remove("inputs")           # the write makes its output
         assert [name for name, _a in m] == want, args
     # the spans between plan and assemble are all measurement spans
     body = events[int(batched):-2]
@@ -124,7 +124,7 @@ def test_spans_cover_the_sweep_in_order_and_flat(tmp_path, batched):
 def test_compile_counters(tmp_path, batched):
     result, db, _events, n_compile_events = _sweep(tmp_path, batched)
     st = result.stats
-    assert st.programs_built == (len(VMAPPED) if batched else 0)
+    assert st.programs_built == len(VMAPPED)
     assert st.xla_compiles + st.cache_loads == n_compile_events
     # a program the coordinator builds is compiled or loaded
     assert n_compile_events >= st.programs_built

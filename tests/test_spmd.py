@@ -17,11 +17,12 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
-from repro.core.coordinator import (_effective_duty, _spmd_branch_fn,
-                                    build_ladder_program,
+from repro.core.coordinator import (build_ladder_program,
                                     build_rung_program,
                                     build_scenario_program,
                                     measured_region_is_fenced)
+from repro.core.exec.plan import effective_duty as _effective_duty
+from repro.core.exec.program import spmd_branch_fn as _spmd_branch_fn
 
 ROWS = 16
 
@@ -218,7 +219,7 @@ def test_fence_check_accepts_pretraced_jaxpr():
 def test_ladder_checker_rejects_unfenced_scan():
     """A scanned ladder whose steps carry no psum sandwich (or only an
     advisory one nothing depends on) must NOT verify."""
-    from repro.core.coordinator import _shard_map_bodies
+    from repro.core.exec.fence import _shard_map_bodies
 
     mesh = compat.make_mesh_from_devices(jax.devices()[:1], ("engine",))
 
